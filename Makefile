@@ -71,10 +71,16 @@ overload-smoke:
 # overload bench: closed-loop TP point serving with and without a concurrent
 # AP flood (admission on), reporting TP QPS/p99 deltas + shed rate
 bench-overload:
-	JAX_PLATFORMS=cpu $(PY) bench.py --overload-only
+	BENCH_PLATFORM=cpu $(PY) bench.py --overload-only
 
 bench:
 	$(PY) bench.py
+
+# the served SQL path on the chip: TPC-H SF1 over the MySQL wire + a TP leg,
+# one process, every result checked against a pandas reference.  Fails
+# without a TPU (python chip_smoke.py --dry-run-cpu --sf 0.02 debugs on a CPU)
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # fast batching smoke: the batching marker suite (batched vs sequential
 # bit-identical results under 100+ concurrent sessions, poisoned-key error
@@ -84,7 +90,7 @@ bench:
 # proof — the runtime witness fails loudly on any acquisition-graph cycle)
 batch-smoke:
 	JAX_PLATFORMS=cpu GALAXYSQL_LOCKDEP=1 $(PY) -m pytest tests/ -q -m batching -p no:cacheprovider
-	JAX_PLATFORMS=cpu BENCH_BATCH_SESSIONS=100,1000 $(PY) bench.py --batch-only
+	BENCH_PLATFORM=cpu BENCH_BATCH_SESSIONS=100,1000 $(PY) bench.py --batch-only
 
 # DML batching smoke: the dml_batch marker suite (batched vs sequential
 # bit-identical table state under 100+ concurrent write sessions, poison-key
@@ -98,7 +104,7 @@ dml-smoke:
 # DML bench: closed-loop point-DML + mixed read/write serving, DML batching
 # on vs off (BENCH json lines on stdout; BENCH_DML_SESSIONS=64,256 default)
 bench-dml:
-	JAX_PLATFORMS=cpu $(PY) bench.py --dml-only
+	BENCH_PLATFORM=cpu $(PY) bench.py --dml-only
 
 # chaos smoke: the fault-injection suite over a real worker subprocess —
 # retry transparency + dedupe-window exactly-once (reply-leg drop), circuit
@@ -120,7 +126,7 @@ skew-smoke:
 # skew bench: Zipf theta sweep on the Q9-like join family, skew-on vs
 # skew-off, 8 virtual devices (BENCH json lines on stdout)
 bench-skew:
-	JAX_PLATFORMS=cpu $(PY) bench.py --skew-only
+	BENCH_PLATFORM=cpu $(PY) bench.py --skew-only
 
 # workload-insight smoke: statement-digest aggregation (exec/error counts,
 # windows, digest stability across literals), the event journal, slow-log
@@ -154,7 +160,7 @@ chaos-rebalance:
 # rebalance bench: closed-loop point serving measured quiesced vs during a
 # live SPLIT (rebalance-while-serving QPS dip + p99; BENCH json on stdout)
 bench-rebalance:
-	JAX_PLATFORMS=cpu $(PY) bench.py --rebalance-only
+	BENCH_PLATFORM=cpu $(PY) bench.py --rebalance-only
 
 # kernel smoke: the kernel-tier matrix — Pallas join/agg vs reference
 # bit-identity (NULL keys, empty build, duplicate keys, overflow-ladder
@@ -169,7 +175,7 @@ kernel-smoke:
 # the honest number until a TPU answers) + the AOT compile-cache cold-vs-warm
 # restart compile_ms comparison (BENCH json on stdout)
 bench-kernels:
-	JAX_PLATFORMS=cpu $(PY) bench.py --kernels-only
+	BENCH_PLATFORM=cpu $(PY) bench.py --kernels-only
 
 # self-heal smoke: the quarantine state machine end-to-end — a genuine
 # stats-driven join-order regression auto-rolls-back, verifies over
@@ -194,7 +200,7 @@ slo-smoke:
 # overhead measurement — closed-loop QPS with the history/SLO tick on vs
 # hatched off (target: <= 3% delta; BENCH json on stdout)
 bench-slo:
-	JAX_PLATFORMS=cpu $(PY) bench.py --slo-only
+	BENCH_PLATFORM=cpu $(PY) bench.py --slo-only
 
 # incident flight-recorder smoke: the incident marker suite — tail-sampled
 # trace retention (slow/shed/error tails kept at sample_rate=0, phase
@@ -210,7 +216,7 @@ incident-smoke:
 # always-on tail-sampled tracing vs GALAXYSQL_TRACING=0 — overhead target
 # <= 3%, dispatch counts unchanged, steady retraces 0 (BENCH_r14.json)
 bench-tracing:
-	JAX_PLATFORMS=cpu $(PY) bench.py --tracing-only
+	BENCH_PLATFORM=cpu $(PY) bench.py --tracing-only
 
 # serving-tier smoke: the router marker suite — consistent-hash affinity,
 # session pinning + typed-once failover, cluster-wide admission gossip,
@@ -225,7 +231,7 @@ scaleout-smoke:
 # metadb, closed-loop point workload through the front router — aggregate
 # QPS, p99, affinity hit rate, gossip staleness into BENCH_r12.json
 bench-scaleout:
-	JAX_PLATFORMS=cpu $(PY) bench.py --scaleout-only
+	BENCH_PLATFORM=cpu $(PY) bench.py --scaleout-only
 
 # columnar HTAP replica: CDC-tailed delta+base tier bit-identical to the
 # row store at arbitrary watermarks, crash-resume, compaction vs racing
@@ -239,11 +245,11 @@ columnar-smoke:
 # HTAP curve: columnar replica vs row store rows/s on AP scans at SF0.2
 # under sustained DML, plus freshness-lag series — into BENCH_r13.json
 bench-htap:
-	JAX_PLATFORMS=cpu $(PY) bench.py --htap-only
+	BENCH_PLATFORM=cpu $(PY) bench.py --htap-only
 
 .PHONY: tier1 fusion-smoke obs-smoke rf-smoke cache-smoke trace-smoke bench \
 	batch-smoke chaos-smoke skew-smoke bench-skew summary-smoke heal-smoke \
 	overload-smoke bench-overload dml-smoke bench-dml lint lint-smoke \
 	rebalance-smoke chaos-rebalance bench-rebalance kernel-smoke \
 	bench-kernels slo-smoke bench-slo scaleout-smoke bench-scaleout \
-	columnar-smoke bench-htap incident-smoke bench-tracing
+	columnar-smoke bench-htap incident-smoke bench-tracing chip-smoke

@@ -38,29 +38,32 @@ from __future__ import annotations
 
 import hashlib
 import io
+import logging
 import os
 import pickle
 import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
-_FORMAT_VERSION = 1
+import jax
+
+from galaxysql_tpu.runtime import exec_platform, host_isa_id
+
+_LOG = logging.getLogger(__name__)
+
+_FORMAT_VERSION = 2  # 2: entries record their execution devices
 
 
-def _host_cpu_id() -> str:
-    """Stable host-CPU ISA fingerprint (same notion as bench.py's host id):
-    model + flags, no frequencies/temperatures."""
-    try:
-        lines = []
-        with open("/proc/cpuinfo") as f:
-            for ln in f:
-                if ln.startswith(("model name", "flags")):
-                    lines.append(ln.strip())
-                    if len(lines) >= 2:
-                        break
-        return hashlib.md5("\n".join(lines).encode()).hexdigest()[:12]
-    except OSError:
-        return "unknown"
+def _assigned_device_ids(compiled) -> list:
+    """Device ids a compiled program executes on, in assignment order, read
+    off its shardings (every sharding of one program spans the same set)."""
+    for s in jax.tree_util.tree_leaves(
+            (compiled.output_shardings, compiled.input_shardings)):
+        mesh = getattr(s, "mesh", None)
+        if mesh is not None:
+            return [int(d.id) for d in mesh.devices.flat]
+        return sorted(int(d.id) for d in s.device_set)
+    raise ValueError("compiled program has no sharding to read devices from")
 
 
 class CompileCache:
@@ -77,6 +80,9 @@ class CompileCache:
         self.hits = 0
         self.misses = 0
         self.stores = 0
+        # restored programs whose first call raised and fell back to a live
+        # build (each also counts a retrace): 0 in a healthy round trip
+        self.call_fallbacks = 0
         self._metrics_refs: list = []
 
     # -- lifecycle ----------------------------------------------------------
@@ -102,12 +108,11 @@ class CompileCache:
 
     def _fingerprint(self) -> str:
         if self._fp is None:
-            import jax
             devs = jax.devices()
             kind = devs[0].device_kind if devs else "none"
             self._fp = "|".join([
                 f"v{_FORMAT_VERSION}", jax.__version__, jax.default_backend(),
-                f"{len(devs)}x{kind}", _host_cpu_id(),
+                f"{len(devs)}x{kind}", host_isa_id(),
             ])
         return self._fp
 
@@ -128,8 +133,11 @@ class CompileCache:
         if not hasattr(f, "lower"):
             return  # host-np programs / plain closures: nothing to serialize
         try:
-            import jax
             import jax.numpy as jnp
+            if exec_platform() != jax.default_backend():
+                # ran under the TP path's CPU pin on an accelerator host:
+                # flush() would AOT-lower it for the accelerator instead
+                return
             leaves, treedef = jax.tree_util.tree_flatten(args)
             specs = []
             for leaf in leaves:
@@ -176,8 +184,14 @@ class CompileCache:
                     or rec.get("key") != repr(key)):
                 raise ValueError("stale compile-cache entry")
             from jax.experimental import serialize_executable as se
-            loaded = se.deserialize_and_load(rec["payload"], rec["in_tree"],
-                                             rec["out_tree"])
+            # load for the devices the program was compiled for, in their
+            # assignment order (jax otherwise assumes ALL devices and every
+            # single-device program fails its first call on a multi-device
+            # host); a recorded id this process lacks is a stale entry
+            by_id = {d.id: d for d in jax.devices()}
+            loaded = se.deserialize_and_load(
+                rec["payload"], rec["in_tree"], rec["out_tree"],
+                execution_devices=[by_id[i] for i in rec["devices"]])
         except FileNotFoundError:
             self.misses += 1
             self._push_metrics()
@@ -203,7 +217,6 @@ class CompileCache:
             pass
         self._push_metrics()
 
-        import jax
         cell = {"fb": None}
 
         def cached_program(*args, **kw):
@@ -214,7 +227,10 @@ class CompileCache:
                 try:
                     return loaded(*jax.tree_util.tree_leaves(args))
                 except Exception:
-                    pass
+                    self.call_fallbacks += 1
+                    _LOG.warning("compile cache: restored program %r "
+                                 "rejected its call; rebuilding live",
+                                 key[0] if key else key, exc_info=True)
             # call-time mismatch (e.g. a shape-polymorphic key whose arrays
             # changed): build live and stay on the built program thereafter
             f2 = builder()
@@ -236,7 +252,6 @@ class CompileCache:
         if d is None or not todo:
             return
         from galaxysql_tpu.exec import operators as ops
-        import jax
         from jax.experimental import serialize_executable as se
         for key, (treedef, specs) in todo:
             path = self._path_for(key)
@@ -256,7 +271,8 @@ class CompileCache:
                 payload, in_tree, out_tree = se.serialize(compiled)
                 rec = {"v": _FORMAT_VERSION, "fp": self._fingerprint(),
                        "key": repr(key), "payload": payload,
-                       "in_tree": in_tree, "out_tree": out_tree}
+                       "in_tree": in_tree, "out_tree": out_tree,
+                       "devices": _assigned_device_ids(compiled)}
                 buf = io.BytesIO()
                 pickle.dump(rec, buf)
                 tmp = path + ".tmp"

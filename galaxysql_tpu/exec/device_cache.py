@@ -2,9 +2,13 @@
 
 The TPU-first answer to the reference's buffer/scan caching: instead of pumping rows
 over JDBC per query (`TableScanClient`, SURVEY.md §2.6), whole column lanes live in
-device memory keyed by (table, partition, column, table-version).  A version bump (DML,
-DDL) invalidates; eviction is LRU by byte budget.  Scans hit HBM, so steady-state AP
-queries read at HBM bandwidth instead of PCIe/host bandwidth.
+device memory keyed by (table, partition, column, table-version, device).  A version
+bump (DML, DDL) invalidates; eviction is LRU by byte budget.  Scans hit HBM, so
+steady-state AP queries read at HBM bandwidth instead of PCIe/host bandwidth.
+
+Every upload names its device (`runtime.exec_device()`: the accelerator, or the CPU
+device inside the TP path's pin) and the key carries it, so a lane first touched by a
+CPU-pinned statement is never what a later accelerator scan of the same version reads.
 
 Concurrent misses on one key are single-flighted: the first thread runs the
 (possibly O(table)) builder + device transfer, the rest wait on a per-key event
@@ -20,10 +24,13 @@ import threading
 import weakref
 from typing import Any, Dict, Tuple
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 
-Key = Tuple[int, int, str, int, int]  # (store.uid, pid, column, version, row_count)
+from galaxysql_tpu.runtime import exec_device
+
+# (store.uid, pid, column, version, row_count, device)
+Key = Tuple[int, int, str, int, int, Any]
 
 # host->device transfer accounting: every cache MISS materializes + ships a
 # lane to the device; bytes/counts accumulate here (plain adds, host-side) and
@@ -49,7 +56,6 @@ def hbm_high_water() -> Dict[str, int]:
     empty dict after the first probe."""
     global _HBM_DEVICES
     if _HBM_DEVICES is None:
-        import jax
         probed = []
         try:
             for d in jax.devices():
@@ -137,7 +143,8 @@ class DeviceCache:
         """Like get_lane, but the host array is built lazily: cache hits skip the
         (possibly O(table)) host-side materialization entirely, and concurrent
         misses on one key run the builder exactly once."""
-        key = (store.uid, pid, column, version, length)
+        device = exec_device()
+        key = (store.uid, pid, column, version, length, device)
         got, ev = self._lookup_or_claim(key)
         if ev is None:
             # hit path is the per-lane scan hot path: refresh the gauges only
@@ -147,7 +154,7 @@ class DeviceCache:
                 self._push_metrics()
             return got
         try:
-            dev = jnp.asarray(builder())
+            dev = jax.device_put(builder(), device)
             nbytes = int(dev.nbytes)
             TRANSFER_STATS["bytes"] += nbytes
             TRANSFER_STATS["transfers"] += 1

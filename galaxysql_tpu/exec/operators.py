@@ -26,6 +26,7 @@ from galaxysql_tpu.expr.compiler import ExprCompiler, batch_env, _find_dictionar
     _signed_div_round, _pow10
 from galaxysql_tpu.exec.runtime_filter import RF_STATS
 from galaxysql_tpu.kernels import relational as K
+from galaxysql_tpu.runtime import exec_platform
 from galaxysql_tpu.types import datatype as dt
 
 MIN_BUCKET = 1024
@@ -71,6 +72,10 @@ DISPATCH_STATS = {"dispatches": 0}
 # bench.py snapshots these per query so compile-cache regressions surface in
 # the perf trajectory, and traced queries get one `compile` span per event.
 COMPILE_STATS = {"retraces": 0, "compile_ms": 0.0, "cache_hits": 0}
+# the same first-invocation wall time split by program family (the key's
+# first element: agg_partial, join_pairs, mpp_agg, ...) with the number of
+# programs behind it — which family a cold start's compile seconds belong to
+COMPILE_MS_BY_PROGRAM: Dict[str, List[float]] = {}
 
 
 def reset_dispatch_stats():
@@ -81,6 +86,7 @@ def reset_compile_stats():
     COMPILE_STATS["retraces"] = 0
     COMPILE_STATS["compile_ms"] = 0.0
     COMPILE_STATS["cache_hits"] = 0
+    COMPILE_MS_BY_PROGRAM.clear()
 
 
 def _timed_first_call(key, f, persist=True):
@@ -105,6 +111,10 @@ def _timed_first_call(key, f, persist=True):
             if _JIT_CACHE.get(key) is wrapper:
                 _JIT_CACHE[key] = f
         COMPILE_STATS["compile_ms"] += dt_ms
+        head = key[0] if isinstance(key, tuple) and key else "program"
+        fam = COMPILE_MS_BY_PROGRAM.setdefault(str(head), [0, 0.0])
+        fam[0] += 1
+        fam[1] += dt_ms
         if persist and not k:
             # record the input signature so Instance.save can AOT-serialize
             # this program into the persistent compile cache (no-op detached)
@@ -113,7 +123,6 @@ def _timed_first_call(key, f, persist=True):
         from galaxysql_tpu.utils import tracing as _tr
         tc = _tr.current()
         if tc is not None:
-            head = key[0] if isinstance(key, tuple) and key else "program"
             tc.event(f"compile:{head}", kind="compile",
                      wall_ms=round(dt_ms, 3))
         return out
@@ -349,7 +358,7 @@ def batched_point_lookup(store, pid: int, part, col: str, version: int,
     from galaxysql_tpu import native
     k = len(lane_vals)
     with part.lock:
-        if not force_device and jax.default_backend() == "cpu":
+        if not force_device and exec_platform() == "cpu":
             return _host_batched_point(part, col, lane_vals, snap, txn_id)
         n = part.num_rows
         lane = part.lanes[col]
@@ -761,7 +770,7 @@ class HashAggOp(Operator):
     def _partial_fn(self, max_groups: int):
         domains = self._matmul_domains()
         prelude = self.prelude
-        key = ("agg_partial", jax.default_backend(), K.kernel_selector_key(),
+        key = ("agg_partial", exec_platform(), K.kernel_selector_key(),
                self._cache_key(), max_groups,
                tuple(domains) if domains is not None else None,
                prelude.key() if prelude is not None else None)
@@ -809,7 +818,7 @@ class HashAggOp(Operator):
                   merge_specs: Tuple[K.AggSpec, ...]):
         # shared across ALL aggregations: behavior depends only on the merge specs and
         # capacity (key/agg lane dtypes are part of jit's own trace signature)
-        key = ("agg_merge", jax.default_backend(), K.kernel_selector_key(),
+        key = ("agg_merge", exec_platform(), K.kernel_selector_key(),
                max_groups, n_keys, merge_specs)
 
         def build():
@@ -1157,7 +1166,7 @@ class HashJoinOp(Operator):
 
     def _pairs_fn(self, cap: int):
         prelude = self.probe_prelude
-        key = ("join_pairs", jax.default_backend(), K.kernel_selector_key(),
+        key = ("join_pairs", exec_platform(), K.kernel_selector_key(),
                cap,
                tuple(expr_cache_key(e) for e in self.build_keys),
                tuple(expr_cache_key(e) for e in self.probe_keys),
@@ -1188,7 +1197,7 @@ class HashJoinOp(Operator):
         whole join (the CSR is also reused across probe batches/retries)."""
         nb = build_batch.capacity
         M = 1 << max(4, int(nb * 4 - 1).bit_length())
-        key = ("join_build_slots", jax.default_backend(),
+        key = ("join_build_slots", exec_platform(),
                K.kernel_selector_key(), nb, M,
                tuple(expr_cache_key(e) for e in self.build_keys))
 
@@ -1209,7 +1218,7 @@ class HashJoinOp(Operator):
 
     def _probe_csr_fn(self, cap: int, M: int, nb: int):
         prelude = self.probe_prelude
-        key = ("join_probe_csr", jax.default_backend(),
+        key = ("join_probe_csr", exec_platform(),
                K.kernel_selector_key(), cap, M, nb,
                tuple(expr_cache_key(e) for e in self.build_keys),
                tuple(expr_cache_key(e) for e in self.probe_keys),
@@ -1620,7 +1629,7 @@ class HashJoinOp(Operator):
         not hand a filterless artifact to a filters-on execution)."""
         rf_sig = tuple(sorted((s.filter_id, tuple(sorted(s.kinds)))
                               for s in self.rf_publish))
-        return ("join_build", self.frag_key.key, jax.default_backend(),
+        return ("join_build", self.frag_key.key, exec_platform(),
                 bool(K.prefer_scatter()),
                 tuple(expr_cache_key(e) for e in self.build_keys), rf_sig)
 

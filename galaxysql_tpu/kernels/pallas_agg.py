@@ -45,12 +45,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from galaxysql_tpu.exec import operators as ops
+from galaxysql_tpu.runtime import exec_platform
 
 
 def _interpret() -> bool:
-    """Mosaic lowering only on real TPU; everywhere else the kernel runs in
-    interpret mode (reference-exact, slow — gated behind the selector)."""
-    return jax.default_backend() != "tpu"
+    """Mosaic lowering only where the enclosing program runs on a TPU (a
+    program under the TP path's CPU pin does not, whatever the default
+    backend); everywhere else the kernel runs in interpret mode
+    (reference-exact, slow — gated behind the selector)."""
+    return exec_platform() != "tpu"
 
 
 def _make_place_kernel(n: int, M: int, max_rounds: int,

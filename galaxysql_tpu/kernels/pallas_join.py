@@ -37,13 +37,16 @@ from jax.experimental import pallas as pl
 
 from galaxysql_tpu.exec import operators as ops
 from galaxysql_tpu.kernels.relational import _GOLDEN, _M1, _M2
+from galaxysql_tpu.runtime import exec_platform
 
 _NULL_TAG = np.uint64(0xDEADBEEFCAFEBABE)
 _THIRTYONE = np.uint64(31)
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Same rule as `pallas_agg._interpret`: Mosaic only where the program
+    runs on a TPU."""
+    return exec_platform() != "tpu"
 
 
 def _mix64_v(h):

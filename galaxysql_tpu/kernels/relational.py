@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from galaxysql_tpu.runtime import exec_platform
+
 # ---------------------------------------------------------------------------
 # hashing
 # ---------------------------------------------------------------------------
@@ -77,11 +79,6 @@ def hash_columns(cols: Sequence[Tuple[Any, Optional[Any]]]) -> Any:
 
 _PALLAS_ENV_OFF = os.environ.get("GALAXYSQL_PALLAS", "1") == "0"
 
-# stats-driven row floor for auto mode: below this the fixed kernel-launch
-# overhead beats any VMEM-locality win, so small batches keep the reference
-# formulation (which is also the correctness oracle and the only CPU path)
-PALLAS_MIN_ROWS = 65536
-
 # trace-time selection counters — the dispatch-count guards in the `kernel`
 # test matrix prove a gated-off selector never even CONSIDERED Pallas for a
 # traced program (structurally off-path, not merely numerically equal)
@@ -113,37 +110,20 @@ def kernel_selector_key() -> str:
     return "k=" + kernel_mode()
 
 
-_PALLAS_OK: Optional[bool] = None
+def use_pallas() -> bool:
+    """Trace-time formulation choice for one kernel call site.
 
-
-def _pallas_ok() -> bool:
-    """Import gate: jax.experimental.pallas may be absent or broken in a
-    stripped runtime — the tier then degrades to the reference formulation
-    instead of erroring (checked once, cached)."""
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        try:
-            from galaxysql_tpu.kernels import pallas_agg  # noqa: F401
-            from galaxysql_tpu.kernels import pallas_join  # noqa: F401
-            _PALLAS_OK = True
-        except Exception:
-            _PALLAS_OK = False
-    return _PALLAS_OK
-
-
-def use_pallas(n: int) -> bool:
-    """Trace-time formulation choice for one kernel call site (`n` is the
-    static row count of the array the kernel sweeps)."""
-    mode = kernel_mode()
-    if _PALLAS_ENV_OFF or mode == "off" or not _pallas_ok():
-        KERNEL_STATS["reference"] += 1
-        return False
-    if mode == "pallas":
+    Auto mode selects no Pallas kernel: on a TPU v5e at TPC-H SF1 shapes Mosaic
+    refused all four (chip run, PR 21 — `build_slots`, `hash_slots`,
+    `hash_place`: "64-bit types are not supported"; `expand_offsets`:
+    RecursionError in lowering; CHANGES.md has the record).  They stay
+    reachable through KERNEL(PALLAS), where a lowering failure is the user's
+    typed error; nothing swaps formulations at run time."""
+    if not _PALLAS_ENV_OFF and kernel_mode() == "pallas":
         KERNEL_STATS["pallas"] += 1
         return True
-    hit = jax.default_backend() == "tpu" and n >= PALLAS_MIN_ROWS
-    KERNEL_STATS["pallas" if hit else "reference"] += 1
-    return hit
+    KERNEL_STATS["reference"] += 1
+    return False
 
 
 def exec_kernel_mode(hints, instance, session_overlay=None) -> str:
@@ -604,7 +584,7 @@ def hash_groupby(keys: Sequence[Tuple[Any, Optional[Any]]],
     step = ((h >> jnp.uint64(32)) << jnp.uint64(1)) | jnp.uint64(1)
 
     sentinel = jnp.int32(n)
-    if n > 0 and use_pallas(n):
+    if n > 0 and use_pallas():
         from galaxysql_tpu.kernels import pallas_agg
         rep, resolved, gid = pallas_agg.hash_place(ident, live, s0, step,
                                                    M, max_rounds)
@@ -677,8 +657,10 @@ def prefer_scatter() -> bool:
     """Kernel-formulation choice is a backend property: XLA:CPU lowers scatters
     to fast native loops but its comparator sorts are single-threaded (measured
     1.3s to lexsort 1.2M rows vs ~10ms for a segment_sum); TPU is the inverse
-    (scatters serialize, bitonic sorts + MXU matmuls are fast)."""
-    return jax.default_backend() == "cpu"
+    (scatters serialize, bitonic sorts + MXU matmuls are fast).  Asks where
+    the program being traced will RUN, not what the default backend is: a
+    program under the TP path's CPU pin gets the CPU formulation."""
+    return exec_platform() == "cpu"
 
 
 def groupby(keys, inputs, specs, live, max_groups, domains=None):
@@ -832,7 +814,7 @@ def _expand_offsets(counts, starts, npr: int, cap: int):
     among non-empty rows) — ~10x faster than searchsorted(offsets,
     arange(cap)) on XLA:CPU.  Selector-gated: the Pallas variant runs the
     same scatter + running-max sweep in VMEM."""
-    if npr > 0 and cap > 0 and use_pallas(cap):
+    if npr > 0 and cap > 0 and use_pallas():
         from galaxysql_tpu.kernels import pallas_join
         return pallas_join.expand_offsets(counts, starts, cap)
     scatter_at = jnp.where(counts > 0, starts, jnp.int64(cap))
@@ -863,7 +845,7 @@ def hash_join_build_slots(build_keys: Sequence[Tuple[Any, Optional[Any]]],
     Dead/NULL-key rows get the scratch slot M."""
     b_live = _effective_live(build_keys, build_live)
     nb = build_keys[0][0].shape[0]
-    if nb > 0 and use_pallas(nb):
+    if nb > 0 and use_pallas():
         from galaxysql_tpu.kernels import pallas_join
         return pallas_join.build_slots(build_keys, b_live, M)
     h_b = hash_columns(build_keys)
@@ -885,7 +867,7 @@ def hash_join_probe_csr(build_keys, probe_keys, build_live, probe_live,
     nb = build_keys[0][0].shape[0]
     npr = probe_keys[0][0].shape[0]
 
-    if npr > 0 and use_pallas(npr):
+    if npr > 0 and use_pallas():
         from galaxysql_tpu.kernels import pallas_join
         s_p = pallas_join.hash_slots(probe_keys, M)
     else:

@@ -1,23 +1,30 @@
 """ctypes bindings for the native storage runtime (libgalaxystore).
 
-Builds on demand with g++ if the shared library is missing (no pybind11 in the image —
-plain C ABI + ctypes per the environment constraints).  Every entry point has a numpy
-fallback so the engine runs without a compiler; `AVAILABLE` tells callers which path
-is live.
+The shared library is built with g++ from `galaxystore.cpp` on the machine
+that runs it (no pybind11 in the image — plain C ABI + ctypes per the
+environment constraints).  Its file name carries a hash of the source's
+content and of the host CPU's ISA (`-march=native` code is only valid there),
+so a stale or foreign `.so` that travelled with a copy of the tree is never
+loaded and a fresh checkout without one builds its own.  Every entry point has
+a numpy fallback so the engine runs without a compiler; `AVAILABLE` tells
+callers which path is live, and a failed build says so on stderr.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
 import numpy as np
 
+from galaxysql_tpu.runtime import host_isa_id
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libgalaxystore.so")
 _SRC = os.path.join(_DIR, "galaxystore.cpp")
 
 _lib: Optional[ctypes.CDLL] = None
@@ -25,13 +32,29 @@ _lock = threading.Lock()
 AVAILABLE = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        tag = hashlib.sha256(f.read() + host_isa_id().encode()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libgalaxystore-{tag}.so")
+
+
+def _build(so: str) -> bool:
+    # build beside the target and rename: concurrent processes (test workers,
+    # subprocess servers) must never load a half-written library
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(["g++", "-O3", "-march=native", "-shared", "-fPIC",
-                        "-o", _SO, _SRC], check=True, capture_output=True,
+                        "-o", tmp, _SRC], check=True, capture_output=True,
                        timeout=120)
+        os.replace(tmp, so)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        print(f"[galaxysql_tpu.native] building {os.path.basename(so)} "
+              f"failed ({e!r}); numpy fallbacks engage. "
+              f"{detail.decode(errors='replace')[-2000:]}", file=sys.stderr)
+        if os.path.exists(tmp):
+            os.remove(tmp)
         return False
 
 
@@ -40,16 +63,14 @@ def _load():
     with _lock:
         if _lib is not None or AVAILABLE:
             return
-        needs_build = not os.path.exists(_SO) or (
-            os.path.exists(_SRC) and
-            os.path.getmtime(_SRC) > os.path.getmtime(_SO))
-        if needs_build and os.path.exists(_SRC):
-            _build()
-        if not os.path.exists(_SO):
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
             return
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+            lib = ctypes.CDLL(so)
+        except OSError as e:
+            print(f"[galaxysql_tpu.native] loading {so} failed ({e!r}); "
+                  "numpy fallbacks engage", file=sys.stderr)
             return
         u64p = ctypes.POINTER(ctypes.c_uint64)
         i64p = ctypes.POINTER(ctypes.c_int64)

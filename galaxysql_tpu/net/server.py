@@ -533,9 +533,11 @@ def main():  # pragma: no cover - manual entry point
                     help="print 'SERVER_READY <mysql_port> <sync_port>' "
                          "once listening (bench/chaos harness handshake)")
     args = ap.parse_args()
+    import jax
+    from galaxysql_tpu import runtime
     if args.platform:
-        import jax
         jax.config.update("jax_platforms", args.platform)
+    runtime.enable_compile_cache()
     inst = Instance(data_dir=args.data_dir) if args.data_dir else Instance()
     if args.init_sql:
         sess = Session(inst)

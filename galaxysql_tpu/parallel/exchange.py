@@ -22,16 +22,6 @@ import jax.numpy as jnp
 AXIS = "shard"
 
 
-def _axis_size(name: str) -> int:
-    """Static mesh-axis size inside a shard_map body.
-
-    jax.lax.axis_size is a 0.6-era addition; on older jax the spelled-out
-    idiom psum(1, axis) folds to the same static int at trace time."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def repartition_by_hash(lanes: Sequence[Any], live: Any, hash_lane: Any,
                         quota: int) -> Tuple[List[Any], Any, Any]:
     """Hash-repartition rows over the mesh axis.
@@ -40,7 +30,7 @@ def repartition_by_hash(lanes: Sequence[Any], live: Any, hash_lane: Any,
     Returns (exchanged lanes [S*quota], exchanged live, overflow flag scalar).
     Row r goes to shard hash % S; each (src, dst) pair carries `quota` slots.
     """
-    ns = _axis_size(AXIS)
+    ns = jax.lax.axis_size(AXIS)
     n = live.shape[0]
     dest = (hash_lane % jnp.uint64(ns)).astype(jnp.int32)
     # dead rows: send nowhere (dest stays, live=False travels with them)

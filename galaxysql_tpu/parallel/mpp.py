@@ -21,14 +21,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.6 jax: shard_map lives in experimental, kw is check_rep
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=bool(check_vma))
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from galaxysql_tpu.chunk.batch import (Column, ColumnBatch, Dictionary,
@@ -41,6 +34,7 @@ from galaxysql_tpu.exec import skew
 from galaxysql_tpu.expr import ir
 from galaxysql_tpu.expr.compiler import ExprCompiler, _find_dictionary
 from galaxysql_tpu.kernels import relational as K
+from galaxysql_tpu.runtime import exec_platform
 from galaxysql_tpu.parallel import exchange
 from galaxysql_tpu.parallel.mesh import GLOBAL_MESH_CACHE
 from galaxysql_tpu.plan import logical as L
@@ -527,7 +521,7 @@ class MppExecutor:
 
     def _agg_round(self, groups, child, inputs, specs, merge_specs, G,
                    prelude=None):
-        key = ("mpp_agg", jax.default_backend(), K.kernel_selector_key(),
+        key = ("mpp_agg", exec_platform(), K.kernel_selector_key(),
                tuple((n, expr_cache_key(e)) for n, e in groups),
                tuple(expr_cache_key(e) for e in inputs), specs, G,
                child.replicated, self.S,
@@ -621,7 +615,7 @@ class MppExecutor:
 
     def _salted_agg_round(self, groups, child, inputs, specs, merge_specs,
                           G, factor, quota, prelude=None):
-        key = ("mpp_agg_salt", jax.default_backend(), K.kernel_selector_key(),
+        key = ("mpp_agg_salt", exec_platform(), K.kernel_selector_key(),
                tuple((n, expr_cache_key(e)) for n, e in groups),
                tuple(expr_cache_key(e) for e in inputs), specs, G, factor,
                self.S, quota,

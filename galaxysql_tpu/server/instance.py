@@ -27,6 +27,13 @@ from galaxysql_tpu.utils import errors
 
 class Instance:
     def __init__(self, data_dir: Optional[str] = None, boot: bool = True):
+        import jax
+        if not jax.config.jax_enable_x64:
+            # the package import enables it; someone switched it back off.
+            # int64 decimal/key lanes would silently truncate to 32 bits.
+            raise errors.TddlError(
+                "galaxysql_tpu needs jax_enable_x64 (64-bit decimal and key "
+                "lanes); it was disabled after `import galaxysql_tpu`")
         self.catalog = Catalog()
         self.stores: Dict[str, TableStore] = {}
         self.planner = Planner(self.catalog)
@@ -954,13 +961,12 @@ class Instance:
         return _Peer()
 
     def mesh(self):
-        """The instance's device mesh for MPP execution (None on a single device)."""
+        """The instance's device mesh for MPP execution (None on a single
+        device).  A backend that cannot start is the backend's error, not
+        "no mesh"."""
         if not hasattr(self, "_mesh"):
             import jax
-            try:
-                devs = jax.devices()
-            except RuntimeError:
-                devs = []
+            devs = jax.devices()
             if len(devs) > 1:
                 from galaxysql_tpu.parallel.mesh import make_mesh
                 self._mesh = make_mesh(devices=devs)

@@ -14,6 +14,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from galaxysql_tpu import runtime
 from galaxysql_tpu.chunk.batch import ColumnBatch, Dictionary
 from galaxysql_tpu.exec.operators import run_to_batch
 from galaxysql_tpu.expr import ir
@@ -64,16 +65,14 @@ _CPU_DEVICE = None
 
 
 def _cpu_device_ctx():
+    """Pin for the TP host path.  The CPU backend is part of every supported
+    start-up (`galaxysql_tpu/__init__` keeps it beside the accelerator); if it
+    is absent the backend's own error surfaces here — TP statements never
+    run on the accelerator unnoticed."""
     global _CPU_DEVICE
-    if _CPU_DEVICE is None:
-        import jax
-        try:
-            _CPU_DEVICE = jax.local_devices(backend="cpu")[0]
-        except RuntimeError:
-            _CPU_DEVICE = False
-    if _CPU_DEVICE is False:
-        return contextlib.nullcontext()
     import jax
+    if _CPU_DEVICE is None:
+        _CPU_DEVICE = jax.local_devices(backend="cpu")[0]
     return jax.default_device(_CPU_DEVICE)
 
 
@@ -1591,6 +1590,9 @@ class Session:
                 device_ctx = _cpu_device_ctx() \
                     if (plan.workload == "TP" or engine_hint == "TP") else _NULL_CTX
                 with device_ctx:
+                    # SHOW TRACE names where this statement's programs ran
+                    # (the accelerator, or the CPU device under the TP pin)
+                    ctx.trace.append(f"exec-device {runtime.exec_device()}")
                     batch = run_to_batch(op)
         prof.phases["execute"] = round((time.perf_counter() - x0) * 1000, 3)
         s0 = time.perf_counter()

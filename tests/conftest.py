@@ -15,7 +15,8 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_enable_x64", True)
+
+import galaxysql_tpu  # noqa: E402,F401 — the package import enables x64
 
 import pytest
 

@@ -8,6 +8,7 @@ wait on the per-key event and adopt its entry, keeping `_bytes` exact.
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -97,7 +98,7 @@ class TestEvictionAndVersioning:
         assert cache._bytes <= cache.budget
         assert len(cache._map) <= 3
         # the most recent key survived
-        assert (store.uid, 5, "c", 1, 1024) in cache._map
+        assert (store.uid, 5, "c", 1, 1024, jax.devices()[0]) in cache._map
 
     def test_version_bump_is_a_miss(self):
         cache = DeviceCache()
@@ -106,6 +107,34 @@ class TestEvictionAndVersioning:
         cache.get_lane(store, 0, "c", 1, lane)
         cache.get_lane(store, 0, "c", 2, lane)
         assert cache.misses == 2 and cache.hits == 0
+
+
+class TestDevicePlacement:
+    def test_pinned_lane_is_not_what_an_unpinned_scan_reads(self):
+        """A lane first loaded under a `jax.default_device` pin (the TP host
+        path pins the CPU device) is keyed by that device: the next unpinned
+        (AP) read of the same table version uploads its own copy to the
+        default device instead of adopting the pinned one."""
+        devs = jax.devices()
+        if len(devs) < 2:
+            pytest.skip("needs two devices to tell the placements apart")
+        cache = DeviceCache()
+        store = _Store()
+        builds = []
+
+        def builder():
+            builds.append(1)
+            return np.arange(64, dtype=np.int64)
+
+        with jax.default_device(devs[1]):
+            pinned = cache.get_lane_built(store, 0, "c", 1, 64, builder)
+            again = cache.get_lane_built(store, 0, "c", 1, 64, builder)
+        assert pinned.devices() == {devs[1]} and again is pinned
+        ap = cache.get_lane_built(store, 0, "c", 1, 64, builder)
+        assert ap.devices() == {devs[0]}
+        assert ap is not pinned and len(builds) == 2
+        assert cache.misses == 2 and cache.hits == 1
+        np.testing.assert_array_equal(np.asarray(ap), np.asarray(pinned))
 
 
 class TestMetrics:

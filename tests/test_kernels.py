@@ -410,6 +410,7 @@ class TestCompileCachePersistence:
         assert not GLOBAL_COMPILE_CACHE.attached
 
     def test_restart_round_trip_zero_steady_retraces(self, tmp_path):
+        fallbacks0 = GLOBAL_COMPILE_CACHE.call_fallbacks
         inst, s = _seed_instance(tmp_path / "db")
         rows = s.execute(QUERY).rows
         s.execute(QUERY)  # steady
@@ -424,6 +425,9 @@ class TestCompileCachePersistence:
         assert rows2 == rows
         assert ops.COMPILE_STATS["cache_hits"] > 0
         assert ops.COMPILE_STATS["retraces"] == 0
+        # every restored program accepted its call: none was loaded for the
+        # wrong devices and silently rebuilt (hit AND retrace)
+        assert GLOBAL_COMPILE_CACHE.call_fallbacks == fallbacks0
         # and the replayed programs stay steady
         ops.reset_compile_stats()
         s2.execute(QUERY)
