@@ -1,0 +1,12 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+SOURCE = "device_trace"
+LAYER = "device"
+MOVES = "tp_ops_per_s"
+UNIT = "%"
+
+
+def read(run):
+    if run.trace is None or "latencies_ms" not in run.window:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])
