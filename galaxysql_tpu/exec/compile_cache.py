@@ -264,6 +264,8 @@ class CompileCache:
             try:
                 def flat(*lv, _f=f, _td=treedef):
                     return _f(*jax.tree_util.tree_unflatten(_td, lv))
+                # a restored executable keeps its family's module name
+                flat.__name__ = flat.__qualname__ = ops.program_family(key)
 
                 # AOT path: lower the flat adapter against the recorded
                 # specs; the executable identity/caching stays in global_jit

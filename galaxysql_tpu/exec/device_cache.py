@@ -154,12 +154,16 @@ class DeviceCache:
                 self._push_metrics()
             return got
         try:
-            dev = jax.device_put(builder(), device)
+            from galaxysql_tpu.utils import tracing as _tr
+            tc = _tr.current()
+            # (a miss: off the hot path) the upload itself is a span in the
+            # profiler's trace while a session records this statement
+            table = getattr(getattr(store, "table", None), "name", "lanes")
+            with _tr.annotation(f"transfer:{table}", column=column):
+                dev = jax.device_put(builder(), device)
             nbytes = int(dev.nbytes)
             TRANSFER_STATS["bytes"] += nbytes
             TRANSFER_STATS["transfers"] += 1
-            from galaxysql_tpu.utils import tracing as _tr
-            tc = _tr.current()
             if tc is not None:
                 tc.event(f"h2d:{column}", kind="transfer", bytes=nbytes)
             with self._lock:

@@ -37,7 +37,6 @@ import os
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -261,7 +260,7 @@ class FusedSegment:
                 return out, live, xp.stack(counts)
 
             picked = run_stats if stats else run
-            return jax.jit(picked) if jit else picked
+            return ops.jit_program(picked) if jit else picked
         key = (backend, "stats" if stats else "prod") + self.key()
         # np-backend programs are plain closures — nothing to AOT-serialize,
         # so keep them out of the persistent compile cache's lookups
@@ -282,12 +281,13 @@ class FusedSegment:
         tc = _trace_ctx()
         timed = sink is not None or tc is not None or _tracer_on()
         t0 = time.perf_counter() if timed else 0.0
-        if sink is not None:
-            out, live2, counts = self._program(jit, stats=True)(
-                env, live, self.lits())
-        else:
-            counts = None
-            out, live2 = self._program(jit)(env, live, self.lits())
+        with _dispatch_annotation(tc, self.chain):
+            if sink is not None:
+                out, live2, counts = self._program(jit, stats=True)(
+                    env, live, self.lits())
+            else:
+                counts = None
+                out, live2 = self._program(jit)(env, live, self.lits())
         ops.DISPATCH_STATS["dispatches"] += 1
         if timed:
             wall = round((time.perf_counter() - t0) * 1000, 3)
@@ -347,23 +347,25 @@ class FusedSegment:
         timed = sink is not None or tc is not None or _tracer_on()
         t0 = time.perf_counter() if timed else 0.0
         counts = None
-        if host:
-            env = {n: (c.data, c.valid) for n, c in batch.columns.items()}
-            live_in = batch.live if batch.live is not None else \
-                np.ones(batch.capacity, np.bool_)
-            f = self._program(False, stats=sink is not None)
-            if sink is not None:
-                out, live, counts = f(env, live_in, self.lits())
+        with _dispatch_annotation(tc, self.chain):
+            if host:
+                env = {n: (c.data, c.valid) for n, c in batch.columns.items()}
+                live_in = batch.live if batch.live is not None else \
+                    np.ones(batch.capacity, np.bool_)
+                f = self._program(False, stats=sink is not None)
+                if sink is not None:
+                    out, live, counts = f(env, live_in, self.lits())
+                else:
+                    out, live = f(env, live_in, self.lits())
+                live = np.broadcast_to(np.asarray(live), (batch.capacity,))
             else:
-                out, live = f(env, live_in, self.lits())
-            live = np.broadcast_to(np.asarray(live), (batch.capacity,))
-        else:
-            f = self._program(True, stats=sink is not None)
-            if sink is not None:
-                out, live, counts = f(batch_env(batch), batch.live_mask(),
-                                      self.lits())
-            else:
-                out, live = f(batch_env(batch), batch.live_mask(), self.lits())
+                f = self._program(True, stats=sink is not None)
+                if sink is not None:
+                    out, live, counts = f(batch_env(batch), batch.live_mask(),
+                                          self.lits())
+                else:
+                    out, live = f(batch_env(batch), batch.live_mask(),
+                                  self.lits())
         ops.DISPATCH_STATS["dispatches"] += 1
         if timed:
             wall = round((time.perf_counter() - t0) * 1000, 3)
@@ -394,14 +396,22 @@ class FusedSegment:
 
 def _tracer_on() -> bool:
     from galaxysql_tpu.utils.tracing import SEGMENT_TRACER
-    # a query-scoped sink on this thread OR the legacy module-level ring
-    return SEGMENT_TRACER.active
+    return SEGMENT_TRACER.active  # a query-scoped sink on this thread
 
 
 def _trace_ctx():
     """The thread's active TraceContext (span tracing), or None."""
     from galaxysql_tpu.utils import tracing
     return tracing.current()
+
+
+def _dispatch_annotation(tc, chain: str):
+    """`segment:<chain>` around one dispatch while a profiler session records
+    the statement; nothing otherwise."""
+    from galaxysql_tpu.utils import tracing
+    if tc is None or not tc.annotate:
+        return tracing.NO_ANNOTATION
+    return tc.annotation("segment:" + chain)
 
 
 class FusedPipelineOp(ops.Operator):

@@ -89,6 +89,47 @@ def reset_compile_stats():
     COMPILE_MS_BY_PROGRAM.clear()
 
 
+def program_family(key) -> str:
+    """The family of a `global_jit` key: its first element (`join_pairs`,
+    `agg_partial`, `mpp_agg`, ...); fused segments, whose key starts with the
+    backend (exec/fusion.py), are `segment`.  Names hold the family only —
+    never a shape or a capacity — so a program keeps its name from run to run."""
+    head = key[0] if isinstance(key, tuple) and key else "program"
+    if head in ("jnp", "np"):
+        return "segment"
+    return str(head).replace("-", "_")
+
+
+_BUILDING = __import__("threading").local()
+
+
+def jit_program(fn, **jit_kwargs):
+    """What a `global_jit` builder calls in place of `jax.jit`: names the
+    function after the family of the key being built, so its HLO module reads
+    `jit_join_pairs` and not `jit_run` in a device profile, then jits it.  A
+    builder that returns two programs names both."""
+    family = getattr(_BUILDING, "family", None)
+    if family is None:
+        raise RuntimeError("jit_program called outside a global_jit builder")
+    fn.__name__ = fn.__qualname__ = family
+    return jax.jit(fn, **jit_kwargs)  # galaxylint: disable=jit-raw -- the one sanctioned jit: every builder comes through here
+
+
+def _family_scoped(key, builder):
+    """`builder` run with the key's family bound for `jit_program` (restored
+    after, since a builder may reach another `global_jit`)."""
+    family = program_family(key)
+
+    def scoped():
+        prev = getattr(_BUILDING, "family", None)
+        _BUILDING.family = family
+        try:
+            return builder()
+        finally:
+            _BUILDING.family = prev
+    return scoped
+
+
 def _timed_first_call(key, f, persist=True):
     """Wrap a freshly built program so its first invocation — where jax pays
     the synchronous trace+compile — is timed into COMPILE_STATS and, when a
@@ -98,21 +139,31 @@ def _timed_first_call(key, f, persist=True):
     holding the wrapper degrade to a single cell-load per call."""
     import time as _t
     cell = [None]
+    family = program_family(key)
 
     def wrapper(*a, **k):
         inner = cell[0]
         if inner is not None:
             return inner(*a, **k)
+        from galaxysql_tpu.utils import tracing as _tr
+        tc = _tr.current()
+        # while a profiler session records the statement, the trace+compile
+        # is a real span in both trees; otherwise an event after the fact
+        sp = tc.begin(f"compile:{family}", kind="compile") \
+            if tc is not None and tc.annotate else None
         t0 = _t.perf_counter()
-        out = f(*a, **k)
+        try:
+            out = f(*a, **k)
+        finally:
+            if sp is not None:
+                tc.end(sp)
         dt_ms = (_t.perf_counter() - t0) * 1000.0
         cell[0] = f
         with _JIT_CACHE_LOCK:
             if _JIT_CACHE.get(key) is wrapper:
                 _JIT_CACHE[key] = f
         COMPILE_STATS["compile_ms"] += dt_ms
-        head = key[0] if isinstance(key, tuple) and key else "program"
-        fam = COMPILE_MS_BY_PROGRAM.setdefault(str(head), [0, 0.0])
+        fam = COMPILE_MS_BY_PROGRAM.setdefault(family, [0, 0.0])
         fam[0] += 1
         fam[1] += dt_ms
         if persist and not k:
@@ -120,10 +171,10 @@ def _timed_first_call(key, f, persist=True):
             # this program into the persistent compile cache (no-op detached)
             from galaxysql_tpu.exec import compile_cache as _cc
             _cc.GLOBAL_COMPILE_CACHE.observe(key, f, a, k)
-        from galaxysql_tpu.utils import tracing as _tr
-        tc = _tr.current()
-        if tc is not None:
-            tc.event(f"compile:{head}", kind="compile",
+        if sp is not None:
+            sp.attrs["wall_ms"] = round(dt_ms, 3)
+        elif tc is not None:
+            tc.event(f"compile:{family}", kind="compile",
                      wall_ms=round(dt_ms, 3))
         return out
 
@@ -155,6 +206,7 @@ def global_jit(key: Tuple, builder, built_flag=None, persist=True):
         if f is not None:
             _JIT_CACHE.move_to_end(key)
             return f
+    builder = _family_scoped(key, builder)
     if persist:
         from galaxysql_tpu.exec import compile_cache as _cc
         g = _cc.GLOBAL_COMPILE_CACHE
@@ -276,7 +328,7 @@ def _batched_point_program(B: int, cap: int, maxdup: int, dtype_str: str):
             dele = ((e >= 0) & (e <= snap)) | (e == -txn)
             vis = in_rng & ins & ~dele
             return jnp.where(vis, posc, -1), (hi - lo) > maxdup
-        return jax.jit(prog)
+        return jit_program(prog)
     return global_jit(("batch_point", dtype_str, B, cap, maxdup), build)
 
 
@@ -544,7 +596,7 @@ class FilterOp(Operator):
                 # make them XLA outputs, copying every lane (50MB/column at
                 # SF1) — the caller reattaches the ORIGINAL column buffers
                 return batch.live_mask() & pred(env)
-            return jax.jit(run)
+            return jit_program(run)
         key = ("filter", tkeys if tkeys is not None
                else expr_cache_key(self.predicate))
         return global_jit(key, build), (lift.values() if lift is not None else ())
@@ -616,7 +668,7 @@ class ProjectOp(Operator):
                     data, valid = broadcast_value(n, *f(env))
                     cols[name] = Column(data, valid, e.dtype, _find_dictionary(e))
                 return ColumnBatch(cols, batch.live)
-            return jax.jit(run)
+            return jit_program(run)
         if tkeys is not None:
             key = ("project", tuple(n for n, _ in self.exprs), tkeys)
         else:
@@ -811,7 +863,7 @@ class HashAggOp(Operator):
                 # small and static, hash (CPU) / lexsort (TPU) otherwise
                 return K.groupby(keys, ins, specs, live, max_groups,
                                  domains)
-            return jax.jit(run)
+            return jit_program(run)
         return global_jit(key, build)
 
     def _merge_fn(self, max_groups: int, n_keys: int, lane_names: Tuple[str, ...],
@@ -825,7 +877,7 @@ class HashAggOp(Operator):
             def run(key_lanes, input_lanes, live):
                 return K.groupby(key_lanes, input_lanes, merge_specs, live,
                                  max_groups)
-            return jax.jit(run)
+            return jit_program(run)
         return global_jit(key, build)
 
     # -- execution ---------------------------------------------------------
@@ -1185,7 +1237,7 @@ class HashJoinOp(Operator):
                 pkeys = [f(penv) for f in pk]
                 return K.hash_join_pairs(bkeys, pkeys, build.live_mask(),
                                          plive, cap)
-            return jax.jit(run)
+            return jit_program(run)
         return global_jit(key, build_fn)
 
     def _csr_host(self, build_batch: ColumnBatch):
@@ -1208,7 +1260,7 @@ class HashJoinOp(Operator):
                 benv = batch_env(build)
                 bkeys = [f(benv) for f in bk]
                 return K.hash_join_build_slots(bkeys, build.live_mask(), M)
-            return jax.jit(run)
+            return jit_program(run)
         s_b = np.asarray(global_jit(key, build_fn)(build_batch))
         perm = np.argsort(s_b, kind="stable").astype(np.int32)
         counts = np.bincount(s_b, minlength=M + 1)[:M].astype(np.int32)
@@ -1239,7 +1291,7 @@ class HashJoinOp(Operator):
                 return K.hash_join_probe_csr(bkeys, pkeys, build.live_mask(),
                                              plive, perm,
                                              slot_starts, slot_counts, M, cap)
-            return jax.jit(run)
+            return jit_program(run)
         return global_jit(key, build_fn)
 
     BLOOM_MAX_BUILD = 1 << 20
@@ -1271,16 +1323,27 @@ class HashJoinOp(Operator):
             nwords *= 2
         words = native.bloom_build(keys, nwords)
         words_dev = jnp.asarray(words)
+        # both keys: a string probe key is translated into the build key's
+        # dictionary by the compiled `pf`
+        key = ("bloom_query", nwords, expr_cache_key(be),
+               expr_cache_key(self.probe_keys[0]))
+
+        def build():
+            def run(batch: ColumnBatch, words):
+                # the MASK only: lanes passed out of a jit would be copied
+                pd, pv = pf(batch_env(batch))
+                pd, _ = broadcast_value(batch.capacity, pd, None)
+                live2 = batch.live_mask() & K.bloom_query_device(
+                    pd.astype(jnp.int64), words)
+                if pv is not None:
+                    # NULL keys never match an inner/semi join anyway
+                    live2 = live2 & pv
+                return live2
+            return jit_program(run)
+        query = global_jit(key, build)
 
         def apply(batch: ColumnBatch) -> ColumnBatch:
-            env = batch_env(batch)
-            pd, pv = pf(env)
-            hit = K.bloom_query_device(pd.astype(jnp.int64), words_dev)
-            live2 = batch.live_mask() & hit
-            if pv is not None:
-                # NULL keys never match an inner/semi join anyway
-                live2 = live2 & pv
-            return ColumnBatch(batch.columns, live2)
+            return ColumnBatch(batch.columns, query(batch, words_dev))
         return apply
 
     BLOOM_DEVICE_MAX_BITS = 1 << 24
@@ -1337,7 +1400,7 @@ class HashJoinOp(Operator):
                     live2 = live2 & pv
                 return ColumnBatch(batch.columns, live2)
 
-            return jax.jit(build_flags), jax.jit(query, static_argnums=())
+            return jit_program(build_flags), jit_program(query)
         build_flags, query = global_jit(key, build_fns)
         flags = build_flags(build_batch)
 
@@ -1612,11 +1675,29 @@ class HashJoinOp(Operator):
 
     @staticmethod
     def _gather(batch: ColumnBatch, idx, live) -> Dict[str, Column]:
+        """One side's payload lanes at the pair indices.  The lanes that live
+        on the device go through one named program (eager `lane[idx]` is six
+        unnamed dispatches a lane); a host lane (a compacted build side) is
+        gathered where it lives, by numpy, and never uploaded for it."""
+        lanes = {n: (c.data, c.valid) for n, c in batch.columns.items()
+                 if isinstance(c.data, jax.Array)}
+        out = {}
+        if lanes:
+            key = ("join_gather", batch.capacity, int(idx.shape[0]),
+                   tuple((n, str(d.dtype), v is not None)
+                         for n, (d, v) in lanes.items()))
+
+            def build():
+                def run(lanes, idx):
+                    return {n: (d[idx], None if v is None else v[idx])
+                            for n, (d, v) in lanes.items()}
+                return jit_program(run)
+            out = global_jit(key, build)(lanes, idx)
         cols = {}
-        for name, c in batch.columns.items():
-            data = c.data[idx]
-            valid = c.valid[idx] if c.valid is not None else None
-            cols[name] = Column(data, valid, c.dtype, c.dictionary)
+        for n, c in batch.columns.items():
+            data, valid = out[n] if n in out else (
+                c.data[idx], None if c.valid is None else c.valid[idx])
+            cols[n] = Column(data, valid, c.dtype, c.dictionary)
         return cols
 
     # -- fragment cache (exec/fragment_cache) --------------------------------
@@ -1982,7 +2063,7 @@ class SortOp(Operator):
                 elif offset:
                     live = K.limit_mask(live, offset, batch.capacity)
                 return ColumnBatch(cols, live)
-            return jax.jit(run)
+            return jit_program(run)
         return global_jit(key, build)
 
     def batches(self) -> Iterator[ColumnBatch]:
@@ -2283,7 +2364,7 @@ class WindowOp(Operator):
                                         c.valid[order] if c.valid is not None
                                         else None, c.dtype, c.dictionary)
                 return cols, live_s, outs
-            return jax.jit(run)
+            return jit_program(run)
 
         cols, live_s, outs = global_jit(key, build)(padded)
         yield self.finalize_calls(cols, live_s, outs, lanes)

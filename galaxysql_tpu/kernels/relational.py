@@ -186,84 +186,87 @@ def sort_groupby(keys: Sequence[Tuple[Any, Optional[Any]]],
         else:
             key_lanes.append(data)
 
-    # lexsort: last key is primary => (minor..major); dead rows pushed to the end
-    order = jnp.lexsort(tuple(reversed([dead.astype(jnp.int8)] + key_lanes))) \
-        if key_lanes else jnp.argsort(dead.astype(jnp.int8), stable=True)
-    live_s = live[order]
-    sorted_lanes = [k[order] for k in key_lanes]
+    with jax.named_scope("groupby/sort"):
+        # lexsort: last key is primary => (minor..major); dead rows pushed to the end
+        order = jnp.lexsort(tuple(reversed([dead.astype(jnp.int8)] + key_lanes))) \
+            if key_lanes else jnp.argsort(dead.astype(jnp.int8), stable=True)
+        live_s = live[order]
+        sorted_lanes = [k[order] for k in key_lanes]
 
-    if sorted_lanes:
-        prev_differs = jnp.zeros(n, dtype=jnp.bool_)
-        for lane in sorted_lanes:
-            prev_differs = prev_differs | jnp.concatenate(
-                [jnp.ones(1, dtype=jnp.bool_), lane[1:] != lane[:-1]])
-        new_group = prev_differs & live_s
-        new_group = new_group.at[0].set(live_s[0])
-    else:
-        new_group = jnp.zeros(n, dtype=jnp.bool_).at[0].set(live_s[0])
+    with jax.named_scope("groupby/boundaries"):
+        if sorted_lanes:
+            prev_differs = jnp.zeros(n, dtype=jnp.bool_)
+            for lane in sorted_lanes:
+                prev_differs = prev_differs | jnp.concatenate(
+                    [jnp.ones(1, dtype=jnp.bool_), lane[1:] != lane[:-1]])
+            new_group = prev_differs & live_s
+            new_group = new_group.at[0].set(live_s[0])
+        else:
+            new_group = jnp.zeros(n, dtype=jnp.bool_).at[0].set(live_s[0])
 
-    num_groups = jnp.sum(new_group.astype(jnp.int32))
-    overflow = num_groups > max_groups
+        num_groups = jnp.sum(new_group.astype(jnp.int32))
+        overflow = num_groups > max_groups
 
-    # run starts: positions of new_group, padded with n (a virtual end sentinel)
-    (starts_raw,) = jnp.nonzero(new_group, size=max_groups + 1, fill_value=n)
-    starts = starts_raw[:max_groups]                # [G] start row of group g
-    ends = starts_raw[1:max_groups + 1]             # [G] start of the next group
-    # dead rows sort to the end, so group g covers sorted rows [starts[g], ends[g]);
-    # the LAST live group's end is the count of live rows, not n
-    n_live = jnp.sum(live_s.astype(jnp.int32))
-    ends = jnp.minimum(ends, n_live)
-    gvalid = starts < n_live                               # real group slots
-    starts_c = jnp.clip(starts, 0, max(n - 1, 0))
+        # run starts: positions of new_group, padded with n (a virtual end sentinel)
+        (starts_raw,) = jnp.nonzero(new_group, size=max_groups + 1, fill_value=n)
+        starts = starts_raw[:max_groups]                # [G] start row of group g
+        ends = starts_raw[1:max_groups + 1]             # [G] start of the next group
+        # dead rows sort to the end, so group g covers sorted rows [starts[g], ends[g]);
+        # the LAST live group's end is the count of live rows, not n
+        n_live = jnp.sum(live_s.astype(jnp.int32))
+        ends = jnp.minimum(ends, n_live)
+        gvalid = starts < n_live                               # real group slots
+        starts_c = jnp.clip(starts, 0, max(n - 1, 0))
 
     def run_reduce_sum(masked):
         c = jnp.cumsum(masked, axis=0)
         c0 = jnp.concatenate([jnp.zeros(1, dtype=c.dtype), c])
         return c0[ends] - c0[starts_c]
 
-    out_keys = []
-    out_key_valid = []
-    for data, valid in keys:
-        out_keys.append(data[order][starts_c])
-        out_key_valid.append(None if valid is None else valid[order][starts_c])
+    with jax.named_scope("groupby/reduce"):
+        out_keys = []
+        out_key_valid = []
+        for data, valid in keys:
+            out_keys.append(data[order][starts_c])
+            out_key_valid.append(None if valid is None else valid[order][starts_c])
 
-    out_aggs: List[Tuple[Any, Any]] = []
-    for spec in specs:
-        if spec.kind == "count_star":
-            cnt = run_reduce_sum(live_s.astype(jnp.int64))
-            out_aggs.append((cnt, None))
-            continue
-        data, valid = inputs[spec.arg]
-        d_s = data[order]
-        v_s = valid[order] if valid is not None else None
-        present = live_s if v_s is None else (live_s & v_s)
-        if spec.kind == "count":
-            out_aggs.append((run_reduce_sum(present.astype(jnp.int64)), None))
-        elif spec.kind in ("sum", "sum_float"):
-            if jnp.issubdtype(d_s.dtype, jnp.floating):
-                masked = jnp.where(present, d_s, jnp.zeros((), dtype=d_s.dtype))
+        out_aggs: List[Tuple[Any, Any]] = []
+        for spec in specs:
+            if spec.kind == "count_star":
+                cnt = run_reduce_sum(live_s.astype(jnp.int64))
+                out_aggs.append((cnt, None))
+                continue
+            data, valid = inputs[spec.arg]
+            d_s = data[order]
+            v_s = valid[order] if valid is not None else None
+            present = live_s if v_s is None else (live_s & v_s)
+            if spec.kind == "count":
+                out_aggs.append((run_reduce_sum(present.astype(jnp.int64)), None))
+            elif spec.kind in ("sum", "sum_float"):
+                if jnp.issubdtype(d_s.dtype, jnp.floating):
+                    masked = jnp.where(present, d_s, jnp.zeros((), dtype=d_s.dtype))
+                else:
+                    masked = jnp.where(present, d_s.astype(jnp.int64), 0)
+                s = run_reduce_sum(masked)
+                nonempty = run_reduce_sum(present.astype(jnp.int32)) > 0
+                out_aggs.append((s, nonempty))
+            elif spec.kind in ("min", "max"):
+                if jnp.issubdtype(d_s.dtype, jnp.floating):
+                    neutral = jnp.array(np.inf if spec.kind == "min" else -np.inf,
+                                        d_s.dtype)
+                else:
+                    info = jnp.iinfo(d_s.dtype)
+                    neutral = jnp.array(info.max if spec.kind == "min" else info.min,
+                                        d_s.dtype)
+                masked = jnp.where(present, d_s, neutral)
+                # segmented running min/max restarting at each run boundary; the last
+                # element of each run then holds the run's reduction
+                m = _segmented_scan(masked, new_group, spec.kind == "min")
+                last = jnp.clip(ends - 1, 0, max(n - 1, 0))
+                nonempty = run_reduce_sum(present.astype(jnp.int32)) > 0
+                out_aggs.append((m[last], nonempty))
             else:
-                masked = jnp.where(present, d_s.astype(jnp.int64), 0)
-            s = run_reduce_sum(masked)
-            nonempty = run_reduce_sum(present.astype(jnp.int32)) > 0
-            out_aggs.append((s, nonempty))
-        elif spec.kind in ("min", "max"):
-            if jnp.issubdtype(d_s.dtype, jnp.floating):
-                neutral = jnp.array(np.inf if spec.kind == "min" else -np.inf,
-                                    d_s.dtype)
-            else:
-                info = jnp.iinfo(d_s.dtype)
-                neutral = jnp.array(info.max if spec.kind == "min" else info.min,
-                                    d_s.dtype)
-            masked = jnp.where(present, d_s, neutral)
-            # segmented running min/max restarting at each run boundary; the last
-            # element of each run then holds the run's reduction
-            m = _segmented_scan(masked, new_group, spec.kind == "min")
-            last = jnp.clip(ends - 1, 0, max(n - 1, 0))
-            nonempty = run_reduce_sum(present.astype(jnp.int32)) > 0
-            out_aggs.append((m[last], nonempty))
-        else:
-            raise ValueError(f"unknown agg kind {spec.kind}")
+                raise ValueError(f"unknown agg kind {spec.kind}")
 
     out_live = gvalid & (jnp.arange(max_groups, dtype=jnp.int32) <
                          jnp.minimum(num_groups, max_groups))
@@ -751,42 +754,46 @@ def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
     nb = build_keys[0][0].shape[0]
     npr = probe_keys[0][0].shape[0]
 
-    h_b = hash_columns(build_keys)
-    # dead build rows get a sentinel hash sorted to the end and never matched
-    h_b = jnp.where(b_live, h_b, jnp.uint64(0xffffffffffffffff))
-    perm = jnp.argsort(h_b)
-    h_sorted = h_b[perm]
+    with jax.named_scope("join_pairs/sort"):
+        h_b = hash_columns(build_keys)
+        # dead build rows get a sentinel hash sorted to the end and never matched
+        h_b = jnp.where(b_live, h_b, jnp.uint64(0xffffffffffffffff))
+        perm = jnp.argsort(h_b)
+        h_sorted = h_b[perm]
 
-    h_p = hash_columns(probe_keys)
-    left = jnp.searchsorted(h_sorted, h_p, side="left")
-    right = jnp.searchsorted(h_sorted, h_p, side="right")
-    counts = jnp.where(p_live, (right - left).astype(jnp.int64), 0)
+    with jax.named_scope("join_pairs/probe"):
+        h_p = hash_columns(probe_keys)
+        left = jnp.searchsorted(h_sorted, h_p, side="left")
+        right = jnp.searchsorted(h_sorted, h_p, side="right")
+        counts = jnp.where(p_live, (right - left).astype(jnp.int64), 0)
 
-    offsets = jnp.cumsum(counts)
-    total = offsets[-1] if npr else jnp.int64(0)
-    overflow = total > cap
-    starts = offsets - counts
+        offsets = jnp.cumsum(counts)
+        total = offsets[-1] if npr else jnp.int64(0)
+        overflow = total > cap
+        starts = offsets - counts
 
-    # ragged expansion: slot j -> probe row p, k-th candidate
-    slots = jnp.arange(cap, dtype=jnp.int64)
-    p_of = jnp.searchsorted(offsets, slots, side="right").astype(jnp.int32)
-    p_of = jnp.clip(p_of, 0, max(npr - 1, 0))
-    k = slots - starts[p_of]
-    pair_live = slots < jnp.minimum(total, cap)
-    bpos = jnp.clip(left[p_of] + k.astype(jnp.int32), 0, max(nb - 1, 0))
-    b_of = perm[bpos].astype(jnp.int32)
+    with jax.named_scope("join_pairs/expand"):
+        # ragged expansion: slot j -> probe row p, k-th candidate
+        slots = jnp.arange(cap, dtype=jnp.int64)
+        p_of = jnp.searchsorted(offsets, slots, side="right").astype(jnp.int32)
+        p_of = jnp.clip(p_of, 0, max(npr - 1, 0))
+        k = slots - starts[p_of]
+        pair_live = slots < jnp.minimum(total, cap)
+        bpos = jnp.clip(left[p_of] + k.astype(jnp.int32), 0, max(nb - 1, 0))
+        b_of = perm[bpos].astype(jnp.int32)
 
-    # verify candidate pairs on the actual key lanes (hash collisions filtered here)
-    verified = pair_live
-    for (bd, bv), (pd, pv) in zip(build_keys, probe_keys):
-        eq = bd[b_of] == pd[p_of]
-        verified = verified & eq
-    verified = verified & b_live[b_of] & p_live[p_of]
+    with jax.named_scope("join_pairs/verify"):
+        # verify candidate pairs on the actual key lanes (hash collisions filtered here)
+        verified = pair_live
+        for (bd, bv), (pd, pv) in zip(build_keys, probe_keys):
+            eq = bd[b_of] == pd[p_of]
+            verified = verified & eq
+        verified = verified & b_live[b_of] & p_live[p_of]
 
-    # pair slots are ordered by probe row, so per-probe-row "any verified" is a
-    # prefix-sum range query — no scatter (TPU scatters serialize)
-    probe_matched = probe_matched_from(verified, starts, offsets) \
-        if npr else jnp.zeros(0, jnp.bool_)
+        # pair slots are ordered by probe row, so per-probe-row "any verified" is a
+        # prefix-sum range query — no scatter (TPU scatters serialize)
+        probe_matched = probe_matched_from(verified, starts, offsets) \
+            if npr else jnp.zeros(0, jnp.bool_)
 
     return JoinPairs(b_of, p_of, verified, probe_matched, starts, offsets, overflow)
 
@@ -985,8 +992,9 @@ def sort_indices(keys: Sequence[Tuple[Any, Optional[Any], bool, bool]],
             zero = jnp.zeros((), dtype=lane.dtype)
             lane = jnp.where(valid, lane, zero)
         lanes.append(lane)
-    dead = (~live).astype(jnp.int8)
-    order = jnp.lexsort(tuple(reversed([dead] + lanes)))
+    with jax.named_scope("sort/lexsort"):
+        dead = (~live).astype(jnp.int8)
+        order = jnp.lexsort(tuple(reversed([dead] + lanes)))
     return order
 
 

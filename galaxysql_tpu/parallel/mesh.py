@@ -72,7 +72,9 @@ class MeshDataCache:
             got = self._map.get(key)
             if got is not None:
                 return got
-        st = _load_sharded(store, mesh, columns, snapshot_ts, txn_id)
+        from galaxysql_tpu.utils import tracing
+        with tracing.annotation(f"transfer:{table.name}"):
+            st = _load_sharded(store, mesh, columns, snapshot_ts, txn_id)
         with self._lock:
             if len(self._map) > 64:
                 self._map.clear()

@@ -29,7 +29,7 @@ from galaxysql_tpu.chunk.batch import (Column, ColumnBatch, Dictionary,
 from galaxysql_tpu.exec.operators import (DISPATCH_STATS, AggCall, HashAggOp,
                                           SortOp, SourceOp, broadcast_value,
                                           bucket_capacity, expr_cache_key,
-                                          global_jit)
+                                          global_jit, jit_program)
 from galaxysql_tpu.exec import skew
 from galaxysql_tpu.expr import ir
 from galaxysql_tpu.expr.compiler import ExprCompiler, _find_dictionary
@@ -206,6 +206,12 @@ class MppExecutor:
                 tc.add(f"shard{si}", kind="shard", parent=sp.span_id,
                        start_us=sp.start_us, dur_us=sp.dur_us,
                        shard=si, rows=int(rn))
+                if tc.annotate:
+                    # one SPMD program ran all shards: the chips' own planes
+                    # hold each shard's time, this marker its row count
+                    with tc.annotation(f"shard:{si}", rows=int(rn),
+                                       stage=sp.name):
+                        pass
             ratio = _shard_skew_ratio(per_shard)
             if ratio is not None:
                 # skew = max/mean live rows per shard: 1.0 is perfectly
@@ -414,7 +420,7 @@ class MppExecutor:
 
         def build():
             pred = ExprCompiler(jnp).compile_predicate(node.cond)
-            return jax.jit(lambda env, live: live & pred(env))
+            return jit_program(lambda env, live: live & pred(env))
         DISPATCH_STATS["dispatches"] += 1
         live = global_jit(key, build)(child.env(), child.live)
         return DistBatch(child.columns, live, child.replicated)
@@ -437,7 +443,7 @@ class MppExecutor:
                         v = jnp.broadcast_to(v, live.shape)
                     out[name] = (d, v)
                 return out
-            return jax.jit(run)
+            return jit_program(run)
         DISPATCH_STATS["dispatches"] += 1
         out = global_jit(key, build)(child.env(), child.live)
         cols = {name: Column(out[name][0], out[name][1], e.dtype, _find_dictionary(e))
@@ -543,7 +549,7 @@ class MppExecutor:
                 def run_rep(env, live, plits):
                     r = local_partial(env, live, plits)
                     return r, r.overflow
-                return jax.jit(run_rep)
+                return jit_program(run_rep)
 
             def spmd(env, live, plits):
                 r = local_partial(env, live, plits)
@@ -568,7 +574,7 @@ class MppExecutor:
 
             fn = shard_map(spmd, mesh=self.mesh, in_specs=(SHARD, SHARD, REP),
                            out_specs=(REP, REP), check_vma=False)
-            return jax.jit(fn)
+            return jit_program(fn)
 
         plits = prelude.lits() if prelude is not None else ()
         DISPATCH_STATS["dispatches"] += 1
@@ -670,7 +676,7 @@ class MppExecutor:
 
             fn = shard_map(spmd, mesh=self.mesh, in_specs=(SHARD, SHARD, REP),
                            out_specs=(REP, REP), check_vma=False)
-            return jax.jit(fn)
+            return jit_program(fn)
 
         plits = prelude.lits() if prelude is not None else ()
         DISPATCH_STATS["dispatches"] += 1
@@ -849,7 +855,7 @@ class MppExecutor:
                             REP if build_rep else SHARD, SHARD, SHARD)
                 fn = shard_map(spmd, mesh=self.mesh, in_specs=in_specs,
                                out_specs=(SHARD, REP), check_vma=False)
-                return jax.jit(fn)
+                return jit_program(fn)
 
             out, over = global_jit(key, builder)(build.env(), build.live,
                                                  probe.env(), probe.live)
@@ -907,7 +913,7 @@ class MppExecutor:
                 fn = shard_map(spmd, mesh=self.mesh,
                                in_specs=(SHARD, SHARD, SHARD, SHARD),
                                out_specs=(SHARD, REP), check_vma=False)
-                return jax.jit(fn)
+                return jit_program(fn)
 
             out, flags = global_jit(key, builder)(build.env(), build.live,
                                                   probe.env(), probe.live)
@@ -1113,7 +1119,7 @@ class MppExecutor:
                 fn = shard_map(spmd, mesh=self.mesh,
                                in_specs=(SHARD, SHARD, SHARD, SHARD, REP, REP),
                                out_specs=(SHARD, REP), check_vma=False)
-                return jax.jit(fn)
+                return jit_program(fn)
 
             out, flags = global_jit(key, builder)(
                 build.env(), build.live, probe.env(), probe.live,
@@ -1226,7 +1232,7 @@ class MppExecutor:
                 fn = shard_map(spmd, mesh=self.mesh, in_specs=(SHARD, SHARD),
                                out_specs=((SHARD, SHARD, SHARD), REP),
                                check_vma=False)
-                return jax.jit(fn)
+                return jit_program(fn)
 
             (cols, live_s, outs), over = global_jit(key, builder)(child.env(),
                                                                   child.live)
@@ -1328,7 +1334,7 @@ class MppExecutor:
             n = len(batches)
             fn = shard_map(spmd, mesh=self.mesh, in_specs=(SHARD,) * (2 * n),
                            out_specs=(SHARD, SHARD), check_vma=False)
-            return jax.jit(fn)
+            return jit_program(fn)
 
         flat = []
         for b in batches:
@@ -1377,11 +1383,11 @@ class MppExecutor:
                 return out, live
 
             if left.replicated:
-                return jax.jit(block)
+                return jit_program(block)
             fn = shard_map(block, mesh=self.mesh,
                            in_specs=(SHARD, SHARD, REP, REP),
                            out_specs=(SHARD, SHARD), check_vma=False)
-            return jax.jit(fn)
+            return jit_program(fn)
 
         renv = {i: (jnp.asarray(c.np_data()),
                     None if c.valid is None else jnp.asarray(c.np_valid()))
@@ -1443,7 +1449,7 @@ class MppExecutor:
 
             fn = shard_map(spmd, mesh=self.mesh, in_specs=(SHARD, SHARD),
                            out_specs=(SHARD, SHARD), check_vma=False)
-            return jax.jit(fn)
+            return jit_program(fn)
 
         cols_o, live = global_jit(key, builder)(child.env(), child.live)
         cols = {i: Column(cols_o[i][0], cols_o[i][1], c.dtype, c.dictionary)

@@ -656,14 +656,17 @@ class TraceOp(ops.Operator):
         t0 = _t.perf_counter()
         batches = 0
         it = self.inner.batches()
+        # a profiler session records: each pull is also an `op:` annotation
+        # (nested like the operators), so a device idle gap names its operator
+        op_name = "op:" + sp.name if tc.annotate else None
         while True:
             prev = tc.cursor
             tc.cursor = sp.span_id
             try:
-                try:
+                with tc.annotation(op_name) if op_name else _tr.NO_ANNOTATION:
                     b = next(it)
-                except StopIteration:
-                    break
+            except StopIteration:
+                break
             finally:
                 tc.cursor = prev
             batches += 1

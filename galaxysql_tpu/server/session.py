@@ -15,7 +15,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from galaxysql_tpu import runtime
-from galaxysql_tpu.chunk.batch import ColumnBatch, Dictionary
+from galaxysql_tpu.chunk.batch import ColumnBatch, Dictionary, concat_batches
 from galaxysql_tpu.exec.operators import run_to_batch
 from galaxysql_tpu.expr import ir
 from galaxysql_tpu.expr.compiler import ExprCompiler, _find_dictionary
@@ -195,6 +195,9 @@ class Session:
         self.user = "root"
         self.last_trace: List[str] = []
         self.last_spans: List[Any] = []  # last traced query's span tree
+        # the CURRENT statement's TraceContext while a jax.profiler session
+        # records (its phase ramps become profiler annotations); else None
+        self._ann = None
         # router trace hint for the CURRENT statement: (trace_id, parent
         # span id, origin node, sampled) parsed off the statement prefix by
         # _execute_one; None for locally-originated statements
@@ -940,11 +943,22 @@ class Session:
         _pc = time.perf_counter
         # read-your-writes: this session's own async GSI/replica applies must
         # land before its reads (one int compare when nothing is pending)
+        trace_id = self.instance.trace_ids.next()
+        # a jax.profiler session is recording: this statement's spans also go
+        # into the profiler's trace (utils/tracing.py).  The one check a
+        # statement pays while none is.
+        annotate = tracing.device_trace_active() and self._tracing_enabled()
         f0 = _pc()
-        self._apply_fence()
+        if annotate:
+            if self._trace_hint is not None:
+                trace_id = self._trace_hint[0]  # adopted below
+            with tracing.phase_annotation("fence_wait", trace_id):
+                self._apply_fence()
+        else:
+            self._apply_fence()
         fence_ms = (_pc() - f0) * 1000.0
         t0 = time.time()
-        prof = tracing.QueryProfile(trace_id=self.instance.trace_ids.next(),
+        prof = tracing.QueryProfile(trace_id=trace_id,
                                     sql=(sql or "<stmt>")[:512], schema=schema,
                                     conn_id=self.conn_id, started_at=t0)
         if fence_ms >= 0.05:  # steady state: fence is one int compare
@@ -982,13 +996,18 @@ class Session:
                 prof.sampled = store is not None and \
                     store.sampler.decide(self._digest_of(sql, schema))
                 # explicit session opt-in (SET ENABLE_QUERY_TRACING=1)
-                # always builds the full tree: that's SHOW TRACE debugging
-                full = prof.sampled or \
+                # always builds the full tree: that's SHOW TRACE debugging;
+                # so does a recording profiler session, whose trace would
+                # otherwise hold the programs and not the statement
+                full = prof.sampled or annotate or \
                     bool(self.vars.get("ENABLE_QUERY_TRACING"))
             if full:
                 tc = tracing.TraceContext(prof.trace_id,
-                                          node=self.instance.node_id)
+                                          node=self.instance.node_id,
+                                          annotate=annotate)
                 prof.spans = tc.spans  # alias: ring sees spans as they land
+                if annotate:
+                    self._ann = tc  # the phase ramps below enter spans on it
             else:
                 self.last_spans = []
         else:
@@ -1002,7 +1021,9 @@ class Session:
         try:
             a0 = _pc()
             try:
-                ticket = self.instance.admission.admit(self, sql or "")
+                with tc.annotation("phase:admission") if annotate \
+                        else _NULL_CTX:
+                    ticket = self.instance.admission.admit(self, sql or "")
             finally:
                 # shed queries keep their partial attribution: an admission
                 # timeout's wait lands in the phases dict BEFORE the typed
@@ -1010,7 +1031,8 @@ class Session:
                 prof.phases["admission"] = round((_pc() - a0) * 1000, 3)
             q0 = _pc()
             try:
-                admission = GLOBAL_CCL.admit(self, sql or "")
+                with tc.annotation("phase:queue") if annotate else _NULL_CTX:
+                    admission = GLOBAL_CCL.admit(self, sql or "")
             finally:
                 prof.phases["queue"] = round((_pc() - q0) * 1000, 3)
             if tc is None:
@@ -1039,6 +1061,8 @@ class Session:
             self._record_query_error(sql, t0, prof, e, tc)
             raise
         finally:
+            if annotate:
+                self._ann = None
             if admission is not None:
                 admission.release()
             if ticket is not None:
@@ -1128,19 +1152,21 @@ class Session:
 
     def _run_query_admitted(self, stmt, sql, params, schema, t0,
                             prof) -> ResultSet:
+        if sql and self.instance.point_plans:
+            rs = self._try_point_exec(sql, params, schema, t0, prof)
+            if rs is not None:
+                return rs
+        ann = self._ann
+        ph = ann.begin("plan", "phase") if ann is not None else None
+        p0 = time.perf_counter()
         if sql:
-            if self.instance.point_plans:
-                rs = self._try_point_exec(sql, params, schema, t0, prof)
-                if rs is not None:
-                    return rs
-            p0 = time.perf_counter()
             plan = self.instance.planner.plan_select(sql, schema, params, self)
-            prof.phases["plan"] = round((time.perf_counter() - p0) * 1000, 3)
         else:
-            p0 = time.perf_counter()
             plan = self.instance.planner.bind_statement(stmt, schema, params or [],
                                                         self)
-            prof.phases["plan"] = round((time.perf_counter() - p0) * 1000, 3)
+        prof.phases["plan"] = round((time.perf_counter() - p0) * 1000, 3)
+        if ph is not None:
+            ann.end(ph)
         if stmt is None:
             # SELECT hot path skipped the raw parse; authorize on the plan's
             # (parameterized) AST — same table names, no second parse
@@ -1422,6 +1448,8 @@ class Session:
         if self.instance.archive.files_for(inst_key, None):
             return None  # cold rows live outside the index: full path
         key_col = pp["key_col"]
+        ann = self._ann
+        ph = ann.begin("execute", "phase") if ann is not None else None
         x0 = time.perf_counter()
         if value is None:
             rows = []  # eq NULL matches nothing
@@ -1429,12 +1457,16 @@ class Session:
             from galaxysql_tpu.plan.rules import _lane_encode
             lane_val = _lane_encode(tm, key_col, value)
             if lane_val is None:
+                if ph is not None:
+                    ann.end(ph)
                 return None
             # cross-session batching: coalesce with other sessions executing
             # this same parameterized statement (returns None -> run solo)
             brs = self._try_batched_point(pp, p, lane_val, sql, t0, prof,
                                           schema)
             if brs is not None:
+                if ph is not None:
+                    ann.end(ph)
                 return brs
             from galaxysql_tpu.meta.catalog import PartitionRouter
             # route in LANE domain: hash routing on insert keys off the lane
@@ -1472,6 +1504,8 @@ class Session:
                             out_cols.append(c.to_pylist())
                     rows.extend(zip(*out_cols))
         prof.phases["execute"] = round((time.perf_counter() - x0) * 1000, 3)
+        if ph is not None:
+            ann.end(ph)
         elapsed = time.time() - t0
         self.last_trace = [f"trace-id {prof.trace_id}",
                            f"point-plan {pp['table']}.{key_col}",
@@ -1578,6 +1612,8 @@ class Session:
         span_scope = SEGMENT_TRACER.scoped(prof.segments) \
             if ctx.collect_stats else contextlib.nullcontext()
         engine_hint = getattr(plan, "hints", {}).get("engine")
+        ann = self._ann
+        ph = ann.begin("execute", "phase") if ann is not None else None
         x0 = time.perf_counter()
         with span_scope:
             batch = self._try_mpp(plan, ctx, count=True)
@@ -1593,12 +1629,24 @@ class Session:
                     # SHOW TRACE names where this statement's programs ran
                     # (the accelerator, or the CPU device under the TP pin)
                     ctx.trace.append(f"exec-device {runtime.exec_device()}")
-                    batch = run_to_batch(op)
+                    if ann is None:
+                        batch = run_to_batch(op)
+                    else:
+                        # the result's lanes come to the host one D2H read
+                        # each: a span of its own in the profiler's trace
+                        parts = list(op.batches())
+                        with ann.annotation("transfer:result"):
+                            batch = concat_batches(parts)
         prof.phases["execute"] = round((time.perf_counter() - x0) * 1000, 3)
+        if ph is not None:
+            ann.end(ph)
+            ph = ann.begin("serialize", "phase")
         s0 = time.perf_counter()
         batch = batch.compact()
         rows = batch.to_pylist()
         prof.phases["serialize"] = round((time.perf_counter() - s0) * 1000, 3)
+        if ph is not None:
+            ann.end(ph)
         fields = plan.fields()
         if plan.workload == "TP":
             self._register_point_plan(plan, batch)

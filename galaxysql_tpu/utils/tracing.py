@@ -18,6 +18,14 @@ ring.  `GALAXYSQL_TRACING=0` (read once at import) or
 `ENABLE_QUERY_TRACING=false` restores the old fully-opt-in behaviour: with
 collection off, `current()` returns None and no code path allocates a span,
 times a dispatch, or syncs a device.
+
+While a `jax.profiler` session is recording (`device_trace_active()`), every
+statement gets the full tree and its spans are ALSO entered as
+`jax.profiler.TraceAnnotation`s (`phase:plan`, `op:Join`, `segment:<chain>`,
+`compile:<family>`, `transfer:<table>`, `stage:`/`shard:` on the mesh), each
+carrying the statement's `trace_id`, so the program's spans sit on the device
+profile's own clock beside the XLA modules they launched.  With no session
+recording, that one check is all a statement pays.
 """
 
 from __future__ import annotations
@@ -31,6 +39,8 @@ import threading
 import time
 import zlib
 from typing import Any, Deque, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 # Emergency hatch (same trio convention as GALAXYSQL_PALLAS / _COLUMNAR):
 # env kills always-on collection process-wide, read once at import so the
@@ -128,19 +138,12 @@ class SegmentTracer:
     them; these spans keep them observable.
 
     Off by default: rows in/out force a device sync per batch, which the hot
-    path must never pay.  Two ways to enable:
+    path must never pay.  `scoped(sink)` enables it: a context manager binding
+    a per-query sink on the calling thread, so spans from concurrent sessions
+    land in their own QueryProfile."""
 
-    - `scoped(sink)` (preferred): a context manager binding a per-query sink on
-      the calling thread, so spans from concurrent sessions land in their own
-      QueryProfile instead of interleaving in one shared ring.
-    - `enabled = True`: the legacy module-level ring fallback (spans from every
-      thread without an active scope share `_ring`)."""
-
-    def __init__(self, capacity: int = 1024):
-        self._ring: Deque[SegmentSpan] = collections.deque(maxlen=capacity)
-        self._lock = threading.Lock()
+    def __init__(self):
         self._local = threading.local()
-        self.enabled = False
 
     def _sink(self) -> Optional[list]:
         return getattr(self._local, "sink", None)
@@ -148,8 +151,8 @@ class SegmentTracer:
     @property
     def active(self) -> bool:
         """True when spans should be recorded on this thread (a scoped sink is
-        bound, or the global ring is enabled)."""
-        return self.enabled or self._sink() is not None
+        bound)."""
+        return self._sink() is not None
 
     @contextlib.contextmanager
     def scoped(self, sink: Optional[list] = None):
@@ -169,23 +172,41 @@ class SegmentTracer:
         sink = self._sink()
         if sink is not None:
             sink.append(span)
-            return
-        with self._lock:
-            self._ring.append(span)
-
-    def spans(self) -> List[SegmentSpan]:
-        with self._lock:
-            return list(self._ring)
-
-    def clear(self):
-        with self._lock:
-            self._ring.clear()
 
 
 SEGMENT_TRACER = SegmentTracer()
 
 
 # -- hierarchical span tracing -------------------------------------------------
+
+
+def device_trace_active() -> bool:
+    """True while a `jax.profiler` session records host annotations — a
+    `start_trace` in this process or a capture taken through
+    `jax.profiler.start_server`.  The ONE place that asks JAX (the profiler's
+    own TraceMe switch, one C call); everything else reads
+    `TraceContext.annotate`, fixed once at the statement's entry."""
+    return _TraceAnnotation.is_enabled()
+
+
+def phase_annotation(name: str, trace_id: int) -> _TraceAnnotation:
+    """`phase:<name>` for a ramp that runs before the statement has its
+    TraceContext (the read-your-writes fence)."""
+    return _TraceAnnotation("phase:" + name, trace_id=trace_id)
+
+
+def _annotation_name(name: str, kind: str) -> str:
+    """A span's name in the profiler's trace: `op:<RelNode class>`,
+    `stage:<RelNode class>`, `phase:<name>`; names that already carry their
+    kind (`segment:<chain>`, `compile:<family>`, `rpc:<op>`) and the root
+    (`query`) stay as they are."""
+    if kind == "operator":
+        return "op:" + name
+    if kind == "stage":
+        return "stage:" + name.removeprefix("mpp:")
+    if ":" in name or name == kind:
+        return name
+    return f"{kind}:{name}"
 
 
 def now_us() -> int:
@@ -231,13 +252,17 @@ class TraceContext:
     with begin/end or the `span()` context manager; leaf recorders (segment
     dispatches, compile events, cache transfers) just read it."""
 
-    def __init__(self, trace_id: int, node: str = ""):
+    def __init__(self, trace_id: int, node: str = "", annotate: bool = False):
         self.trace_id = trace_id
         self.node = node
         self.spans: List[Span] = []
         self._ids = itertools.count(1)
         self._lock = threading.Lock()
         self.cursor = 0  # current parent span id (0 = attach to root/none)
+        # a profiler session was recording when the statement entered:
+        # begin/end (and the leaf sites that ask) also enter annotations
+        self.annotate = annotate
+        self._open: List[Span] = []  # annotated spans not yet ended
 
     # -- span construction ---------------------------------------------------
 
@@ -264,6 +289,10 @@ class TraceContext:
         """Open a span and move the cursor under it (manual form; pair with
         `end`)."""
         sp = self.add(name, kind, **attrs)
+        if self.annotate:
+            sp._ann = self.annotation(_annotation_name(name, kind))
+            sp._ann.__enter__()
+            self._open.append(sp)
         sp._t0 = time.perf_counter()
         sp._prev_cursor = self.cursor
         self.cursor = sp.span_id
@@ -272,6 +301,20 @@ class TraceContext:
     def end(self, sp: Span):
         sp.dur_us = round((time.perf_counter() - sp._t0) * 1e6, 1)
         self.cursor = sp._prev_cursor
+        if self.annotate and sp._ann is not None:
+            # a raise may have skipped the end of spans opened under this
+            # one (a phase, a stage): the profiler's tree closes them here
+            while self._open:
+                inner = self._open.pop()
+                inner._ann.__exit__(None, None, None)
+                inner._ann = None
+                if inner is sp:
+                    break
+
+    def annotation(self, name: str, **args) -> _TraceAnnotation:
+        """A profiler annotation carrying this statement's trace id (enter it
+        with `with`).  Only for callers that have checked `annotate`."""
+        return _TraceAnnotation(name, trace_id=self.trace_id, **args)
 
     @contextlib.contextmanager
     def span(self, name: str, kind: str, **attrs):
@@ -384,6 +427,19 @@ def activate(tc: Optional[TraceContext]):
         yield tc
     finally:
         _ACTIVE.trace = prev
+
+
+NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **args):
+    """Context manager for leaf sites off the hot path (a lane upload, a
+    sharded load): `name` entered in the profiler's trace under the thread's
+    active statement while a session records it, nothing otherwise."""
+    tc = current()
+    if tc is None or not tc.annotate:
+        return NO_ANNOTATION
+    return tc.annotation(name, **args)
 
 
 def swap_active(tc: Optional[TraceContext]) -> Optional[TraceContext]:

@@ -304,14 +304,9 @@ class TestSegmentTracing:
         b = sample_batch(200, device=True)
         pred, projs = seg_filter_project(b, lim=77)
         seg = FusedSegment([("filter", pred), ("project", projs)])
-        SEGMENT_TRACER.clear()
-        SEGMENT_TRACER.enabled = True
-        try:
+        with SEGMENT_TRACER.scoped() as spans:
             seg.run_batch(b)
             seg.run_batch(b)
-        finally:
-            SEGMENT_TRACER.enabled = False
-        spans = SEGMENT_TRACER.spans()
         assert len(spans) == 2
         s0, s1 = spans
         assert s0.chain == "filter>project"

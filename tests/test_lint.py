@@ -128,17 +128,50 @@ class TestJitRules:
 
     def test_builder_closure_clean(self):
         fs = L.lint_source(
-            "import jax\n"
             "def op(key):\n"
             "    def build():\n"
             "        def run(x):\n"
             "            return x\n"
-            "        return jax.jit(run)\n"
+            "        return jit_program(run)\n"
             "    return global_jit(key, build)\n"
             "def op2(key):\n"
-            "    return global_jit(key, lambda: jax.jit(lambda x: x))\n",
+            "    return global_jit(key, lambda: ops.jit_program(lambda x: x))\n",
             "galaxysql_tpu/exec/x.py")
         assert rules_of(fs) == []
+
+    @pytest.mark.parametrize("jit,where", [
+        ("jax.jit", "build"),          # bare jit in a builder: module jit_run
+        ("jit_program", "elsewhere"),  # the helper outside a builder
+        ("ops.jit_program", "elsewhere"),
+    ])
+    def test_unnamed_or_escaped_program_flagged(self, jit, where):
+        fs = L.lint_source(
+            "import jax\n"
+            "def op(key):\n"
+            f"    def {where}():\n"
+            "        def run(x):\n"
+            "            return x\n"
+            f"        return {jit}(run)\n"
+            "    return global_jit(key, build)\n",
+            "galaxysql_tpu/exec/x.py")
+        assert rules_of(fs) == ["jit-raw"]
+
+    @pytest.mark.parametrize("key,rules", [
+        ('("join_pairs", cap)', []),
+        ('("filter-np",) + rest', []),
+        ('(backend, "prod") + rest', []),      # a fused segment's key
+        ('(family_of(x), cap)', ["jit-family"]),
+        ('make_key(x)', ["jit-family"]),
+    ])
+    def test_key_family_readable_from_source(self, key, rules):
+        fs = L.lint_source(
+            "def op(x, cap, rest, backend):\n"
+            f"    key = {key}\n"
+            "    def build():\n"
+            "        return jit_program(lambda a: a)\n"
+            "    return global_jit(key, build)\n",
+            "galaxysql_tpu/exec/x.py")
+        assert rules_of(fs) == rules
 
     def test_raw_pallas_call_flagged(self):
         fs = L.lint_source(
@@ -466,7 +499,8 @@ class TestTreeClean:
     def test_rules_registered(self):
         rules = {r for ck in ALL_CHECKERS for r in ck.rules}
         assert rules == {"lock-order", "lock-blocking", "jit-raw",
-                         "pallas-raw", "jit-device-sync", "swallow",
+                         "jit-family", "pallas-raw", "jit-device-sync",
+                         "swallow",
                          "untyped-raise", "dead-failpoint", "metric-orphan",
                          "event-untested", "histogram-unsampled",
                          "event-uncorrelated"}

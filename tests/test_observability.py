@@ -288,7 +288,7 @@ class TestSqlSurfaces:
 class TestScopedSegmentTracer:
     def test_two_sessions_do_not_interleave(self):
         """Two sessions profiling concurrently: each QueryProfile holds only
-        its own segment spans (the global-ring fallback would interleave)."""
+        its own segment spans."""
         inst = Instance()
         s0 = Session(inst)
         s0.execute("CREATE DATABASE il")
@@ -302,7 +302,6 @@ class TestScopedSegmentTracer:
             {"a": list(range(700)), "b": list(range(700))},
             inst.tso.next_timestamp())
 
-        ring_before = len(SEGMENT_TRACER.spans())
         results = {}
         barrier = threading.Barrier(2)
 
@@ -329,23 +328,23 @@ class TestScopedSegmentTracer:
                 # every span in this query's profile is from ITS table
                 assert all(sp.rows_out == expect for sp in p.segments), (
                     name, [(sp.chain, sp.rows_out) for sp in p.segments])
-        # scoped sinks bypass the module-level ring entirely
-        assert len(SEGMENT_TRACER.spans()) == ring_before
 
-    def test_global_ring_fallback_still_works(self):
-        SEGMENT_TRACER.clear()
-        SEGMENT_TRACER.enabled = True
-        try:
-            b = ColumnBatch({"a": Column(jnp.arange(2048), None,
-                                         dt.BIGINT, None)}, None)
-            seg = FusedSegment([("filter",
-                                 ir.call("lt", ir.ColRef("a", dt.BIGINT, None),
-                                         ir.lit(100)))])
+    def test_scopes_nest_and_unscoped_dispatch_records_nothing(self):
+        b = ColumnBatch({"a": Column(jnp.arange(2048), None,
+                                     dt.BIGINT, None)}, None)
+        seg = FusedSegment([("filter",
+                             ir.call("lt", ir.ColRef("a", dt.BIGINT, None),
+                                     ir.lit(100)))])
+        with SEGMENT_TRACER.scoped() as outer:
             seg.run_batch(b)
-        finally:
-            SEGMENT_TRACER.enabled = False
-        assert SEGMENT_TRACER.spans(), "unscoped spans land in the ring"
-        SEGMENT_TRACER.clear()
+            with SEGMENT_TRACER.scoped() as inner:
+                seg.run_batch(b)
+                seg.run_batch(b)
+            seg.run_batch(b)  # the previous sink is restored on exit
+        assert (len(outer), len(inner)) == (2, 2)
+        assert not SEGMENT_TRACER.active
+        seg.run_batch(b)  # no scope: no span, no device sync
+        assert (len(outer), len(inner)) == (2, 2)
 
 
 # -- web console --------------------------------------------------------------
