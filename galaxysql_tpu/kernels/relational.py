@@ -9,11 +9,16 @@ TPU-friendly primitives:
   boundaries are detected by comparing adjacent rows, and aggregates are `jax.ops.segment_*`
   reductions.  The reference's sort-based fallback for huge-NDV aggs (`SpillableAggHashMap`)
   is here the *primary* strategy because sort is what the hardware does well.
-- **hash join = hash + sort + searchsorted probe.**  The build side is sorted by a 64-bit key
-  hash; probes binary-search the sorted hash lane; every candidate pair is then verified
-  against the actual key columns, so hash collisions cost duplicates-filtered work, never
-  correctness.  This is the flat-array open-addressing idea of `ConcurrentRawHashTable`
-  (Appendix A) re-expressed without scatter contention.
+- **hash join = hash + sort + directory probe.**  The build side is sorted by a 64-bit key
+  hash.  A probe reads its bucket's bounds from a prefix directory over the hashes' top bits,
+  finishes with a binary search inside the bucket (3-6 levels on uniform hashes, as many as
+  the widest bucket needs) and takes the range's end from the run lengths of the sorted
+  lane; every candidate pair is then verified against the actual key columns, so hash
+  collisions cost duplicates-filtered work, never correctness.  This is the flat-array
+  open-addressing idea of `ConcurrentRawHashTable` (Appendix A) re-expressed without
+  scatter contention.  (Two `searchsorted` passes over the whole lane, which this replaced,
+  were 6.14 of Q3's 9.30 s on a v5e: a uint64 lane is two uint32 lanes there, and each of a
+  search's 18-22 levels a dependent gather of both over every probe slot; PERF.md, PR 26.)
 
 All kernels are fixed-shape: output capacity is a static argument and kernels report
 `overflow` so the host can re-bucket and retry (the dynamic-shape escape hatch, SURVEY.md
@@ -660,7 +665,9 @@ def prefer_scatter() -> bool:
     """Kernel-formulation choice is a backend property: XLA:CPU lowers scatters
     to fast native loops but its comparator sorts are single-threaded (measured
     1.3s to lexsort 1.2M rows vs ~10ms for a segment_sum); TPU is the inverse
-    (scatters serialize, bitonic sorts + MXU matmuls are fast).  Asks where
+    (scatters serialize, bitonic sorts + MXU matmuls are fast; gathers are
+    its other slow thing, about 54-116M elements a second on a v5e, which is
+    why the TPU join counts its gather passes: PERF.md, PR 26).  Asks where
     the program being traced will RUN, not what the default backend is: a
     program under the TP path's CPU pin gets the CPU formulation."""
     return exec_platform() == "cpu"
@@ -717,6 +724,9 @@ class JoinPairs(NamedTuple):
     probe_starts: Any   # [n_probe] int64 — first pair slot of each probe row
     probe_offsets: Any  # [n_probe] int64 — end pair slot of each probe row
     overflow: Any       # scalar bool
+    # scalar int32 — levels the range search ran (sorted formulation only;
+    # read against `full_search_depth(nb)`)
+    search_levels: Any = None
 
 
 def _effective_live(keys, live):
@@ -736,10 +746,13 @@ def hash_join_pairs(build_keys: Sequence[Tuple[Any, Optional[Any]]],
 
     NULL join keys never match (SQL semantics): rows with any NULL key are masked out of
     both sides before hashing.  Backend-adaptive: the TPU formulation sorts the
-    build hashes and binary-searches them (sorts vectorize, scatters serialize);
-    the CPU formulation buckets the build side into a slot-table CSR and probes
-    by direct gather (XLA:CPU searchsorted costs ~200ms per 1.2M probes — 18
-    full gather passes — while scatters are native loops)."""
+    build hashes and finds each probe's range through a prefix directory, a
+    bounded search and run lengths (`_probe_ranges`; sorts vectorize, scatters
+    serialize, and a gather pass over 6.3M probe slots costs 54-117 ms on a
+    v5e, so the passes are what it saves: 7-10 where two whole-lane searches
+    made 36-44); the CPU formulation buckets the build side into a slot-table CSR
+    and probes by direct gather (XLA:CPU searchsorted costs ~200ms per 1.2M
+    probes — 18 full gather passes — while scatters are native loops)."""
     if prefer_scatter():
         return _hash_join_pairs_table(build_keys, probe_keys, build_live,
                                       probe_live, cap)
@@ -747,28 +760,117 @@ def hash_join_pairs(build_keys: Sequence[Tuple[Any, Optional[Any]]],
                                    probe_live, cap)
 
 
+# hash given to dead build rows: sorts past every live one and matches no probe,
+# because live hashes are held to the value below it (two live hashes made equal
+# there are one more collision for `verify`)
+_DEAD_HASH = np.uint64(0xffffffffffffffff)
+_TOP_LIVE_HASH = np.uint64(0xfffffffffffffffe)
+
+
+def full_search_depth(nb: int) -> int:
+    """Levels a binary search over a whole `nb`-slot lane runs (what
+    `jnp.searchsorted` pays for every query): the depth `search_levels` of
+    `JoinPairs` is read against."""
+    return int(nb).bit_length()
+
+
+def _run_ends(h_sorted):
+    """For every slot of a sorted lane, one past the last slot of its run of
+    equal values: a reverse running minimum over the run boundaries, by
+    doubling strides, that stops once every slot has seen its boundary —
+    `bit_length(longest run - 1)` passes, none when the values are unique.
+    (`lax.cummin` gives the same lane; its reduce-window took the chip's
+    compiler 33-40 s at 163,840 and 229,376 slots, this loop about 1 s.)"""
+    nb = h_sorted.shape[0]
+    unseen = jnp.int32(nb + 1)
+    last_of_run = jnp.concatenate(
+        [h_sorted[1:] != h_sorted[:-1], jnp.ones(1, jnp.bool_)])
+    ends = jnp.where(last_of_run, jnp.arange(1, nb + 1, dtype=jnp.int32), unseen)
+    beyond = jnp.full(nb, unseen)
+
+    def look_ahead(state):
+        stride, ends = state
+        ahead = jax.lax.dynamic_slice(jnp.concatenate([ends, beyond]),
+                                      (stride,), (nb,))
+        return stride * 2, jnp.minimum(ends, ahead)
+
+    return jax.lax.while_loop(lambda state: jnp.any(state[1] == unseen),
+                              look_ahead, (jnp.int32(1), ends))[1]
+
+
+def _probe_ranges(h_sorted, h_p):
+    """Range of equal hashes in the sorted build lane for every probe hash:
+    `(left, run, levels)`, `left` as `searchsorted(h_sorted, h_p, "left")`
+    gives it and `run` the number of build slots that hold `h_p`.
+
+    Live build hashes are uniform 64-bit values, so their top K bits say
+    within a few slots where a hash sorts.  A prefix directory over those bits
+    (`dir[b]` = first slot whose bucket is >= b; dead rows take bucket 2^K, so
+    `dir[2^K]` is the live count and padding is never searched) bounds a
+    binary search whose trip count, `bit_length(widest bucket)`, is a device
+    scalar read off the directory: 3-6 levels on uniform hashes, the full
+    depth only when the build side is one hot key.  The range's end needs no
+    second search: the run of equal hashes that starts at `left` ends where
+    `_run_ends` says."""
+    nb = h_sorted.shape[0]
+    k_bits = max(nb.bit_length() - 4, 1)  # 8-16 slots a bucket when all are live
+    shift = jnp.uint64(64 - k_bits)
+    # 2^K + 1 full-depth queries, where every probe slot made two
+    bounds = jnp.concatenate([jnp.arange(1 << k_bits, dtype=jnp.uint64) << shift,
+                              jnp.full(1, _DEAD_HASH)])
+    directory = jnp.searchsorted(h_sorted, bounds, side="left").astype(jnp.int32)
+    widest = jnp.max(directory[1:] - directory[:-1])
+    levels = (32 - jax.lax.clz(widest)).astype(jnp.int32)  # its bit_length
+
+    b_p = (h_p >> shift).astype(jnp.int32)
+    lo, hi = directory[b_p], directory[b_p + 1]
+
+    def level(_, lo_hi):
+        lo, hi = lo_hi
+        mid = lo + ((hi - lo) >> 1)
+        below = h_sorted[jnp.minimum(mid, nb - 1)] < h_p
+        open_ = lo < hi
+        return (jnp.where(open_ & below, mid + 1, lo),
+                jnp.where(open_ & ~below, mid, hi))
+
+    left, _ = jax.lax.fori_loop(0, levels, level, (lo, hi))
+
+    run_end = _run_ends(h_sorted)
+    at = jnp.minimum(left, nb - 1)  # left == nb: every slot is below h_p
+    run = jnp.where(h_sorted[at] == h_p, run_end[at] - left, 0)
+    return left, run, levels
+
+
 def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
                             cap: int) -> JoinPairs:
+    """TPU join: sort the build hashes, find every probe hash's range of
+    candidates (`_probe_ranges`), expand the ranges into `cap` pair slots,
+    verify the pairs on the key lanes."""
     b_live = _effective_live(build_keys, build_live)
     p_live = _effective_live(probe_keys, probe_live)
     nb = build_keys[0][0].shape[0]
     npr = probe_keys[0][0].shape[0]
+    if nb == 0 or npr == 0:  # nothing to gather from: no candidate, no pair
+        none = jnp.zeros(cap, jnp.int32)
+        ends = jnp.zeros(npr, jnp.int64)
+        return JoinPairs(none, none, jnp.zeros(cap, jnp.bool_),
+                         jnp.zeros(npr, jnp.bool_), ends, ends,
+                         jnp.bool_(False), jnp.int32(0))
 
     with jax.named_scope("join_pairs/sort"):
-        h_b = hash_columns(build_keys)
+        h_b = jnp.minimum(hash_columns(build_keys), _TOP_LIVE_HASH)
         # dead build rows get a sentinel hash sorted to the end and never matched
-        h_b = jnp.where(b_live, h_b, jnp.uint64(0xffffffffffffffff))
+        h_b = jnp.where(b_live, h_b, _DEAD_HASH)
         perm = jnp.argsort(h_b)
         h_sorted = h_b[perm]
 
     with jax.named_scope("join_pairs/probe"):
-        h_p = hash_columns(probe_keys)
-        left = jnp.searchsorted(h_sorted, h_p, side="left")
-        right = jnp.searchsorted(h_sorted, h_p, side="right")
-        counts = jnp.where(p_live, (right - left).astype(jnp.int64), 0)
+        h_p = jnp.minimum(hash_columns(probe_keys), _TOP_LIVE_HASH)
+        left, run, levels = _probe_ranges(h_sorted, h_p)
+        counts = jnp.where(p_live, run.astype(jnp.int64), 0)
 
         offsets = jnp.cumsum(counts)
-        total = offsets[-1] if npr else jnp.int64(0)
+        total = offsets[-1]
         overflow = total > cap
         starts = offsets - counts
 
@@ -776,10 +878,10 @@ def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
         # ragged expansion: slot j -> probe row p, k-th candidate
         slots = jnp.arange(cap, dtype=jnp.int64)
         p_of = jnp.searchsorted(offsets, slots, side="right").astype(jnp.int32)
-        p_of = jnp.clip(p_of, 0, max(npr - 1, 0))
+        p_of = jnp.clip(p_of, 0, npr - 1)
         k = slots - starts[p_of]
         pair_live = slots < jnp.minimum(total, cap)
-        bpos = jnp.clip(left[p_of] + k.astype(jnp.int32), 0, max(nb - 1, 0))
+        bpos = jnp.clip(left[p_of] + k.astype(jnp.int32), 0, nb - 1)
         b_of = perm[bpos].astype(jnp.int32)
 
     with jax.named_scope("join_pairs/verify"):
@@ -792,10 +894,10 @@ def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
 
         # pair slots are ordered by probe row, so per-probe-row "any verified" is a
         # prefix-sum range query — no scatter (TPU scatters serialize)
-        probe_matched = probe_matched_from(verified, starts, offsets) \
-            if npr else jnp.zeros(0, jnp.bool_)
+        probe_matched = probe_matched_from(verified, starts, offsets)
 
-    return JoinPairs(b_of, p_of, verified, probe_matched, starts, offsets, overflow)
+    return JoinPairs(b_of, p_of, verified, probe_matched, starts, offsets,
+                     overflow, levels)
 
 
 def _device_csr(build_keys, build_live, nb: int):

@@ -327,6 +327,13 @@ class TraceContext:
         finally:
             self.end(sp)
 
+    def span_at_cursor(self) -> Optional[Span]:
+        """The span leaf recorders are attaching under, for a recorder that
+        has an attribute for it and no span of its own."""
+        with self._lock:
+            i = self.cursor - 1  # ids count from 1, in append order
+            return self.spans[i] if 0 <= i < len(self.spans) else None
+
     @property
     def root_id(self) -> int:
         return self.spans[0].span_id if self.spans else 0

@@ -1,0 +1,352 @@
+"""The TPU join's range lookup (`K._probe_ranges`: prefix directory, bounded
+search, run lengths) against a NumPy oracle that searches the whole sorted
+build lane twice, as the formulation did before: every leaf of `JoinPairs` equal,
+the depth it reports, and the same function under `shard_map`.
+
+The sorted formulation is called directly: on this backend `hash_join_pairs`
+picks the slot-table one."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from galaxysql_tpu.kernels import relational as K
+
+DEAD = np.uint64(0xffffffffffffffff)
+
+
+def _np_live(keys, live):
+    m = np.asarray(live)
+    for _, v in keys:
+        if v is not None:
+            m = m & np.asarray(v)
+    return m
+
+
+def oracle(build_keys, probe_keys, build_live, probe_live, cap) -> K.JoinPairs:
+    """Two full-depth searches of the stably sorted build hashes, in NumPy."""
+    b_live, p_live = _np_live(build_keys, build_live), _np_live(probe_keys, probe_live)
+    nb, npr = b_live.shape[0], p_live.shape[0]
+    if nb == 0 or npr == 0:  # no candidate: every slot dead and zero
+        none, ends = np.zeros(cap, np.int32), np.zeros(npr, np.int64)
+        return K.JoinPairs(none, none, np.zeros(cap, bool), np.zeros(npr, bool),
+                           ends, ends, np.bool_(False), np.int32(0))
+    top = DEAD - np.uint64(1)
+    h_b = np.where(b_live, np.minimum(np.asarray(K.hash_columns(build_keys)), top), DEAD)
+    h_p = np.minimum(np.asarray(K.hash_columns(probe_keys)), top)
+    perm = np.argsort(h_b, kind="stable")
+    h_sorted = h_b[perm]
+    left = np.searchsorted(h_sorted, h_p, side="left")
+    right = np.searchsorted(h_sorted, h_p, side="right")
+    counts = np.where(p_live, right - left, 0).astype(np.int64)
+    offsets = np.cumsum(counts)
+    total = offsets[-1]
+    starts = offsets - counts
+    slots = np.arange(cap, dtype=np.int64)
+    p_of = np.clip(np.searchsorted(offsets, slots, side="right"), 0,
+                   npr - 1).astype(np.int32)
+    k = slots - np.asarray(starts)[p_of]
+    pair_live = slots < min(total, cap)
+    bpos = np.clip(np.asarray(left)[p_of].astype(np.int32) + k.astype(np.int32), 0,
+                   nb - 1)
+    b_of = np.asarray(perm)[bpos].astype(np.int32)
+    verified = pair_live & np.asarray(b_live)[b_of] & np.asarray(p_live)[p_of]
+    for (bd, _), (pd, _) in zip(build_keys, probe_keys):
+        verified = verified & (np.asarray(bd)[b_of] == np.asarray(pd)[p_of])
+    c = np.concatenate([[0], np.cumsum(verified)])
+    matched = (c[np.clip(offsets, 0, cap)] - c[np.clip(starts, 0, cap)]) > 0
+    widest = _widest_bucket(h_sorted, nb)
+    return K.JoinPairs(b_of, p_of, verified, matched, starts, offsets,
+                       np.bool_(total > cap), np.int32(int(widest).bit_length()))
+
+
+def _widest_bucket(h_sorted, nb):
+    """Most live hashes that share their top `bit_length(nb) - 4` bits (one bit
+    at least)."""
+    live = h_sorted[h_sorted != DEAD]
+    k_bits = max(int(nb).bit_length() - 4, 1)
+    if not live.size:
+        return 0
+    return np.bincount((live >> np.uint64(64 - k_bits)).astype(np.int64)).max()
+
+
+def _lane(rng, n, ndv, null_share=0.0, dtype=np.int64):
+    data = jnp.asarray(rng.integers(0, ndv, n).astype(dtype))
+    if not null_share:
+        return data, None
+    return data, jnp.asarray(rng.random(n) >= null_share)
+
+
+def _unique(rng):
+    nb, npr = 4096, 10_000
+    bk = jnp.asarray(rng.permutation(nb).astype(np.int64))
+    return [(bk, None)], [_lane(rng, npr, 2 * nb)], np.ones(nb, bool), \
+        rng.random(npr) > 0.1, 1 << 14
+
+
+def _duplicates(rng):
+    nb, npr = 2048, 5000
+    return [_lane(rng, nb, 40)], [_lane(rng, npr, 60)], rng.random(nb) > 0.2, \
+        rng.random(npr) > 0.2, 1 << 18
+
+
+def _sparse(rng):
+    # what a build side gathered out of an upstream join looks like
+    nb, npr = 1 << 16, 20_000
+    return [_lane(rng, nb, 1 << 30)], [_lane(rng, npr, 1 << 30)], \
+        rng.random(nb) < 0.05, np.ones(npr, bool), 1 << 15
+
+
+def _sparse_matching(rng):
+    nb, npr = 1 << 16, 20_000
+    return [_lane(rng, nb, 3000)], [_lane(rng, npr, 3000)], \
+        rng.random(nb) < 0.05, rng.random(npr) > 0.5, 1 << 16
+
+
+def _nulls(rng):
+    nb, npr = 1024, 3000
+    return [_lane(rng, nb, 300, 0.3)], [_lane(rng, npr, 300, 0.3)], \
+        rng.random(nb) > 0.1, rng.random(npr) > 0.1, 1 << 15
+
+
+def _all_dead(rng):
+    nb, npr = 512, 700
+    return [_lane(rng, nb, 50)], [_lane(rng, npr, 50)], np.zeros(nb, bool), \
+        np.ones(npr, bool), 1 << 10
+
+
+def _hot_key(rng):
+    nb, npr = 1000, 64
+    return [(jnp.full(nb, 7, jnp.int64), None)], [_lane(rng, npr, 9)], \
+        np.ones(nb, bool), np.ones(npr, bool), 1 << 15
+
+
+def _two_columns(rng):
+    nb, npr = 3000, 9000
+    return [_lane(rng, nb, 30, 0.05), _lane(rng, nb, 20, dtype=np.int32)], \
+        [_lane(rng, npr, 30), _lane(rng, npr, 20, 0.05, dtype=np.int32)], \
+        rng.random(nb) > 0.1, rng.random(npr) > 0.1, 1 << 17
+
+
+def _cap_too_small(rng):
+    nb, npr = 128, 128
+    return [(jnp.zeros(nb, jnp.int64), None)], [(jnp.zeros(npr, jnp.int64), None)], \
+        np.ones(nb, bool), np.ones(npr, bool), 256
+
+
+def _no_probe_rows(rng):
+    return [_lane(rng, 64, 10)], [(jnp.zeros(0, jnp.int64), None)], \
+        np.ones(64, bool), np.zeros(0, bool), 32
+
+
+def _one_build_slot(rng):
+    return [(jnp.full(1, 3, jnp.int64), None)], [_lane(rng, 50, 6)], \
+        np.ones(1, bool), rng.random(50) > 0.1, 64
+
+
+def _one_dead_build_slot(rng):
+    return [(jnp.full(1, 3, jnp.int64), None)], [_lane(rng, 50, 6)], \
+        np.zeros(1, bool), np.ones(50, bool), 64
+
+
+CASES = {
+    "unique_build_keys": _unique,
+    "heavy_duplicates": _duplicates,
+    "five_percent_live": _sparse,
+    "five_percent_live_matching": _sparse_matching,
+    "null_keys_both_sides": _nulls,
+    "all_dead_build": _all_dead,
+    "one_hot_key": _hot_key,
+    "two_column_keys": _two_columns,
+    "cap_too_small": _cap_too_small,
+    "npr_0": _no_probe_rows,
+    "nb_1": _one_build_slot,
+    "nb_1_dead": _one_dead_build_slot,
+}
+
+
+def _three_bit_hash(cols):
+    return _REAL_HASH(cols) >> np.uint64(61) << np.uint64(61)
+
+
+_REAL_HASH = K.hash_columns
+
+
+def _assert_equal_pairs(got: K.JoinPairs, want: K.JoinPairs):
+    for name, g, w in zip(K.JoinPairs._fields, got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.shape == w.shape, name
+        assert (g == w).all(), (name, np.nonzero(g != w)[0][:5])
+
+
+@pytest.mark.parametrize("collide", [False, True], ids=["mix64", "three_bit_hash"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_every_leaf_equals_the_full_search_oracle(case, collide, monkeypatch):
+    if collide:
+        # eight distinct hashes: nearly every candidate pair is a collision
+        # that `verify` has to drop, and the widest bucket holds an eighth of
+        # the build side
+        monkeypatch.setattr(K, "hash_columns", _three_bit_hash)
+    bkeys, pkeys, blive, plive, cap = CASES[case](np.random.default_rng(11))
+    if collide:
+        cap = max(cap, 1 << 20)
+    # a fresh function each time: a trace cached under the other hash must not answer
+    got = jax.jit(lambda *a: K._hash_join_pairs_sorted(*a, cap))(
+        bkeys, pkeys, jnp.asarray(blive), jnp.asarray(plive))
+    want = oracle(bkeys, pkeys, blive, plive, cap)
+    _assert_equal_pairs(got, want)
+    nb = blive.shape[0]
+    assert 0 <= int(got.search_levels) <= K.full_search_depth(nb)
+    if case == "cap_too_small":
+        assert bool(got.overflow) != collide  # 16,384 candidates in 256 slots
+    if case == "one_hot_key":
+        assert int(got.search_levels) == K.full_search_depth(nb) == 10
+    if case == "all_dead_build":
+        assert int(got.search_levels) == 0 and not np.asarray(got.live).any()
+
+
+def test_verified_pairs_are_the_equal_keys():
+    """The oracle shares the hash with the kernel; this one shares nothing."""
+    rng = np.random.default_rng(3)
+    bkeys, pkeys, blive, plive, cap = _nulls(rng)
+    r = K._hash_join_pairs_sorted(bkeys, pkeys, jnp.asarray(blive),
+                                  jnp.asarray(plive), cap)
+    live = np.asarray(r.live)
+    got = sorted(zip(np.asarray(r.build_idx)[live].tolist(),
+                     np.asarray(r.probe_idx)[live].tolist()))
+    bl, pl = _np_live(bkeys, blive), _np_live(pkeys, plive)
+    bd, pd = np.asarray(bkeys[0][0]), np.asarray(pkeys[0][0])
+    want = sorted((int(b), int(p)) for p in np.nonzero(pl)[0]
+                  for b in np.nonzero(bl & (bd == pd[p]))[0])
+    assert got == want and not bool(r.overflow)
+
+
+def test_dead_sentinel_hash_is_a_live_hash_like_any_other(monkeypatch):
+    """A live row whose keys hash to the dead rows' value still finds its
+    match, and dead rows are no candidates for it."""
+    monkeypatch.setattr(K, "hash_columns",
+                        lambda cols: jnp.full(cols[0][0].shape, DEAD, jnp.uint64))
+    bk = jnp.asarray(np.arange(32, dtype=np.int64))
+    pk = jnp.asarray(np.array([5, 40, 31, 6], np.int64))
+    blive = np.arange(32) % 2 == 1
+    r = K._hash_join_pairs_sorted([(bk, None)], [(pk, None)], jnp.asarray(blive),
+                                  jnp.ones(4, bool), 128)
+    assert np.asarray(r.probe_matched).tolist() == [True, False, True, False]
+    assert int(np.asarray(r.probe_offsets)[-1]) == 4 * 16  # live rows only
+    assert int(r.search_levels) == 5
+
+
+def test_uniform_keys_search_a_few_levels_of_the_full_depth():
+    nb, npr = 65_536, 100_000
+    rng = np.random.default_rng(17)
+    bk = jnp.asarray(rng.permutation(nb).astype(np.int64))
+    pk = jnp.asarray(rng.integers(0, nb, npr))
+    r = jax.jit(lambda *a: K._hash_join_pairs_sorted(*a, 1 << 17))(
+        [(bk, None)], [(pk, None)], jnp.ones(nb, bool), jnp.ones(npr, bool))
+    assert K.full_search_depth(nb) == 17
+    assert 1 <= int(r.search_levels) <= 8
+    assert int(np.asarray(r.live).sum()) == npr and not bool(r.overflow)
+
+
+def test_slot_table_formulation_reports_no_depth():
+    one = [(jnp.zeros(8, jnp.int64), None)]
+    r = K._hash_join_pairs_table(one, one, jnp.ones(8, bool), jnp.ones(8, bool), 128)
+    assert r.search_levels is None
+
+
+def test_traces_and_agrees_under_shard_map():
+    """Each shard joins its own block, with its own trip count: the loop holds
+    no collective (what `parallel/mpp._join_block` relies on)."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    shards, nb, npr, cap = 4, 512, 1024, 4096
+    rng = np.random.default_rng(23)
+    # shard 0: one hot key (full depth); shard 3: an all-dead build side
+    bk = rng.integers(0, 200, (shards, nb))
+    bk[0] = 7
+    pk = rng.integers(0, 200, (shards, npr))
+    blive = rng.random((shards, nb)) > 0.2
+    blive[0], blive[3] = True, False
+    plive = rng.random((shards, npr)) > 0.2
+
+    def block(bk, pk, blive, plive):
+        r = K._hash_join_pairs_sorted([(bk, None)], [(pk, None)], blive, plive, cap)
+        return r._replace(overflow=r.overflow[None],
+                          search_levels=r.search_levels[None])
+
+    mesh = Mesh(np.array(jax.devices()[:shards]), ("x",))
+    fn = jax.jit(shard_map(block, mesh=mesh, in_specs=(P("x"),) * 4,
+                           out_specs=P("x")))
+    flat = [jnp.asarray(a.reshape(-1)) for a in (bk, pk, blive, plive)]
+    got = fn(*flat)
+    for s in range(shards):
+        want = oracle([(bk[s], None)], [(pk[s], None)], blive[s], plive[s], cap)
+        mine = K.JoinPairs(*(np.asarray(leaf).reshape(shards, -1)[s].reshape(
+            np.shape(w)) for leaf, w in zip(got, want)))
+        _assert_equal_pairs(mine, want)
+    levels = np.asarray(got.search_levels).tolist()
+    assert levels[0] == K.full_search_depth(nb) and levels[3] == 0
+
+
+@pytest.mark.parametrize("join_type", ["inner", "left", "semi", "anti"])
+def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, monkeypatch):
+    """`HashJoinOp` on the TPU's formulation: one count per probe batch in
+    `JOIN_STATS`, "levels of full depth" on the span under the cursor, and the
+    rows of the slot-table formulation."""
+    from galaxysql_tpu.chunk.batch import Column, ColumnBatch
+    from galaxysql_tpu.exec import operators as ops
+    from galaxysql_tpu.expr import ir
+    from galaxysql_tpu.types import datatype as dt
+    from galaxysql_tpu.utils import tracing
+
+    def batch(name, values, live=None):
+        col = Column(jnp.asarray(np.asarray(values, np.int64)), None, dt.BIGINT, None)
+        return ColumnBatch({name: col}, None if live is None else jnp.asarray(live))
+
+    rng = np.random.default_rng(29)
+    build = batch("k", rng.permutation(3000)[:2000])
+    probes = [batch("a", rng.integers(0, 3000, 700), rng.random(700) > 0.1)
+              for _ in range(2)]
+    bk, pk = [ir.ColRef("k", dt.BIGINT, None)], [ir.ColRef("a", dt.BIGINT, None)]
+
+    def rows(op):
+        out = []
+        for b in op.batches():
+            cols = sorted(b.columns)
+            d = b.compact().to_pydict()
+            out += list(zip(*(d[c] for c in cols)))
+        return sorted(out, key=str)
+
+    def join():
+        return ops.HashJoinOp(ops.SourceOp([build]), ops.SourceOp(probes), bk, pk,
+                              join_type, enable_bloom=False,
+                              build_schema={"k": (dt.BIGINT, None)})
+    before = dict(ops.JOIN_STATS)
+    want = rows(join())  # this backend's own formulation, which counts nothing
+    assert ops.JOIN_STATS == before
+
+    monkeypatch.setattr(K, "prefer_scatter", lambda: False)
+    with ops._JIT_CACHE_LOCK:  # programs keyed alike must not outlive the patch
+        saved = dict(ops._JIT_CACHE)
+        ops._JIT_CACHE.clear()
+    try:
+        tc = tracing.TraceContext(7)
+        span = tc.add("Join", kind="operator")
+        tc.cursor = span.span_id
+        op = join()
+        with tracing.activate(tc):
+            got = rows(op)
+    finally:
+        with ops._JIT_CACHE_LOCK:
+            ops._JIT_CACHE.clear()
+            ops._JIT_CACHE.update(saved)
+    assert got == want and len(want) > 0
+    full = K.full_search_depth(ops.bucket_capacity(2000))
+    assert ops.JOIN_STATS["probes"] == before["probes"] + 2
+    assert ops.JOIN_STATS["full_depth_levels"] == before["full_depth_levels"] + 2 * full
+    levels = ops.JOIN_STATS["search_levels"] - before["search_levels"]
+    assert 2 <= levels <= 2 * 8 < 2 * full
+    assert span.attrs["search_levels"] == f"{levels} of {2 * full}"
+    assert f"search_levels={levels} of {2 * full}" in "\n".join(tc.tree_lines())
