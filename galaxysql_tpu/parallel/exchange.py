@@ -14,12 +14,34 @@ bounded buckets + retry, consistent with the engine's overflow-retry discipline)
 
 from __future__ import annotations
 
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 AXIS = "shard"
+
+
+def _cost(kind: str, lanes: Sequence[Any], slots: int) -> Dict[str, int]:
+    return {kind + "_calls": len(lanes) + 1,
+            kind + "_bytes": slots * (sum(x.dtype.itemsize for x in lanes) + 1),
+            "slots": slots}
+
+
+def repartition_cost(lanes: Sequence[Any], quota: int) -> Dict[str, int]:
+    """What `repartition_by_hash(lanes, ..., quota)` moves, from static shapes
+    alone (call it where the exchange is traced): one `all_to_all` a lane plus
+    one for `live`, each handing over ONE shard's send buffer of `S * quota`
+    slots, of which `(S - 1) / S` leave the chip."""
+    return _cost("all_to_all", lanes, jax.lax.axis_size(AXIS) * quota)
+
+
+def broadcast_cost(lanes: Sequence[Any], rows: int) -> Dict[str, int]:
+    """What `broadcast_all` of `rows`-slot lanes moves: one `all_gather` a lane
+    plus one for `live`.  Bytes and slots are those of ONE shard's gathered
+    result (`S * rows` slots a lane), so that, as for the repartition,
+    `(S - 1) / S` of them crossed the interconnect."""
+    return _cost("all_gather", lanes, jax.lax.axis_size(AXIS) * rows)
 
 
 def repartition_by_hash(lanes: Sequence[Any], live: Any, hash_lane: Any,
@@ -30,6 +52,11 @@ def repartition_by_hash(lanes: Sequence[Any], live: Any, hash_lane: Any,
     Returns (exchanged lanes [S*quota], exchanged live, overflow flag scalar).
     Row r goes to shard hash % S; each (src, dst) pair carries `quota` slots.
     """
+    with jax.named_scope("exchange/repartition"):
+        return _repartition_by_hash(lanes, live, hash_lane, quota)
+
+
+def _repartition_by_hash(lanes, live, hash_lane, quota):
     ns = jax.lax.axis_size(AXIS)
     n = live.shape[0]
     dest = (hash_lane % jnp.uint64(ns)).astype(jnp.int32)
@@ -63,12 +90,10 @@ def broadcast_all(lanes: Sequence[Any], live: Any) -> Tuple[List[Any], Any]:
     """Replicate every shard's rows to all shards (broadcast join build side).
 
     Returns lanes of shape [S*R] and the combined live mask."""
-    out = [jax.lax.all_gather(lane, AXIS, axis=0, tiled=False).reshape(
-        (-1,) + lane.shape[1:]) for lane in lanes]
-    live_g = jax.lax.all_gather(live, AXIS, axis=0, tiled=False).reshape(-1)
+    with jax.named_scope("exchange/broadcast"):
+        out = [jax.lax.all_gather(lane, AXIS, axis=0, tiled=False).reshape(
+            (-1,) + lane.shape[1:]) for lane in lanes]
+        live_g = jax.lax.all_gather(live, AXIS, axis=0,
+                                    tiled=False).reshape(-1)
     return out, live_g
 
-
-def gather_concat(lanes: Sequence[Any], live: Any) -> Tuple[List[Any], Any]:
-    """all_gather: every shard receives the concatenation (replicated result)."""
-    return broadcast_all(lanes, live)

@@ -46,6 +46,62 @@ BROADCAST_BUILD_LIMIT = 1 << 19  # est. rows: at or below, broadcast the build s
 SHARD = P("shard")
 REP = P()
 
+# What the exchange plane moved, cumulative since process start, kept by the
+# host loop at every dispatch of a program that exchanges: collective calls and
+# the bytes ONE shard handed to each kind (`exchange.repartition_cost` /
+# `broadcast_cost`, from static shapes; `(S - 1) / S` of them cross the
+# interconnect), the slots those buffers held and the rows found live in them
+# (both summed over the shards), overflow retries of the capacity ladders, and
+# MPP statements, so that a per-statement value is a ratio of two of these.
+EXCHANGE_STATS = {"statements": 0, "all_to_all_calls": 0, "all_to_all_bytes": 0,
+                  "all_gather_calls": 0, "all_gather_bytes": 0,
+                  "slots_offered": 0, "live_rows": 0, "overflow_retries": 0}
+_COST_KEYS = ("all_to_all_calls", "all_to_all_bytes", "all_gather_calls",
+              "all_gather_bytes", "slots")
+
+
+def _exchange_report(costs, *lives):
+    """Inside a shard_map block, what a program returns beside its overflow
+    flags, replicated: the static cost of its exchanges (`exchange.*_cost`
+    dicts, summed into a constant vector) and the live rows of each mask on
+    every shard, [S, len(lives)] int32 (one all_gather of a few integers) —
+    fill and skew then cost the host no sync beyond the flags it reads
+    anyway."""
+    vec = jnp.asarray([sum(c.get(k, 0) for c in costs) for k in _COST_KEYS],
+                      jnp.int64)
+    counts = jnp.stack([jnp.sum(x, dtype=jnp.int32) for x in lives])
+    return vec, jax.lax.all_gather(counts, "shard")
+
+
+def _note_exchange(vec, exchanged, overflowed) -> Tuple[int, int]:
+    """Add one dispatch's exchanges to EXCHANGE_STATS: `vec` is its
+    `_exchange_report` cost vector, `exchanged` the [S, n] live counts of the
+    buffers it exchanged (a gathered buffer reads the same on every shard and
+    counts S times, as its slots do), `overflowed` whether the ladder goes
+    round again.  Returns (live rows, slots), both over all shards."""
+    for k, v in zip(_COST_KEYS[:4], vec):
+        EXCHANGE_STATS[k] += int(v)
+    live, slots = int(exchanged.sum()), int(vec[4]) * exchanged.shape[0]
+    EXCHANGE_STATS["slots_offered"] += slots
+    EXCHANGE_STATS["live_rows"] += live
+    EXCHANGE_STATS["overflow_retries"] += bool(overflowed)
+    return live, slots
+
+
+def _gather_partials(r, costs):
+    """Inside a shard_map block: every shard's partial groups on every shard
+    (the merge stage's input), as (keys, aggs, live) of S x G slots."""
+    pairs = list(r.keys) + list(r.aggs)
+    lanes = _pack_lanes(pairs)
+    costs.append(exchange.broadcast_cost(lanes, r.live.shape[0]))
+    glanes, live_g = exchange.broadcast_all(lanes, r.live)
+    moved = _unpack_lanes(glanes, pairs)
+    return moved[:len(r.keys)], moved[len(r.keys):], live_g
+
+
+def _fill(live_rows: int, slots: int) -> float:
+    return round(live_rows / slots, 4) if slots else 0.0
+
 
 def _shard_skew_ratio(per_shard) -> Optional[float]:
     """max/mean live rows per shard, or None for an empty stage."""
@@ -83,12 +139,13 @@ class DistBatch:
     columns: Dict[str, Column]
     live: Any
     replicated: bool  # True: lanes [N] identical everywhere; False: [S*R] sharded
+    # live rows per shard, where the producing program returned them (joins)
+    shard_rows: Optional[np.ndarray] = None
+    # what the producing stage writes on its span (exchange kind, quotas, fill)
+    stage_attrs: Optional[Dict[str, Any]] = None
 
     def env(self):
         return {n: (c.data, c.valid) for n, c in self.columns.items()}
-
-
-
 
 
 def _join_block(benv, blive, penv, plive, bk, pk, kind, residual_pred, cap,
@@ -151,10 +208,13 @@ class MppExecutor:
         self.ctx = ctx
         self.mesh = mesh
         self.S = mesh.shape["shard"]
+        self._depth = 0      # nesting of traced `run` calls
+        self._pending = []   # traced stages whose row counts are still on device
 
     # -- entry ---------------------------------------------------------------
 
     def execute(self, node: L.RelNode) -> ColumnBatch:
+        EXCHANGE_STATS["statements"] += 1
         return self._to_host(self.run(node))
 
     def _to_host(self, b: DistBatch) -> ColumnBatch:
@@ -189,19 +249,53 @@ class MppExecutor:
         # traced: one `stage` span per plan node (nested — the stage tree IS
         # the span tree), with per-shard child spans on sharded outputs so the
         # Chrome-trace export shows one row per shard and mesh skew is
-        # visible.  Counting shard rows syncs the device — tracing is opt-in,
-        # exactly like profiling.
+        # visible.
         sp = tc.begin(f"mpp:{type(node).__name__}", kind="stage")
+        self._depth += 1
         try:
             out = self._run_collect(node) if collecting \
                 else self._run_node(node)
         finally:
+            self._depth -= 1
             tc.end(sp)
-        live = np.asarray(out.live)
-        sp.attrs["rows"] = int(live.sum())
+        if out.stage_attrs:
+            sp.attrs.update(out.stage_attrs)
         sp.attrs["replicated"] = out.replicated
-        if not out.replicated and live.size and live.size % self.S == 0:
-            per_shard = live.reshape(self.S, -1).sum(axis=1)
+        info = getattr(self.ctx, "skew_stats", {}).get(id(node))
+        if info is not None:
+            # the hybrid/salted decision rides the stage span (HotKeys /
+            # Salted in information_schema.query_spans and /trace/<id>)
+            sp.attrs["skew_exec"] = skew.explain_line(info)
+        # rows per shard: S integers counted on the device (or the ones the
+        # join program returned), read in ONE transfer when the outermost
+        # stage is done, so the traced statement syncs where the untraced does
+        self._pending.append((sp, self._shard_rows(out), out.replicated))
+        if self._depth == 0:
+            self._resolve_stage_rows(tc)
+        return out
+
+    def _shard_rows(self, out: DistBatch):
+        """Live rows of `out` per shard ([S], or [1] for a replicated or an
+        unevenly laid out batch), never the lane itself on the host."""
+        if out.shard_rows is not None:
+            return out.shard_rows
+        n = int(out.live.shape[0])
+        parts = self.S if not out.replicated and n and n % self.S == 0 else 1
+        key = ("mpp_filter", "shard_rows", parts, n)
+
+        def build():
+            return jit_program(lambda live: jnp.sum(
+                live.reshape(parts, -1), axis=1, dtype=jnp.int32))
+        return global_jit(key, build)(out.live)
+
+    def _resolve_stage_rows(self, tc):
+        pending, self._pending = self._pending, []
+        counts = jax.device_get([c for _sp, c, _rep in pending])
+        for (sp, _c, replicated), per_shard in zip(pending, counts):
+            per_shard = np.asarray(per_shard).reshape(-1)
+            sp.attrs["rows"] = int(per_shard.sum())
+            if replicated or per_shard.size != self.S:
+                continue
             for si, rn in enumerate(per_shard):
                 tc.add(f"shard{si}", kind="shard", parent=sp.span_id,
                        start_us=sp.start_us, dur_us=sp.dur_us,
@@ -218,12 +312,6 @@ class MppExecutor:
                 # balanced, ~S means one shard holds everything
                 sp.attrs["skew"] = ratio
                 self._note_shard_skew(ratio)
-        info = getattr(self.ctx, "skew_stats", {}).get(id(node))
-        if info is not None:
-            # the hybrid/salted decision rides the stage span (HotKeys /
-            # Salted in information_schema.query_spans and /trace/<id>)
-            sp.attrs["skew_exec"] = skew.explain_line(info)
-        return out
 
     def _run_collect(self, node: L.RelNode) -> DistBatch:
         # profiling: per-stage wall + row counts (the reference's MPP
@@ -237,14 +325,13 @@ class MppExecutor:
             # _streaming_chain already reported this node (fused entry with
             # per-stage rows) — a second plain entry would double-count it
             return out
-        live = np.asarray(out.live)
+        per_shard = np.asarray(self._shard_rows(out)).reshape(-1)
         st = {"node_id": id(node), "operator": type(node).__name__,
-              "engine": "mpp", "batches": 1, "rows_out": int(live.sum()),
+              "engine": "mpp", "batches": 1, "rows_out": int(per_shard.sum()),
               "wall_ms": round((_t.perf_counter() - t0) * 1000, 3),
               "replicated": out.replicated}
-        if not out.replicated and live.size % self.S == 0:
+        if not out.replicated and per_shard.size == self.S:
             # per-shard task stats: shard s owns slice s of the [S*R] layout
-            per_shard = live.reshape(self.S, -1).sum(axis=1)
             st["rows_per_shard"] = [int(x) for x in per_shard]
             ratio = _shard_skew_ratio(per_shard)
             if ratio is not None:
@@ -554,23 +641,12 @@ class MppExecutor:
             def spmd(env, live, plits):
                 r = local_partial(env, live, plits)
                 over = r.overflow
-
-                def gather_pairs(pairs):
-                    out = []
-                    for d, v in pairs:
-                        dg = jax.lax.all_gather(d, "shard", axis=0).reshape(-1)
-                        vg = None if v is None else \
-                            jax.lax.all_gather(v, "shard", axis=0).reshape(-1)
-                        out.append((dg, vg))
-                    return out
-
-                flat_keys = gather_pairs(r.keys)
-                flat_aggs = gather_pairs(r.aggs)
-                live_g = jax.lax.all_gather(r.live, "shard", axis=0).reshape(-1)
+                costs = []
+                flat_keys, flat_aggs, live_g = _gather_partials(r, costs)
                 m = K.groupby(flat_keys, flat_aggs, merge_specs, live_g, G)
                 over = jax.lax.pmax((over | m.overflow).astype(jnp.int32),
                                     "shard").astype(jnp.bool_)
-                return m, over
+                return m, (over, _exchange_report(costs, live_g))
 
             fn = shard_map(spmd, mesh=self.mesh, in_specs=(SHARD, SHARD, REP),
                            out_specs=(REP, REP), check_vma=False)
@@ -578,7 +654,11 @@ class MppExecutor:
 
         plits = prelude.lits() if prelude is not None else ()
         DISPATCH_STATS["dispatches"] += 1
-        r, overflow = global_jit(key, build)(child.env(), child.live, plits)
+        r, res = global_jit(key, build)(child.env(), child.live, plits)
+        if child.replicated:
+            return r, bool(res)
+        overflow, (vec, counts) = jax.device_get(res)
+        _note_exchange(vec, counts, overflow)
         return r, bool(overflow)
 
     def _aggregate_salted(self, child: DistBatch, groups, calls, est: float,
@@ -644,8 +724,10 @@ class MppExecutor:
                 salt = jnp.arange(n, dtype=jnp.uint64) % jnp.uint64(factor)
                 dh = K.hash_columns([(kh, None), (salt, None)])
                 pairs = keys0 + ins0
+                lanes = _pack_lanes(pairs)
+                costs = [exchange.repartition_cost(lanes, quota)]
                 out_lanes, live_x, over_x = exchange.repartition_by_hash(
-                    _pack_lanes(pairs), live, dh, quota)
+                    lanes, live, dh, quota)
                 moved = _unpack_lanes(out_lanes, pairs)
                 keys = moved[:len(keys0)]
                 ins = moved[len(keys0):]
@@ -653,26 +735,14 @@ class MppExecutor:
 
                 # final merge stage: gather every shard's partial groups and
                 # re-combine the salt buckets (replicated result)
-                def gather_pairs(prs):
-                    out = []
-                    for d, v in prs:
-                        dg = jax.lax.all_gather(d, "shard", axis=0).reshape(-1)
-                        vg = None if v is None else \
-                            jax.lax.all_gather(v, "shard",
-                                               axis=0).reshape(-1)
-                        out.append((dg, vg))
-                    return out
-
-                flat_keys = gather_pairs(r.keys)
-                flat_aggs = gather_pairs(r.aggs)
-                live_g = jax.lax.all_gather(r.live, "shard",
-                                            axis=0).reshape(-1)
+                flat_keys, flat_aggs, live_g = _gather_partials(r, costs)
                 m = K.groupby(flat_keys, flat_aggs, merge_specs, live_g, G)
 
                 def rep(x):
                     return jax.lax.pmax(x.astype(jnp.int32),
                                         "shard").astype(jnp.bool_)
-                return m, (rep(over_x), rep(r.overflow | m.overflow))
+                return m, ((rep(over_x), rep(r.overflow | m.overflow)),
+                           _exchange_report(costs, live_x, live_g))
 
             fn = shard_map(spmd, mesh=self.mesh, in_specs=(SHARD, SHARD, REP),
                            out_specs=(REP, REP), check_vma=False)
@@ -680,8 +750,10 @@ class MppExecutor:
 
         plits = prelude.lits() if prelude is not None else ()
         DISPATCH_STATS["dispatches"] += 1
-        r, flags = global_jit(key, build)(child.env(), child.live, plits)
+        r, res = global_jit(key, build)(child.env(), child.live, plits)
+        flags, (vec, counts) = jax.device_get(res)
         over_shuffle, over_groups = (bool(x) for x in flags)
+        _note_exchange(vec, counts, over_shuffle or over_groups)
         return r, over_shuffle, over_groups
 
     # -- join ------------------------------------------------------------------------
@@ -815,6 +887,7 @@ class MppExecutor:
                         build_ids, probe_ids):
         probe_R = int(probe.live.shape[0]) // self.S
         cap = bucket_capacity(max(probe_R * 2, 1024))
+        retries = 0
         while True:
             key = ("mpp_bjoin", node.kind, K.kernel_selector_key(),
                    tuple(expr_cache_key(e) for e in build_keys),
@@ -832,24 +905,22 @@ class MppExecutor:
                 _cap = cap
 
                 def spmd(benv, blive, penv, plive):
+                    costs = []
                     if not build_rep:
                         ids = list(benv.keys())
-                        lanes = [benv[i][0] for i in ids]
-                        glanes, glive = exchange.broadcast_all(lanes, blive)
-                        new_benv = {}
-                        for k2, i in enumerate(ids):
-                            v = benv[i][1]
-                            if v is not None:
-                                gv, _ = exchange.broadcast_all([v], blive)
-                                v = gv[0]
-                            new_benv[i] = (glanes[k2], v)
-                        benv, blive = new_benv, glive
+                        pairs = [benv[i] for i in ids]
+                        lanes = _pack_lanes(pairs)
+                        costs.append(exchange.broadcast_cost(lanes, blive.shape[0]))
+                        glanes, blive = exchange.broadcast_all(lanes, blive)
+                        benv = dict(zip(ids, _unpack_lanes(glanes, pairs)))
                     (cols, live), over = _join_block(
                         benv, blive, penv, plive, bk, pk, kind, residual_pred,
                         _cap, bids, pids)
                     over = jax.lax.pmax(over.astype(jnp.int32),
                                         "shard").astype(jnp.bool_)
-                    return (cols, live), over
+                    # a gathered build side is whole on every shard
+                    return (cols, live), (over, _exchange_report(
+                        costs, live, *([blive] if costs else [])))
 
                 in_specs = (REP if build_rep else SHARD,
                             REP if build_rep else SHARD, SHARD, SHARD)
@@ -857,21 +928,33 @@ class MppExecutor:
                                out_specs=(SHARD, REP), check_vma=False)
                 return jit_program(fn)
 
-            out, over = global_jit(key, builder)(build.env(), build.live,
-                                                 probe.env(), probe.live)
+            out, res = global_jit(key, builder)(build.env(), build.live,
+                                                probe.env(), probe.live)
+            over, (vec, counts) = jax.device_get(res)
+            live, slots = _note_exchange(vec, counts[:, 1:], over)
             if not bool(over):
-                return out
+                attrs = {"exchange": "replicated"} if build.replicated else {
+                    "exchange": "broadcast", "build_slots": slots // self.S,
+                    "build_rows": live // self.S, "fill": _fill(live, slots)}
+                return out, counts[:, 0], dict(attrs, cap=cap, retries=retries)
+            retries += 1
             cap *= 2
             if cap > (1 << 24):
                 raise errors.TddlError("MPP join output exceeds capacity ceiling")
 
+    def _shuffle_quotas(self, bR: int, pR: int) -> Tuple[int, int]:
+        """Where the shuffle's quota ladders start: slots each (source,
+        destination) pair carries, twice a uniform hash's share of the
+        side's SLOTS per shard (live rows are not known here)."""
+        return max(2 * bR // self.S, 128), max(2 * pR // self.S, 128)
+
     def _shuffle_join(self, node, build, probe, build_keys, probe_keys,
                       build_ids, probe_ids):
-        bR = int(build.live.shape[0]) // self.S
-        pR = int(probe.live.shape[0]) // self.S
-        quota_b = max(2 * bR // self.S, 128)
-        quota_p = max(2 * pR // self.S, 128)
+        quota_b, quota_p = self._shuffle_quotas(
+            int(build.live.shape[0]) // self.S,
+            int(probe.live.shape[0]) // self.S)
         cap = bucket_capacity(max(2 * quota_p * self.S, 1024))
+        retries = 0
         while True:
             key = ("mpp_sjoin", node.kind, K.kernel_selector_key(),
                    tuple(expr_cache_key(e) for e in build_keys),
@@ -888,13 +971,17 @@ class MppExecutor:
                 _qb, _qp, _cap = quota_b, quota_p, cap
 
                 def spmd(benv, blive, penv, plive):
+                    costs = []
+
                     def shuffle_side(env, live, key_fns, quota):
                         keys = [f(env) for f in key_fns]
                         h = K.hash_columns(keys)
                         ids = list(env.keys())
                         pairs = [env[i] for i in ids]
+                        lanes = _pack_lanes(pairs)
+                        costs.append(exchange.repartition_cost(lanes, quota))
                         out_lanes, live_x, over = exchange.repartition_by_hash(
-                            _pack_lanes(pairs), live, h, quota)
+                            lanes, live, h, quota)
                         return (dict(zip(ids, _unpack_lanes(out_lanes,
                                                             pairs))),
                                 live_x, over)
@@ -908,18 +995,30 @@ class MppExecutor:
                     def rep(x):
                         return jax.lax.pmax(x.astype(jnp.int32),
                                             "shard").astype(jnp.bool_)
-                    return (cols, live), (rep(over_b), rep(over_p), rep(over_cap))
+                    return (cols, live), (
+                        (rep(over_b), rep(over_p), rep(over_cap)),
+                        _exchange_report(costs, blive2, plive2, live))
 
                 fn = shard_map(spmd, mesh=self.mesh,
                                in_specs=(SHARD, SHARD, SHARD, SHARD),
                                out_specs=(SHARD, REP), check_vma=False)
                 return jit_program(fn)
 
-            out, flags = global_jit(key, builder)(build.env(), build.live,
-                                                  probe.env(), probe.live)
+            out, res = global_jit(key, builder)(build.env(), build.live,
+                                                probe.env(), probe.live)
+            flags, (vec, counts) = jax.device_get(res)
             over_b, over_p, over_cap = (bool(x) for x in flags)
+            moved, slots = _note_exchange(vec, counts[:, :2],
+                                          over_b or over_p or over_cap)
             if not (over_b or over_p or over_cap):
-                return out
+                return out, counts[:, 2], {
+                    "exchange": "shuffle", "quota_b": quota_b,
+                    "quota_p": quota_p, "cap": cap, "retries": retries,
+                    "build_rows": int(counts[:, 0].sum()),
+                    "probe_rows": int(counts[:, 1].sum()),
+                    "probe_skew": _shard_skew_ratio(counts[:, 1]),
+                    "fill": _fill(moved, slots)}
+            retries += 1
             if over_b:
                 quota_b *= 2
             if over_p:
@@ -971,8 +1070,7 @@ class MppExecutor:
         # the skewed side's cold shuffle excludes the hot mass — size its
         # quota for the remainder (the ladder covers sketch underestimates)
         cold = 1.0 - active.hot_mass()
-        quota_b = max(2 * bR // self.S, 128)
-        quota_p = max(2 * pR // self.S, 128)
+        quota_b, quota_p = self._shuffle_quotas(bR, pR)
         if skew_on_probe:
             quota_p = max(int(quota_p * cold), 128)
         else:
@@ -987,6 +1085,7 @@ class MppExecutor:
         # BALANCED across shards (that is the point), so the fair-share bound
         # holds where the plain shuffle's hot shard overflows it
         cap = bucket_capacity(max(2 * quota_p * self.S, 1024))
+        retries = 0
         while True:
             key = ("mpp_hybrid_join", node.kind, K.kernel_selector_key(),
                    active.orientation,
@@ -1007,10 +1106,12 @@ class MppExecutor:
                 _hq, _lq = hot_quota, loc_quota
                 _qb, _qp, _cap = quota_b, quota_p, cap
 
-                def shuffle_cold(env, live, h, quota, ids):
+                def shuffle_cold(env, live, h, quota, ids, costs):
                     pairs = [env[i] for i in ids]
+                    lanes = _pack_lanes(pairs)
+                    costs.append(exchange.repartition_cost(lanes, quota))
                     out_lanes, live_x, over = exchange.repartition_by_hash(
-                        _pack_lanes(pairs), live, h, quota)
+                        lanes, live, h, quota)
                     return (dict(zip(ids, _unpack_lanes(out_lanes, pairs))),
                             live_x, over)
 
@@ -1043,12 +1144,13 @@ class MppExecutor:
                                   None if v is None else compact(v))
                     return out, clive, over
 
-                def broadcast_hot(env, hot_mask, ids):
+                def broadcast_hot(env, hot_mask, ids, costs):
                     # compact hot rows to _hq slots, then replicate
                     cenv, clive, over = compact_hot(env, hot_mask, ids, _hq)
                     pairs = [cenv[i] for i in ids]
-                    gl, glive = exchange.broadcast_all(_pack_lanes(pairs),
-                                                       clive)
+                    lanes = _pack_lanes(pairs)
+                    costs.append(exchange.broadcast_cost(lanes, _hq))
+                    gl, glive = exchange.broadcast_all(lanes, clive)
                     return (dict(zip(ids, _unpack_lanes(gl, pairs))),
                             glive, over)
 
@@ -1068,6 +1170,7 @@ class MppExecutor:
                     return out, jnp.concatenate([a_live, b_live])
 
                 def spmd(benv, blive, penv, plive, hoth, hotv):
+                    costs = []
                     bkeys_l = [f(benv) for f in bk]
                     pkeys_l = [f(penv) for f in pk]
                     hot_b = K.hot_key_mask(bkeys_l, hoth, hotv) & blive
@@ -1077,15 +1180,15 @@ class MppExecutor:
 
                     # cold rows of both sides hash-shuffle as today
                     cb_env, cb_live, over_b = shuffle_cold(
-                        benv, blive & ~hot_b, bh, _qb, bids)
+                        benv, blive & ~hot_b, bh, _qb, bids, costs)
                     cp_env, cp_live, over_p = shuffle_cold(
-                        penv, plive & ~hot_p, ph, _qp, pids)
+                        penv, plive & ~hot_p, ph, _qp, pids, costs)
 
                     if skew_on_probe:
                         # hot build rows broadcast; hot probe rows stay
                         # local (compacted — their shard does not change)
                         ghot, ghot_live, over_h = broadcast_hot(
-                            benv, hot_b, bids)
+                            benv, hot_b, bids, costs)
                         lenv, llive, over_l = compact_hot(
                             penv, hot_p, pids, _lq)
                         ubenv, ublive = union(ghot, ghot_live,
@@ -1096,7 +1199,7 @@ class MppExecutor:
                         # skewed build: hot probe rows broadcast, hot build
                         # rows stay where the scan layout balanced them
                         ghot, ghot_live, over_h = broadcast_hot(
-                            penv, hot_p, pids)
+                            penv, hot_p, pids, costs)
                         lenv, llive, over_l = compact_hot(
                             benv, hot_b, bids, _lq)
                         ubenv, ublive = union(lenv, llive,
@@ -1112,22 +1215,31 @@ class MppExecutor:
                     def rep(x):
                         return jax.lax.pmax(x.astype(jnp.int32),
                                             "shard").astype(jnp.bool_)
-                    return (cols, live), (rep(over_h), rep(over_l),
-                                          rep(over_b), rep(over_p),
-                                          rep(over_cap))
+                    return (cols, live), (
+                        (rep(over_h), rep(over_l), rep(over_b), rep(over_p),
+                         rep(over_cap)),
+                        _exchange_report(costs, cb_live, cp_live, ghot_live,
+                                         live))
 
                 fn = shard_map(spmd, mesh=self.mesh,
                                in_specs=(SHARD, SHARD, SHARD, SHARD, REP, REP),
                                out_specs=(SHARD, REP), check_vma=False)
                 return jit_program(fn)
 
-            out, flags = global_jit(key, builder)(
+            out, res = global_jit(key, builder)(
                 build.env(), build.live, probe.env(), probe.live,
                 jnp.asarray(hot_h), jnp.asarray(hot_v))
+            flags, (vec, counts) = jax.device_get(res)
             over_h, over_l, over_b, over_p, over_cap = \
                 (bool(x) for x in flags)
-            if not (over_h or over_l or over_b or over_p or over_cap):
-                return out
+            overflowed = over_h or over_l or over_b or over_p or over_cap
+            moved, slots = _note_exchange(vec, counts[:, :3], overflowed)
+            if not overflowed:
+                return out, counts[:, 3], {
+                    "exchange": "hybrid", "quota_b": quota_b,
+                    "quota_p": quota_p, "hot_quota": hot_quota, "cap": cap,
+                    "retries": retries, "fill": _fill(moved, slots)}
+            retries += 1
             if over_h:
                 hot_quota *= 2
             if over_l:
@@ -1143,14 +1255,16 @@ class MppExecutor:
                     "MPP hybrid join exceeds capacity ceiling")
 
     def _join_result(self, node, out, build_ids, probe_ids) -> DistBatch:
-        cols, live = out
+        (cols, live), out_rows, attrs = out
         src_meta = {fid: (typ, d)
                     for fid, typ, d in (node.left.fields() + node.right.fields())}
         out_cols = {}
         for i, (d, v) in cols.items():
             typ, dic = src_meta.get(i, (None, None))
             out_cols[i] = Column(d, v, typ, dic)
-        return DistBatch(out_cols, live, False)
+        attrs["out_fill"] = _fill(int(out_rows.sum()), int(live.shape[0]))
+        return DistBatch(out_cols, live, False, shard_rows=out_rows,
+                         stage_attrs=attrs)
 
     def _cross_attach(self, left: DistBatch, right: DistBatch) -> DistBatch:
         # 1-row replicated right side (uncorrelated scalar subquery): broadcast columns
@@ -1209,8 +1323,10 @@ class MppExecutor:
                     h = K.hash_columns([broadcast_value(live.shape[0], *kv)
                                         for kv in pk0])
                     in_pairs = [env[i] for i in cids]
+                    lanes = _pack_lanes(in_pairs)
+                    costs = [exchange.repartition_cost(lanes, _q)]
                     out_lanes, live_x, over = exchange.repartition_by_hash(
-                        _pack_lanes(in_pairs), live, h, _q)
+                        lanes, live, h, _q)
                     new_env = dict(zip(cids, _unpack_lanes(out_lanes,
                                                            in_pairs)))
                     n = live_x.shape[0]
@@ -1227,15 +1343,18 @@ class MppExecutor:
                         cols[i] = (d[order], None if v is None else v[order])
                     over = jax.lax.pmax(over.astype(jnp.int32),
                                         "shard").astype(jnp.bool_)
-                    return (cols, live_s, outs), over
+                    return (cols, live_s, outs), (
+                        over, _exchange_report(costs, live_x))
 
                 fn = shard_map(spmd, mesh=self.mesh, in_specs=(SHARD, SHARD),
                                out_specs=((SHARD, SHARD, SHARD), REP),
                                check_vma=False)
                 return jit_program(fn)
 
-            (cols, live_s, outs), over = global_jit(key, builder)(child.env(),
-                                                                  child.live)
+            (cols, live_s, outs), res = global_jit(key, builder)(child.env(),
+                                                                 child.live)
+            over, (vec, counts) = jax.device_get(res)
+            _note_exchange(vec, counts, over)
             if not bool(over):
                 break
             quota *= 2

@@ -1,0 +1,331 @@
+"""The exchange plane on four virtual devices: TPC-H Q3 through `MppExecutor`
+against a plain pandas reference down each exchange kind and through an
+overflow retry, `EXCHANGE_STATS` against bytes and calls worked out by hand,
+the `stage:Join` span attributes in SHOW TRACE, and the traced run's host
+transfers."""
+
+import numpy as np
+import pandas as pd
+import pytest
+
+import jax
+
+from galaxysql_tpu.parallel import mpp as M
+from galaxysql_tpu.parallel.mesh import make_mesh, shard_bucket
+from galaxysql_tpu.plan import logical as L
+from galaxysql_tpu.plan.physical import ExecContext
+from galaxysql_tpu.plan.rules import estimate_rows
+from galaxysql_tpu.server.instance import Instance
+from galaxysql_tpu.server.session import Session
+from galaxysql_tpu.storage import tpch
+from galaxysql_tpu.storage.tpch_queries import QUERIES
+from galaxysql_tpu.utils import tracing
+
+S = 4
+SF, SEED = 0.02, 2147483659
+EPOCH = np.datetime64("1970-01-01")
+
+
+@pytest.fixture(scope="module")
+def env():
+    assert len(jax.devices()) >= S, "conftest must provide virtual devices"
+    data = tpch.generate(SF, seed=SEED)
+    inst = Instance()
+    inst._mesh = make_mesh(S)            # the session's mesh: four of the eight
+    s = Session(inst)
+    s.execute("CREATE DATABASE tpch")
+    s.execute("USE tpch")
+    for t in tpch.TABLE_ORDER:
+        s.execute(tpch.TPCH_DDL[t])
+        inst.store("tpch", t).insert_arrays(data[t], inst.tso.next_timestamp())
+    s.execute("ANALYZE TABLE " + ", ".join(tpch.TABLE_ORDER))
+    yield inst, s, data
+    s.close()
+
+
+def q3_reference(data):
+    """Q3 (BUILDING, 1995-03-15) in pandas, revenue in exact scaled integers:
+    [(orderkey, revenue x 10^4, orderdate days, shippriority)], the ten first
+    by revenue descending, then date."""
+    cutoff = int((np.datetime64("1995-03-15") - EPOCH).astype(int))
+    c = pd.DataFrame({"ck": data["customer"]["c_custkey"],
+                      "seg": data["customer"]["c_mktsegment"]})
+    o = pd.DataFrame({"ok": data["orders"]["o_orderkey"],
+                      "ck": data["orders"]["o_custkey"],
+                      "od": np.asarray(data["orders"]["o_orderdate"], np.int64),
+                      "sp": data["orders"]["o_shippriority"]})
+    li = pd.DataFrame({
+        "ok": data["lineitem"]["l_orderkey"],
+        "price": np.round(np.asarray(data["lineitem"]["l_extendedprice"]) * 100
+                          ).astype(np.int64),
+        "disc": np.round(np.asarray(data["lineitem"]["l_discount"]) * 100
+                         ).astype(np.int64),
+        "ship": np.asarray(data["lineitem"]["l_shipdate"], np.int64)})
+    j = li[li.ship > cutoff].merge(
+        o[o.od < cutoff].merge(c[c.seg == "BUILDING"], on="ck"), on="ok")
+    rev = (j.price * (100 - j.disc)).groupby([j.ok, j.od, j.sp]).sum()
+    rows = sorted(((int(k[0]), int(v), int(k[1]), int(k[2]))
+                   for k, v in rev.items()), key=lambda r: (-r[1], r[2]))
+    return rows[:10]
+
+
+def normalise(batch):
+    out = []
+    for ok, rev, od, sp in batch.to_pylist():
+        days = int((np.datetime64(str(od)) - EPOCH).astype(int))
+        out.append((int(ok), int(round(float(rev) * 10 ** 4)), days, int(sp)))
+    return out
+
+
+def joins_of(node):
+    found = [node] if isinstance(node, L.Join) else []
+    for child in node.children:
+        found += joins_of(child)
+    return found
+
+
+def run_q3(inst, traced=False):
+    inst.frag_cache.clear()     # a warm aggregate would replay and run no join
+    plan = inst.planner.plan_select(QUERIES[3], "tpch")
+    ctx = ExecContext(inst.stores, inst.tso.next_timestamp(), [],
+                      archive=inst.archive, archive_instance=inst)
+    ex = M.MppExecutor(ctx, make_mesh(S))
+    tc = tracing.TraceContext(7, node="t") if traced else None
+    with tracing.activate(tc):
+        batch = ex.execute(plan.rel)
+    return batch, ([] if tc is None else tc.spans)
+
+
+def exchanges(spans):
+    """The Join stages' exchange kinds, outermost join first."""
+    return [sp.attrs["exchange"] for sp in spans
+            if sp.kind == "stage" and sp.name == "mpp:Join"]
+
+
+def second_build_estimates(inst):
+    """(estimate of the first join's build side, of the second's): the limit
+    between them sends the first down broadcast and the second down shuffle."""
+    plan = inst.planner.plan_select(QUERIES[3], "tpch")
+    outer, inner = joins_of(plan.rel)[:2]
+    ests = []
+    for j in (inner, outer):
+        ests.append(min(estimate_rows(j.left), estimate_rows(j.right)))
+    return ests
+
+
+@pytest.fixture()
+def limit(monkeypatch):
+    def set_limit(value):
+        monkeypatch.setattr(M, "BROADCAST_BUILD_LIMIT", value)
+    return set_limit
+
+
+def test_q3_planners_choice_equals_pandas(env):
+    inst, _s, data = env
+    batch, spans = run_q3(inst, traced=True)
+    assert normalise(batch) == q3_reference(data)
+    # at this scale both build sides are under the limit
+    assert exchanges(spans) == ["broadcast", "broadcast"]
+
+
+@pytest.mark.parametrize("kind", ["broadcast", "shuffle"])
+def test_q3_second_join_down_each_exchange_equals_pandas(env, limit, kind):
+    inst, _s, data = env
+    first, second = second_build_estimates(inst)
+    assert first < second
+    limit(int(second) + 1 if kind == "broadcast" else int(first))
+    batch, spans = run_q3(inst, traced=True)
+    assert normalise(batch) == q3_reference(data)
+    assert exchanges(spans) == [kind, "broadcast"]
+    outer = next(sp for sp in spans if sp.name == "mpp:Join")
+    assert outer.attrs["retries"] == 0 and 0 < outer.attrs["fill"] <= 1
+    if kind == "shuffle":
+        assert outer.attrs["build_rows"] > 0 and outer.attrs["probe_rows"] > 0
+        assert outer.attrs["quota_b"] >= 128 and outer.attrs["cap"] >= 1024
+
+
+def test_q3_shuffle_from_a_quota_that_overflows_retries_and_equals_pandas(
+        env, limit, monkeypatch):
+    inst, _s, data = env
+    limit(int(second_build_estimates(inst)[0]))
+    monkeypatch.setattr(M.MppExecutor, "_shuffle_quotas",
+                        lambda self, bR, pR: (128, 128))
+    before = dict(M.EXCHANGE_STATS)
+    batch, spans = run_q3(inst, traced=True)
+    assert normalise(batch) == q3_reference(data)
+    outer = next(sp for sp in spans if sp.name == "mpp:Join")
+    assert outer.attrs["exchange"] == "shuffle"
+    assert outer.attrs["retries"] >= 1
+    assert outer.attrs["quota_p"] > 128      # the ladder doubled it
+    assert M.EXCHANGE_STATS["overflow_retries"] - before["overflow_retries"] \
+        == outer.attrs["retries"]
+
+
+# -- EXCHANGE_STATS against a count by hand ----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """b(k, v) 400 rows and p(k, w) 3,000 rows, BIGINT NOT NULL lanes, four
+    partitions each: one partition a shard.  Partitioned by the payload
+    column: HASH(k) would line the rows up with the exchange's own hash of k
+    and one destination would overflow its quota."""
+    inst = Instance()
+    s = Session(inst)
+    s.execute("CREATE DATABASE ex; USE ex")
+    for t, c, n in (("b", "v", 400), ("p", "w", 3000)):
+        s.execute(f"CREATE TABLE {t} (k BIGINT NOT NULL, {c} BIGINT NOT NULL) "
+                  f"PARTITION BY HASH({c}) PARTITIONS {S}")
+        inst.store("ex", t).insert_arrays(
+            {"k": np.arange(n, dtype=np.int64) % 400,
+             c: np.arange(n, dtype=np.int64)}, inst.tso.next_timestamp())
+    s.execute("ANALYZE TABLE b, p")
+    yield inst, s
+    s.close()
+
+
+def slots_per_shard(inst, table):
+    store = inst.store("ex", table)
+    per_shard = [0] * S
+    for pid, part in enumerate(store.partitions):
+        per_shard[pid % S] += part.num_rows
+    return shard_bucket(max(per_shard))
+
+
+def run_tiny(inst):
+    inst.frag_cache.clear()
+    plan = inst.planner.plan_select(
+        "SELECT p.w, b.v FROM p JOIN b ON p.k = b.k", "ex")
+    ctx = ExecContext(inst.stores, inst.tso.next_timestamp(), [],
+                      archive=inst.archive, archive_instance=inst)
+    before = dict(M.EXCHANGE_STATS)
+    batch = M.MppExecutor(ctx, make_mesh(S)).execute(plan.rel)
+    assert len(batch.to_pylist()) == 3000
+    return {k: M.EXCHANGE_STATS[k] - before[k] for k in before}
+
+
+def test_exchange_stats_of_one_repartition_by_hand(tiny, limit):
+    inst, _s = tiny
+    limit(0)
+    got = run_tiny(inst)
+    # each side: two int64 lanes and `live`, one all_to_all each; a shard's
+    # send buffer is S x quota slots of 8 + 8 + 1 bytes
+    qb = max(2 * slots_per_shard(inst, "b") // S, 128)
+    qp = max(2 * slots_per_shard(inst, "p") // S, 128)
+    assert got["statements"] == 1
+    assert got["all_to_all_calls"] == 2 * 3
+    assert got["all_to_all_bytes"] == S * qb * 17 + S * qp * 17
+    assert got["all_gather_calls"] == 0 and got["all_gather_bytes"] == 0
+    assert got["slots_offered"] == S * (S * qb + S * qp)
+    assert got["live_rows"] == 400 + 3000 and got["overflow_retries"] == 0
+
+
+def test_exchange_stats_of_one_broadcast_by_hand(tiny, limit):
+    inst, _s = tiny
+    limit(1 << 19)
+    got = run_tiny(inst)
+    # the build side's two lanes and `live`, one all_gather each; a shard's
+    # gathered result is S x R slots, every row live on every shard
+    R = slots_per_shard(inst, "b")
+    assert got["all_gather_calls"] == 3 and got["all_to_all_calls"] == 0
+    assert got["all_gather_bytes"] == S * R * 17
+    assert got["slots_offered"] == S * S * R
+    assert got["live_rows"] == S * 400
+
+
+# -- spans in SHOW TRACE ------------------------------------------------------------
+
+
+def test_show_trace_of_q3_shows_both_joins_with_quotas_cap_and_fill(env, limit):
+    inst, s, _data = env
+    limit(int(second_build_estimates(inst)[0]))
+    s.vars["MPP_MIN_AP_ROWS"] = 1
+    s.vars["ENABLE_QUERY_TRACING"] = True
+    try:
+        s.execute("/*+TDDL:FRAGMENT_CACHE(OFF)*/ " + QUERIES[3])
+        lines = [r[0] for r in s.execute("SHOW TRACE").rows]
+    finally:
+        s.vars.pop("ENABLE_QUERY_TRACING", None)
+        s.vars.pop("MPP_MIN_AP_ROWS", None)
+    joins = [ln for ln in lines if "mpp:Join [stage]" in ln]
+    assert len(joins) == 2, lines
+    shuffle, broadcast = joins
+    assert "exchange=shuffle" in shuffle and "exchange=broadcast" in broadcast
+    for attr in ("quota_b=", "quota_p=", "cap=", "retries=0", "fill=",
+                 "out_fill=", "rows="):
+        assert attr in shuffle, (attr, shuffle)
+    for attr in ("build_slots=", "cap=", "retries=0", "fill=", "rows="):
+        assert attr in broadcast, (attr, broadcast)
+    # the Chrome export carries the same attributes
+    prof = inst.profiles.entries()[-1]
+    events = tracing.chrome_trace(prof.trace_id, prof.spans)["traceEvents"]
+    assert {e["args"].get("exchange") for e in events
+            if e.get("name") == "mpp:Join"} == {"shuffle", "broadcast"}
+
+
+# -- the traced round is the timed path --------------------------------------------
+
+
+class CountingNumpy:
+    """`numpy` for `parallel/mpp.py` with `asarray` recording what it brings
+    over from a device."""
+
+    def __init__(self, sink):
+        self.sink = sink
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, x, *a, **kw):
+        if isinstance(x, jax.Array):
+            self.sink.append(int(x.size))
+        return np.asarray(x, *a, **kw)
+
+
+def transfers_of_run(inst, monkeypatch, traced):
+    """Sizes of the device arrays `MppExecutor.run` brought to the host for
+    Q3's join subtree (below the aggregate: no result, no gather)."""
+    sink = []
+    real_get = jax.device_get
+    inst.frag_cache.clear()
+
+    def counting_get(tree):
+        sink.extend(int(x.size) for x in jax.tree.leaves(tree)
+                    if isinstance(x, jax.Array))
+        return real_get(tree)
+
+    plan = inst.planner.plan_select(QUERIES[3], "tpch")
+    ctx = ExecContext(inst.stores, inst.tso.next_timestamp(), [],
+                      archive=inst.archive, archive_instance=inst)
+    ex = M.MppExecutor(ctx, make_mesh(S))
+    with monkeypatch.context() as m:
+        m.setattr(M, "np", CountingNumpy(sink))
+        m.setattr(M.jax, "device_get", counting_get)
+        tc = tracing.TraceContext(9, node="t") if traced else None
+        with tracing.activate(tc):
+            out = ex.run(joins_of(plan.rel)[0])
+    assert not out.replicated
+    return sorted(sink), ([] if tc is None else tc.spans)
+
+
+def test_the_traced_run_brings_no_lane_longer_than_the_mesh_to_the_host(
+        env, limit, monkeypatch):
+    inst, _s, _data = env
+    limit(int(second_build_estimates(inst)[0]))
+    untraced, _ = transfers_of_run(inst, monkeypatch, traced=False)
+    traced, spans = transfers_of_run(inst, monkeypatch, traced=True)
+    extra = list(traced)
+    for size in untraced:
+        extra.remove(size)
+    # what tracing adds: the stages' row counts, S integers (or one) a stage
+    assert extra and max(extra) <= S, extra
+    stages = [sp for sp in spans if sp.kind == "stage"]
+    joins = [sp for sp in stages if sp.name == "mpp:Join"]
+    # the two joins' counts came with their overflow flags, in both runs
+    assert len(joins) == 2 and len(extra) == len(stages) - len(joins)
+    assert all("rows" in sp.attrs for sp in stages)
+    join = next(sp for sp in stages if sp.name == "mpp:Join")
+    shards = [sp for sp in spans
+              if sp.kind == "shard" and sp.parent_id == join.span_id]
+    assert len(shards) == S
+    assert sum(sp.attrs["rows"] for sp in shards) == join.attrs["rows"] > 0

@@ -13,6 +13,7 @@ from galaxysql_tpu.devtools.checkers.jit_discipline import program_families
 from galaxysql_tpu.exec import operators as ops
 from galaxysql_tpu.exec.compile_cache import GLOBAL_COMPILE_CACHE
 from galaxysql_tpu.kernels import relational as K
+from galaxysql_tpu.parallel import mpp
 from galaxysql_tpu.parallel.mesh import make_mesh
 from galaxysql_tpu.parallel.mpp import MppExecutor
 from galaxysql_tpu.plan.physical import ExecContext
@@ -45,7 +46,8 @@ def module_names(key, program):
 @pytest.fixture(scope="module")
 def modules_by_family(tmp_path_factory):
     """Tiny TPC-H Q1/Q3/Q5/Q6 on the local engine (CPU formulation, then the
-    TPU's sort-based one) and Q3 on four virtual devices; every program then
+    TPU's sort-based one) and Q3 on four virtual devices, broadcast and
+    shuffled; every program then
     in `_JIT_CACHE`, by family."""
     data = tpch.generate(0.01)
     inst = Instance()
@@ -69,6 +71,13 @@ def modules_by_family(tmp_path_factory):
         ctx = ExecContext(inst.stores, inst.tso.next_timestamp(), [],
                           archive=inst.archive, archive_instance=inst)
         MppExecutor(ctx, make_mesh(4)).execute(plan.rel)
+        # and once more with every join down the shuffle exchange
+        inst.frag_cache.clear()
+        limit, mpp.BROADCAST_BUILD_LIMIT = mpp.BROADCAST_BUILD_LIMIT, 0
+        try:
+            MppExecutor(ctx, make_mesh(4)).execute(plan.rel)
+        finally:
+            mpp.BROADCAST_BUILD_LIMIT = limit
         found = {}
         with ops._JIT_CACHE_LOCK:
             cached = list(ops._JIT_CACHE.items())
@@ -85,7 +94,7 @@ def modules_by_family(tmp_path_factory):
 @pytest.mark.parametrize("family", [
     "agg_partial", "filter", "segment", "sort",   # local Q1/Q3/Q5/Q6
     "join_pairs", "join_gather", "bloom_query",   # the TPU's join formulation
-    "mpp_agg", "mpp_bjoin",                       # Q3 on four devices
+    "mpp_agg", "mpp_bjoin", "mpp_sjoin",          # Q3 on four devices
 ])
 def test_a_familys_programs_lower_to_modules_named_after_it(
         modules_by_family, family):
