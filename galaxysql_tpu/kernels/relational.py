@@ -1104,12 +1104,6 @@ def sort_indices(keys: Sequence[Tuple[Any, Optional[Any], bool, bool]],
 # compaction / misc
 # ---------------------------------------------------------------------------
 
-def compaction_order(live: Any) -> Tuple[Any, Any]:
-    """Stable permutation putting live rows first; returns (order, num_live)."""
-    order = jnp.argsort(~live, stable=True)
-    return order, jnp.sum(live.astype(jnp.int32))
-
-
 def limit_mask(live: Any, offset: int, count: int) -> Any:
     """LIMIT offset, count over live rows (order = physical order)."""
     rank = jnp.cumsum(live.astype(jnp.int64)) - 1

@@ -297,8 +297,10 @@ def _mix64_np(h: np.ndarray) -> np.ndarray:
 
 
 def hash_partition_of(values: np.ndarray, count: int) -> np.ndarray:
-    """Shard id per value — the same mix the device kernels use, so shard-local data
-    stays consistent with device-side repartitioning.  Routed through the native
+    """Shard id per value — the same mix the device kernels use, taken modulo
+    `count` (its low bits).  The MPP exchange deals rows by the mix's high word
+    (`parallel/exchange.repartition_by_hash`), so a table's partitioning and
+    a repartition on the same key are independent.  Routed through the native
     runtime (libgalaxystore) when available."""
     from galaxysql_tpu import native
     return native.hash_partition(np.asarray(values).astype(np.int64), count)

@@ -10,6 +10,8 @@ All functions run INSIDE shard_map blocks: arrays are the local shard ([R] lanes
 Fixed shapes: each destination gets a `quota`-sized bucket; senders report overflow so
 the host can retry with a bigger quota (the reference's unbounded buffers become
 bounded buckets + retry, consistent with the engine's overflow-retry discipline).
+`compact_rows` is what a sparse join side goes through first, so that those shapes
+follow its rows: it moves nothing between shards and cannot overflow.
 """
 
 from __future__ import annotations
@@ -50,7 +52,11 @@ def repartition_by_hash(lanes: Sequence[Any], live: Any, hash_lane: Any,
 
     lanes: per-row payload arrays [R]; live: [R] bool; hash_lane: uint64 [R].
     Returns (exchanged lanes [S*quota], exchanged live, overflow flag scalar).
-    Row r goes to shard hash % S; each (src, dst) pair carries `quota` slots.
+    Row r goes to shard (hash >> 32) % S; each (src, dst) pair carries `quota`
+    slots.  The HIGH word, because tables are partitioned on the low bits of
+    the same mix (`meta/catalog.hash_partition_of`): from the low bits a join
+    on the partition key would send every row of a shard to one destination,
+    S times the uniform share that the quotas are sized for.
     """
     with jax.named_scope("exchange/repartition"):
         return _repartition_by_hash(lanes, live, hash_lane, quota)
@@ -59,7 +65,8 @@ def repartition_by_hash(lanes: Sequence[Any], live: Any, hash_lane: Any,
 def _repartition_by_hash(lanes, live, hash_lane, quota):
     ns = jax.lax.axis_size(AXIS)
     n = live.shape[0]
-    dest = (hash_lane % jnp.uint64(ns)).astype(jnp.int32)
+    dest = ((hash_lane >> jnp.uint64(32)).astype(jnp.uint32)
+            % jnp.uint32(ns)).astype(jnp.int32)
     # dead rows: send nowhere (dest stays, live=False travels with them)
     order = jnp.lexsort((jnp.arange(n), jnp.where(live, dest, ns)))
     dest_s = dest[order]
@@ -84,6 +91,24 @@ def _repartition_by_hash(lanes, live, hash_lane, quota):
     live_buf = jnp.zeros(ns * quota, dtype=jnp.bool_).at[flat].set(ok, mode="drop")
     live_x = jax.lax.all_to_all(live_buf.reshape(ns, quota), AXIS, 0, 0).reshape(-1)
     return out_lanes, live_x, overflow
+
+
+def compact_rows(lanes: Sequence[Any], live: Any,
+                 rows: int) -> Tuple[List[Any], Any]:
+    """The shard's live rows moved, in their order, to the front of `rows`
+    slots: what a join side goes through before it is exchanged, so that
+    quotas and `cap` follow rows and not the slots a filter left empty.
+
+    Returns (lanes [rows], live [rows]).  `rows` is at least the shard's live
+    count (the caller read it), so nothing is dropped and no flag is needed.
+    Gathers only: a running count of `live`, the position of the j-th live
+    row by a search of that count, one gather a lane."""
+    with jax.named_scope("exchange/compact"):
+        run = jnp.cumsum(live, dtype=jnp.int32)
+        nth = jnp.arange(1, rows + 1, dtype=jnp.int32)
+        pos = jnp.minimum(jnp.searchsorted(run, nth, side="left"),
+                          live.shape[0] - 1)
+        return [lane[pos] for lane in lanes], nth <= run[-1]
 
 
 def broadcast_all(lanes: Sequence[Any], live: Any) -> Tuple[List[Any], Any]:

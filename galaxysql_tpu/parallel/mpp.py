@@ -53,9 +53,15 @@ REP = P()
 # interconnect), the slots those buffers held and the rows found live in them
 # (both summed over the shards), overflow retries of the capacity ladders, and
 # MPP statements, so that a per-statement value is a ratio of two of these.
+# `compactions` counts the join sides `_compact` moved into fewer slots before
+# their exchange, `compact_slots_in` / `compact_slots_out` the slots those
+# sides had and kept (summed over the shards; a side that passed through as it
+# was adds to none of the three).
 EXCHANGE_STATS = {"statements": 0, "all_to_all_calls": 0, "all_to_all_bytes": 0,
                   "all_gather_calls": 0, "all_gather_bytes": 0,
-                  "slots_offered": 0, "live_rows": 0, "overflow_retries": 0}
+                  "slots_offered": 0, "live_rows": 0, "overflow_retries": 0,
+                  "compactions": 0, "compact_slots_in": 0,
+                  "compact_slots_out": 0}
 _COST_KEYS = ("all_to_all_calls", "all_to_all_bytes", "all_gather_calls",
               "all_gather_bytes", "slots")
 
@@ -143,6 +149,8 @@ class DistBatch:
     shard_rows: Optional[np.ndarray] = None
     # what the producing stage writes on its span (exchange kind, quotas, fill)
     stage_attrs: Optional[Dict[str, Any]] = None
+    # (slots in, slots out) a shard, where `_compact` made this batch
+    compacted: Optional[Tuple[int, int]] = None
 
     def env(self):
         return {n: (c.data, c.valid) for n, c in self.columns.items()}
@@ -782,14 +790,19 @@ class MppExecutor:
             build_node, probe_node = node.left, node.right
             build_keys, probe_keys = probe_keys, build_keys
 
-        build = self._build_side(node, build_node)
+        # the exchange is chosen on the ESTIMATE, before either side's rows
+        # are counted: observed counts shape the programs, not the plan
+        broadcast = estimate_rows(build_node) <= BROADCAST_BUILD_LIMIT
+        build = self._build_side(node, build_node, broadcast)
+        broadcast = broadcast or build.replicated
         probe = self.run(probe_node)
         if probe.replicated:
             probe = build_replicated_to_dist_error(node)
+        probe = self._compact(probe, broadcast)
         build_ids = list(build.columns.keys())
         probe_ids = list(probe.columns.keys())
 
-        if build.replicated or estimate_rows(build_node) <= BROADCAST_BUILD_LIMIT:
+        if broadcast:
             out = self._broadcast_join(node, build, probe, build_keys, probe_keys,
                                        build_ids, probe_ids)
         else:
@@ -806,13 +819,64 @@ class MppExecutor:
             else:
                 out = self._shuffle_join(node, build, probe, build_keys,
                                          probe_keys, build_ids, probe_ids)
-        return self._join_result(node, out, build_ids, probe_ids)
+        return self._join_result(node, out, build, probe)
 
-    def _build_side(self, node: L.Join, build_node: L.RelNode) -> DistBatch:
-        """Run (or reuse) a join's build side.  The distributed build lanes +
-        the runtime filters published from them are fragment-cached per mesh:
-        a warm join goes straight to the probe subtree with the sharded build
-        already device-resident and the filters already in hand."""
+    def _compact(self, batch: DistBatch, broadcast: bool) -> DistBatch:
+        """A join side before it is exchanged or probed, every shard's live
+        rows moved (in their order) into `R' = bucket_capacity(most live rows
+        on a shard)` slots, so that the join's quotas, its `cap` and what it
+        hands on are sized from rows and not from the slots a filter or an
+        earlier join left empty.  The counts are the producing join's own, or
+        S integers counted on the device and read here: one small transfer a
+        side, on a path that reads its overflow flags after every join.
+
+        Engages where R' is at most half the side's slots a shard; a denser
+        side (a base table) is returned as it is.  R' holds the fullest
+        shard, so nothing overflows and no ladder is needed.  The program
+        takes the name of the join it feeds (`broadcast`: the exchange the
+        caller chose; a hybrid join is a shuffle)."""
+        n = int(batch.live.shape[0])
+        if batch.replicated or not n or n % self.S:
+            return batch
+        counts = np.asarray(self._shard_rows(batch)).reshape(-1)
+        R, Rc = n // self.S, bucket_capacity(int(counts.max()))
+        if 2 * Rc > R:
+            return batch
+        ids = list(batch.columns.keys())
+        if broadcast:
+            key = ("mpp_bjoin", "compact", tuple(ids), self.S, R, Rc)
+        else:
+            key = ("mpp_sjoin", "compact", tuple(ids), self.S, R, Rc)
+
+        def builder():
+            def spmd(env, live):
+                pairs = [env[i] for i in ids]
+                lanes, keep = exchange.compact_rows(_pack_lanes(pairs), live,
+                                                    Rc)
+                return dict(zip(ids, _unpack_lanes(lanes, pairs))), keep
+
+            fn = shard_map(spmd, mesh=self.mesh, in_specs=(SHARD, SHARD),
+                           out_specs=(SHARD, SHARD), check_vma=False)
+            return jit_program(fn)
+
+        DISPATCH_STATS["dispatches"] += 1
+        cols_o, live = global_jit(key, builder)(batch.env(), batch.live)
+        EXCHANGE_STATS["compactions"] += 1
+        EXCHANGE_STATS["compact_slots_in"] += n
+        EXCHANGE_STATS["compact_slots_out"] += self.S * Rc
+        cols = {i: Column(cols_o[i][0], cols_o[i][1], c.dtype, c.dictionary)
+                for i, c in batch.columns.items()}
+        return DistBatch(cols, live, False, shard_rows=counts,
+                         compacted=(R, Rc))
+
+    def _build_side(self, node: L.Join, build_node: L.RelNode,
+                    broadcast: bool) -> DistBatch:
+        """Run (or reuse) a join's build side, compacted (`_compact`) as soon
+        as it exists, so that the runtime filter's publication reads `S x R'`
+        key slots back.  The distributed build lanes + the runtime filters
+        published from them are fragment-cached per mesh: a warm join goes
+        straight to the probe subtree with the sharded build already
+        device-resident and the filters already in hand."""
         from galaxysql_tpu.exec import fragment_cache as fc
         from galaxysql_tpu.exec import runtime_filter as rfmod
         build_is_left = build_node is node.left
@@ -843,7 +907,7 @@ class MppExecutor:
                     rfmod.publish_captured(getattr(self.ctx, "rf", None),
                                            active_specs, art.filters)
                     return art.batch
-        build = self.run(build_node)
+        build = self._compact(self.run(build_node), broadcast)
         specs = self._publish_rf(node, build, build_is_left)
         if akey is not None:
             art = fc.BuildArtifact(batch=build)
@@ -945,7 +1009,10 @@ class MppExecutor:
     def _shuffle_quotas(self, bR: int, pR: int) -> Tuple[int, int]:
         """Where the shuffle's quota ladders start: slots each (source,
         destination) pair carries, twice a uniform hash's share of the
-        side's SLOTS per shard (live rows are not known here)."""
+        side's slots per shard.  A sparse side arrives compacted
+        (`_compact`): its slots are the bucket above its fullest shard's
+        rows.  A denser one arrives as it was produced, in under twice
+        that."""
         return max(2 * bR // self.S, 128), max(2 * pR // self.S, 128)
 
     def _shuffle_join(self, node, build, probe, build_keys, probe_keys,
@@ -1254,8 +1321,11 @@ class MppExecutor:
                 raise errors.TddlError(
                     "MPP hybrid join exceeds capacity ceiling")
 
-    def _join_result(self, node, out, build_ids, probe_ids) -> DistBatch:
+    def _join_result(self, node, out, build, probe) -> DistBatch:
         (cols, live), out_rows, attrs = out
+        for name, side in (("compact_b", build), ("compact_p", probe)):
+            if side.compacted is not None:
+                attrs[name] = "%d/%d" % side.compacted  # slots a shard, in/out
         src_meta = {fid: (typ, d)
                     for fid, typ, d in (node.left.fields() + node.right.fields())}
         out_cols = {}

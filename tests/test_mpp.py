@@ -120,6 +120,44 @@ def test_shuffle_join_path(env):
         M.BROADCAST_BUILD_LIMIT = old
 
 
+SPARSE_JOINS = {
+    "inner": "SELECT o_orderkey, l_linenumber FROM orders, lineitem "
+             "WHERE o_orderkey = l_orderkey AND l_quantity < 5 "
+             "AND o_totalprice > 300000 ORDER BY 1, 2",
+    "left": "SELECT o_orderkey, l_linenumber FROM orders LEFT JOIN "
+            "(SELECT l_orderkey, l_linenumber FROM lineitem "
+            "WHERE l_quantity < 5) l ON o_orderkey = l_orderkey "
+            "WHERE o_totalprice > 300000 ORDER BY 1, 2",
+    "semi": "SELECT o_orderkey FROM orders WHERE o_totalprice > 300000 AND "
+            "o_orderkey IN (SELECT l_orderkey FROM lineitem "
+            "WHERE l_quantity < 5) ORDER BY 1",
+    "anti": "SELECT o_orderkey FROM orders WHERE o_totalprice > 300000 AND "
+            "o_orderkey NOT IN (SELECT l_orderkey FROM lineitem "
+            "WHERE l_quantity < 5) ORDER BY 1",
+}
+
+
+@pytest.mark.parametrize("exchange", ["broadcast", "shuffle"])
+@pytest.mark.parametrize("kind", sorted(SPARSE_JOINS))
+def test_join_kinds_over_compacted_sides_match_local(env, kind, exchange,
+                                                     monkeypatch):
+    """Filters leave a few rows in a hundred on both sides, so each side is
+    compacted to its live rows before the join (`MppExecutor._compact`)."""
+    import galaxysql_tpu.parallel.mpp as M
+    inst, s, mesh = env
+    if exchange == "shuffle":
+        monkeypatch.setattr(M, "BROADCAST_BUILD_LIMIT", 0)
+    inst.frag_cache.clear()
+    local = s.execute(SPARSE_JOINS[kind])
+    assert local.rows
+    before = dict(M.EXCHANGE_STATS)
+    mpp = run_mpp(inst, s, mesh, SPARSE_JOINS[kind])
+    assert_same(rows_of(mpp), local.rows, True)
+    assert M.EXCHANGE_STATS["compactions"] - before["compactions"] == 2
+    calls = "all_gather_calls" if exchange == "broadcast" else "all_to_all_calls"
+    assert M.EXCHANGE_STATS[calls] > before[calls]
+
+
 def test_semi_anti_join_mpp(env):
     inst, s, mesh = env
     sql = ("SELECT c_custkey FROM customer WHERE c_custkey IN "

@@ -1,7 +1,8 @@
 """The exchange plane on four virtual devices: TPC-H Q3 through `MppExecutor`
 against a plain pandas reference down each exchange kind and through an
-overflow retry, `EXCHANGE_STATS` against bytes and calls worked out by hand,
-the `stage:Join` span attributes in SHOW TRACE, and the traced run's host
+overflow retry, the compaction of a join's sides to their live rows,
+`EXCHANGE_STATS` against bytes, calls and slots worked out by hand, the
+`stage:Join` span attributes in SHOW TRACE, and the traced run's host
 transfers."""
 
 import numpy as np
@@ -10,6 +11,8 @@ import pytest
 
 import jax
 
+from galaxysql_tpu.chunk.batch import Column
+from galaxysql_tpu.exec.operators import bucket_capacity
 from galaxysql_tpu.parallel import mpp as M
 from galaxysql_tpu.parallel.mesh import make_mesh, shard_bucket
 from galaxysql_tpu.plan import logical as L
@@ -19,10 +22,13 @@ from galaxysql_tpu.server.instance import Instance
 from galaxysql_tpu.server.session import Session
 from galaxysql_tpu.storage import tpch
 from galaxysql_tpu.storage.tpch_queries import QUERIES
+from galaxysql_tpu.types import datatype as dt
 from galaxysql_tpu.utils import tracing
 
 S = 4
-SF, SEED = 0.02, 2147483659
+# the scale at which every side of both of Q3's joins is sparse enough to be
+# compacted: `customer` 1,875 rows a shard in 2,048 slots, a fifth BUILDING
+SF, SEED = 0.05, 2147483659
 EPOCH = np.datetime64("1970-01-01")
 
 
@@ -157,8 +163,156 @@ def test_q3_shuffle_from_a_quota_that_overflows_retries_and_equals_pandas(
     assert outer.attrs["exchange"] == "shuffle"
     assert outer.attrs["retries"] >= 1
     assert outer.attrs["quota_p"] > 128      # the ladder doubled it
+    # over compacted sides: the ladder is the quotas', not the compaction's
+    assert "compact_b" in outer.attrs and "compact_p" in outer.attrs
     assert M.EXCHANGE_STATS["overflow_retries"] - before["overflow_retries"] \
         == outer.attrs["retries"]
+
+
+# -- a join's sides, compacted to their live rows ----------------------------------
+
+
+def slots_in_out(attr):
+    slots_in, slots_out = attr.split("/")
+    return int(slots_in), int(slots_out)
+
+
+def most_rows_a_shard(spans, stage):
+    return max(sp.attrs["rows"] for sp in spans
+               if sp.kind == "shard" and sp.parent_id == stage.span_id)
+
+
+@pytest.mark.parametrize("kind", ["broadcast", "shuffle"])
+def test_q3_joins_take_their_shapes_from_the_rows_that_are_live(env, limit,
+                                                               kind):
+    inst, _s, data = env
+    first, second = second_build_estimates(inst)
+    limit(int(second) + 1 if kind == "broadcast" else int(first))
+    before = dict(M.EXCHANGE_STATS)
+    batch, spans = run_q3(inst, traced=True)
+    assert normalise(batch) == q3_reference(data)
+    joins = [sp for sp in spans if sp.name == "mpp:Join"]
+    assert exchanges(spans) == [kind, "broadcast"]
+    seen_in = seen_out = 0
+    for join in joins:
+        sides = [sp for sp in spans
+                 if sp.kind == "stage" and sp.parent_id == join.span_id]
+        assert len(sides) == 2
+        got = {}
+        for name in ("compact_b", "compact_p"):      # engaged on both sides
+            slots_in, slots_out = slots_in_out(join.attrs[name])
+            assert 2 * slots_out <= slots_in
+            got[name] = slots_out
+            seen_in += S * slots_in
+            seen_out += S * slots_out
+        # R' is the bucket over the fullest shard of each side
+        assert sorted(got.values()) == sorted(
+            bucket_capacity(most_rows_a_shard(spans, side)) for side in sides)
+        # and every shape downstream follows it, by the formulas that stood
+        if join.attrs["exchange"] == "broadcast":
+            assert join.attrs["build_slots"] == S * got["compact_b"]
+            assert join.attrs["cap"] == bucket_capacity(
+                max(2 * got["compact_p"], 1024))
+        else:
+            assert join.attrs["quota_b"] == max(2 * got["compact_b"] // S, 128)
+            assert join.attrs["quota_p"] == max(2 * got["compact_p"] // S, 128)
+            assert join.attrs["cap"] == bucket_capacity(
+                max(2 * join.attrs["quota_p"] * S, 1024))
+        assert join.attrs["retries"] == 0
+    delta = {k: M.EXCHANGE_STATS[k] - before[k] for k in before}
+    assert delta["compactions"] == 4
+    assert delta["compact_slots_in"] == seen_in
+    assert delta["compact_slots_out"] == seen_out
+
+
+R_SLOTS = 4096      # slots a shard of the hand-made batches below
+
+
+def live_mask(case):
+    rng = np.random.default_rng(28)
+    live = np.zeros((S, R_SLOTS), np.bool_)
+    if case == "all_live":
+        live[:] = True
+    elif case == "one_shard_empty":
+        live[:] = rng.random((S, R_SLOTS)) < 0.3
+        live[2] = False
+    elif case == "one_row_in_the_last_slot":
+        live[S - 1, R_SLOTS - 1] = True
+    elif case.startswith("random_"):
+        live[:] = rng.random((S, R_SLOTS)) < int(case[7:]) / 100
+    else:
+        assert case == "all_dead"
+    return live.reshape(-1)
+
+
+def hand_made(live, nullable):
+    n = live.shape[0]
+    rng = np.random.default_rng(7)
+    valid = jax.numpy.asarray(rng.random(n) < 0.7) if nullable else None
+    cols = {"k": Column(jax.numpy.arange(n, dtype=np.int64), None, dt.BIGINT),
+            "v": Column(jax.numpy.asarray(rng.integers(0, 99, n)), valid,
+                        dt.BIGINT)}
+    return M.DistBatch(cols, jax.numpy.asarray(live), False)
+
+
+def rows_by_shard(batch):
+    """[(k, v or None), ...] of each shard's live rows, in slot order."""
+    live = np.asarray(batch.live).reshape(S, -1)
+    k = np.asarray(batch.columns["k"].data).reshape(S, -1)
+    v = np.asarray(batch.columns["v"].data).reshape(S, -1)
+    valid = batch.columns["v"].valid
+    ok = np.ones_like(live) if valid is None else \
+        np.asarray(valid).reshape(S, -1)
+    return [[(int(a), int(b) if c else None)
+             for a, b, c in zip(k[s][live[s]], v[s][live[s]], ok[s][live[s]])]
+            for s in range(S)]
+
+
+@pytest.mark.parametrize("nullable", [False, True], ids=["not_null", "nulls"])
+@pytest.mark.parametrize("case", [
+    "all_live", "all_dead", "one_shard_empty", "one_row_in_the_last_slot",
+    "random_1", "random_50"])
+def test_compaction_keeps_rows_order_and_nulls(case, nullable):
+    live = live_mask(case)
+    batch = hand_made(live, nullable)
+    before = dict(M.EXCHANGE_STATS)
+    out = M.MppExecutor(None, make_mesh(S))._compact(batch, False)
+    delta = {k: M.EXCHANGE_STATS[k] - before[k] for k in before}
+    fullest = int(live.reshape(S, -1).sum(axis=1).max())
+    rows = bucket_capacity(fullest)
+    if 2 * rows > R_SLOTS:
+        # dense: passed through untouched, nothing counted
+        assert out is batch and out.compacted is None
+        assert not any(delta.values())
+        assert case in ("all_live", "random_50")
+        return
+    assert out.compacted == (R_SLOTS, rows)
+    assert out.live.shape == (S * rows,) and not out.replicated
+    assert (out.columns["v"].valid is None) == (not nullable)
+    assert rows_by_shard(out) == rows_by_shard(batch)
+    assert list(out.shard_rows) == list(live.reshape(S, -1).sum(axis=1))
+    # live rows first on every shard: the slots behind them are dead
+    packed = np.asarray(out.live).reshape(S, rows)
+    assert all(not packed[s, int(packed[s].sum()):].any() for s in range(S))
+    assert delta["compactions"] == 1 and not delta["overflow_retries"]
+
+
+def test_exchange_stats_of_one_compaction_by_hand():
+    # 100 rows a shard in 4,096 slots: the bucket over 100 is the smallest,
+    # 1,024; then a batch with every slot live, which adds nothing
+    live = np.zeros((S, R_SLOTS), np.bool_)
+    live[:, ::41][:, :100] = True
+    assert live.sum() == S * 100
+    ex = M.MppExecutor(None, make_mesh(S))
+    before = dict(M.EXCHANGE_STATS)
+    out = ex._compact(hand_made(live.reshape(-1), False), True)
+    ex._compact(hand_made(np.ones(S * R_SLOTS, np.bool_), False), True)
+    delta = {k: M.EXCHANGE_STATS[k] - before[k] for k in before}
+    assert out.compacted == (4096, 1024)
+    assert delta.pop("compactions") == 1
+    assert delta.pop("compact_slots_in") == S * 4096
+    assert delta.pop("compact_slots_out") == S * 1024
+    assert not any(delta.values())      # no exchange, no statement
 
 
 # -- EXCHANGE_STATS against a count by hand ----------------------------------------
@@ -218,6 +372,8 @@ def test_exchange_stats_of_one_repartition_by_hand(tiny, limit):
     assert got["all_gather_calls"] == 0 and got["all_gather_bytes"] == 0
     assert got["slots_offered"] == S * (S * qb + S * qp)
     assert got["live_rows"] == 400 + 3000 and got["overflow_retries"] == 0
+    # every slot of both tables is live: neither side was compacted
+    assert got["compactions"] == got["compact_slots_in"] == 0
 
 
 def test_exchange_stats_of_one_broadcast_by_hand(tiny, limit):
@@ -231,6 +387,7 @@ def test_exchange_stats_of_one_broadcast_by_hand(tiny, limit):
     assert got["all_gather_bytes"] == S * R * 17
     assert got["slots_offered"] == S * S * R
     assert got["live_rows"] == S * 400
+    assert got["compactions"] == got["compact_slots_out"] == 0
 
 
 # -- spans in SHOW TRACE ------------------------------------------------------------
@@ -252,9 +409,10 @@ def test_show_trace_of_q3_shows_both_joins_with_quotas_cap_and_fill(env, limit):
     shuffle, broadcast = joins
     assert "exchange=shuffle" in shuffle and "exchange=broadcast" in broadcast
     for attr in ("quota_b=", "quota_p=", "cap=", "retries=0", "fill=",
-                 "out_fill=", "rows="):
+                 "out_fill=", "rows=", "compact_b=", "compact_p="):
         assert attr in shuffle, (attr, shuffle)
-    for attr in ("build_slots=", "cap=", "retries=0", "fill=", "rows="):
+    for attr in ("build_slots=", "cap=", "retries=0", "fill=", "rows=",
+                 "compact_b=", "compact_p="):
         assert attr in broadcast, (attr, broadcast)
     # the Chrome export carries the same attributes
     prof = inst.profiles.entries()[-1]
