@@ -68,14 +68,6 @@ trace-smoke:
 overload-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m overload -p no:cacheprovider
 
-# overload bench: closed-loop TP point serving with and without a concurrent
-# AP flood (admission on), reporting TP QPS/p99 deltas + shed rate
-bench-overload:
-	BENCH_PLATFORM=cpu $(PY) bench.py --overload-only
-
-bench:
-	$(PY) bench.py
-
 # the served SQL path on the chip: TPC-H SF1 over the MySQL wire + a TP leg,
 # one process, every result checked against a pandas reference.  Fails
 # without a TPU (python chip_smoke.py --dry-run-cpu --sf 0.02 debugs on a CPU)
@@ -84,13 +76,11 @@ chip-smoke:
 
 # fast batching smoke: the batching marker suite (batched vs sequential
 # bit-identical results under 100+ concurrent sessions, poisoned-key error
-# isolation, snapshot/txn bypass edges, static-bucket retrace guard) plus the
-# closed-loop multi-session serving bench (QPS/chip + p99, batching on vs off)
+# isolation, snapshot/txn bypass edges, static-bucket retrace guard)
 # (GALAXYSQL_LOCKDEP=1: every concurrency test doubles as a lock-order
 # proof — the runtime witness fails loudly on any acquisition-graph cycle)
 batch-smoke:
 	JAX_PLATFORMS=cpu GALAXYSQL_LOCKDEP=1 $(PY) -m pytest tests/ -q -m batching -p no:cacheprovider
-	BENCH_PLATFORM=cpu BENCH_BATCH_SESSIONS=100,1000 $(PY) bench.py --batch-only
 
 # DML batching smoke: the dml_batch marker suite (batched vs sequential
 # bit-identical table state under 100+ concurrent write sessions, poison-key
@@ -100,11 +90,6 @@ batch-smoke:
 # (GALAXYSQL_LOCKDEP=1: the lockdep witness rides every write-path test)
 dml-smoke:
 	JAX_PLATFORMS=cpu GALAXYSQL_LOCKDEP=1 $(PY) -m pytest tests/ -q -m dml_batch -p no:cacheprovider
-
-# DML bench: closed-loop point-DML + mixed read/write serving, DML batching
-# on vs off (BENCH json lines on stdout; BENCH_DML_SESSIONS=64,256 default)
-bench-dml:
-	BENCH_PLATFORM=cpu $(PY) bench.py --dml-only
 
 # chaos smoke: the fault-injection suite over a real worker subprocess —
 # retry transparency + dedupe-window exactly-once (reply-leg drop), circuit
@@ -122,11 +107,6 @@ chaos-smoke:
 # hot-key-set change, the hatch trio, and shard-skew observability surfaces
 skew-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m skew -p no:cacheprovider
-
-# skew bench: Zipf theta sweep on the Q9-like join family, skew-on vs
-# skew-off, 8 virtual devices (BENCH json lines on stdout)
-bench-skew:
-	BENCH_PLATFORM=cpu $(PY) bench.py --skew-only
 
 # workload-insight smoke: statement-digest aggregation (exec/error counts,
 # windows, digest stability across literals), the event journal, slow-log
@@ -157,25 +137,13 @@ rebalance-smoke:
 chaos-rebalance:
 	JAX_PLATFORMS=cpu GALAXYSQL_LOCKDEP=1 $(PY) -m pytest tests/ -q -m rebalance_chaos -p no:cacheprovider
 
-# rebalance bench: closed-loop point serving measured quiesced vs during a
-# live SPLIT (rebalance-while-serving QPS dip + p99; BENCH json on stdout)
-bench-rebalance:
-	BENCH_PLATFORM=cpu $(PY) bench.py --rebalance-only
-
-# kernel smoke: the kernel-tier matrix — Pallas join/agg vs reference
-# bit-identity (NULL keys, empty build, duplicate keys, overflow-ladder
-# doubling, both hybrid orientations, TPC-H Q5/Q9 on-vs-off), the
-# escape-hatch trio proven structurally off-path, and the persistent AOT
-# compile cache (restart round trip with zero steady retraces, corrupted
-# entries recompiling, metrics/EXPLAIN surfaces)
+# kernel smoke: the chip's sort formulations against the CPU's scatter ones
+# as relations (NULL keys, empty build, duplicate keys, overflow-ladder
+# doubling, both hybrid orientations, TPC-H Q1/Q3/Q5/Q6/Q9), and the
+# persistent AOT compile cache (restart round trip with zero steady
+# retraces, corrupted entries recompiling, metrics/EXPLAIN surfaces)
 kernel-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m kernel -p no:cacheprovider
-
-# kernel bench: Pallas-vs-reference join/agg rows/s (interpret mode on CPU —
-# the honest number until a TPU answers) + the AOT compile-cache cold-vs-warm
-# restart compile_ms comparison (BENCH json on stdout)
-bench-kernels:
-	BENCH_PLATFORM=cpu $(PY) bench.py --kernels-only
 
 # self-heal smoke: the quarantine state machine end-to-end — a genuine
 # stats-driven join-order regression auto-rolls-back, verifies over
@@ -195,13 +163,6 @@ heal-smoke:
 slo-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m slo -p no:cacheprovider
 
-# SLO bench: steady-state serving snapshotted through the metric history
-# (slo_snapshot: history-derived qps + p99 + burn state) and the sampler
-# overhead measurement — closed-loop QPS with the history/SLO tick on vs
-# hatched off (target: <= 3% delta; BENCH json on stdout)
-bench-slo:
-	BENCH_PLATFORM=cpu $(PY) bench.py --slo-only
-
 # incident flight-recorder smoke: the incident marker suite — tail-sampled
 # trace retention (slow/shed/error tails kept at sample_rate=0, phase
 # breakdown on every root span), the injected-burn end-to-end (one bundle,
@@ -212,12 +173,6 @@ bench-slo:
 incident-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m incident -p no:cacheprovider
 
-# tracing-overhead bench: 32-session batched-serving closed loop with
-# always-on tail-sampled tracing vs GALAXYSQL_TRACING=0 — overhead target
-# <= 3%, dispatch counts unchanged, steady retraces 0 (BENCH_r14.json)
-bench-tracing:
-	BENCH_PLATFORM=cpu $(PY) bench.py --tracing-only
-
 # serving-tier smoke: the router marker suite — consistent-hash affinity,
 # session pinning + typed-once failover, cluster-wide admission gossip,
 # placement-driven locality, SHOW COORDINATORS / SHOW CLUSTER surfaces,
@@ -226,12 +181,6 @@ bench-tracing:
 scaleout-smoke:
 	JAX_PLATFORMS=cpu GALAXYSQL_LOCKDEP=1 $(PY) -m pytest tests/ -q \
 		-m router -p no:cacheprovider
-
-# serving-tier curve: 1/2/4 coordinator subprocesses over one shared
-# metadb, closed-loop point workload through the front router — aggregate
-# QPS, p99, affinity hit rate, gossip staleness into BENCH_r12.json
-bench-scaleout:
-	BENCH_PLATFORM=cpu $(PY) bench.py --scaleout-only
 
 # columnar HTAP replica: CDC-tailed delta+base tier bit-identical to the
 # row store at arbitrary watermarks, crash-resume, compaction vs racing
@@ -242,14 +191,7 @@ columnar-smoke:
 	JAX_PLATFORMS=cpu GALAXYSQL_LOCKDEP=1 $(PY) -m pytest tests/ -q \
 		-m columnar -p no:cacheprovider
 
-# HTAP curve: columnar replica vs row store rows/s on AP scans at SF0.2
-# under sustained DML, plus freshness-lag series — into BENCH_r13.json
-bench-htap:
-	BENCH_PLATFORM=cpu $(PY) bench.py --htap-only
-
-.PHONY: tier1 fusion-smoke obs-smoke rf-smoke cache-smoke trace-smoke bench \
-	batch-smoke chaos-smoke skew-smoke bench-skew summary-smoke heal-smoke \
-	overload-smoke bench-overload dml-smoke bench-dml lint lint-smoke \
-	rebalance-smoke chaos-rebalance bench-rebalance kernel-smoke \
-	bench-kernels slo-smoke bench-slo scaleout-smoke bench-scaleout \
-	columnar-smoke bench-htap incident-smoke bench-tracing chip-smoke
+.PHONY: tier1 fusion-smoke obs-smoke rf-smoke cache-smoke trace-smoke \
+	batch-smoke chaos-smoke skew-smoke summary-smoke heal-smoke overload-smoke \
+	dml-smoke lint lint-smoke rebalance-smoke chaos-rebalance kernel-smoke \
+	slo-smoke scaleout-smoke columnar-smoke incident-smoke chip-smoke

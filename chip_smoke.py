@@ -8,11 +8,11 @@ a socket with `MiniClient`:
 
 - loads TPC-H at `--sf` (default 1, the smallest scale the specification
   defines) generated from `--seed`, in bulk through `TableStore.insert_arrays`
-  + `ANALYZE TABLE`, as `bench.py` and `__graft_entry__` do;
+  + `ANALYZE TABLE`, as `__graft_entry__` does;
 - AP leg: Q1, Q6, Q3, Q5 over the wire, each once cold and once more under
-  `FRAGMENT_CACHE(OFF)` (a warm fragment-cache hit executes nothing); Q3/Q5
-  also under `KERNEL(OFF)`.  Every result is compared in full with a plain
-  pandas/numpy reference over the same generated data, outside any timing;
+  `FRAGMENT_CACHE(OFF)` (a warm fragment-cache hit executes nothing).  Every
+  result is compared in full with a plain pandas/numpy reference over the
+  same generated data, outside any timing;
 - TP leg: a primary-key table, a few thousand INSERTed rows, point SELECTs,
   an UPDATE read back by the same and by a second connection,
   BEGIN/INSERT/ROLLBACK leaving no row.  Acknowledged writes read back exactly.
@@ -45,7 +45,6 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 AP_QUERIES = (1, 6, 3, 5)
 NO_FRAG = "/*+TDDL:FRAGMENT_CACHE(OFF)*/ "
-NO_FRAG_NO_KERNEL = "/*+TDDL:FRAGMENT_CACHE(OFF) KERNEL(OFF)*/ "
 
 
 def days(y: int, m: int, d: int) -> int:
@@ -291,7 +290,6 @@ def main():
     from galaxysql_tpu.exec import operators as ops
     from galaxysql_tpu.exec.device_cache import (GLOBAL_DEVICE_CACHE,
                                                  hbm_high_water)
-    from galaxysql_tpu.kernels import relational as K
     from galaxysql_tpu.parallel.mesh import GLOBAL_MESH_CACHE
     from galaxysql_tpu.storage import tpch
     from galaxysql_tpu.storage.tpch_queries import QUERIES
@@ -359,10 +357,6 @@ def main():
         entry = {"correct": True, "engine": engine, "rows": len(cold),
                  "cold_wall_s_observed": round(cold_s, 3),
                  "warm_wall_s_observed": round(warm_s, 3)}
-        if qid in (3, 5):
-            _, ref_sel = c.query(NO_FRAG_NO_KERNEL + q)
-            assert ref_sel == warm, f"Q{qid}: KERNEL(OFF) != default selector"
-            entry["kernel_off_agrees"] = True
         queries[f"q{qid}"] = entry
         say(f"q{qid}", **entry)
 
@@ -470,7 +464,6 @@ def main():
             k: {"programs": n, "wall_s": round(ms / 1000, 1)}
             for k, (n, ms) in sorted(ops.COMPILE_MS_BY_PROGRAM.items(),
                                      key=lambda kv: -kv[1][1])},
-        "kernel_stats": dict(K.KERNEL_STATS),
         "mpp_queries": inst.counters["mpp_queries"],
         "mpp_fallback_local": inst.counters["mpp_fallback_local"],
         "device_cache_bytes": GLOBAL_DEVICE_CACHE._bytes,

@@ -108,14 +108,7 @@ ENABLE_SKEW_EXECUTION = _p(
     "broadcast/shuffle joins and salted aggregation on the MPP mesh; "
     "planted skew plans go inert when off (cached plans stay valid)")
 
-# --- kernel tier / compile cache ----------------------------------------------
-ENABLE_PALLAS_KERNELS = _p(
-    "ENABLE_PALLAS_KERNELS", True,
-    "Pallas join/agg kernel tier (kernels/pallas_join.py, pallas_agg.py): "
-    "auto-selected on TPU above the stats row floor; the reference "
-    "formulations remain the CPU path and correctness oracle.  Per-statement "
-    "override via KERNEL(OFF|PALLAS|ON) hint; GALAXYSQL_PALLAS=0 env kills "
-    "the tier process-wide")
+# --- compile cache ------------------------------------------------------------
 ENABLE_COMPILE_CACHE = _p(
     "ENABLE_COMPILE_CACHE", True,
     "persistent AOT compile cache under data_dir (exec/compile_cache.py): "

@@ -25,7 +25,7 @@ these passes mechanize them:
 - **pallas-raw**: `pl.pallas_call(...)` constructs a kernel program with the
   exact same escape hazard — same rule shape: legal only inside a
   `global_jit` builder, so Pallas kernels are cached per static shape and
-  counted like every other program (kernels/pallas_join.py idiom).
+  counted like every other program.
 - **jit-device-sync**: `.item()` / `.block_until_ready()` on the default
   query path forces a host<->device sync per call.  Flagged in the hot-path
   layers (exec/, kernels/, parallel/, chunk/, server/, storage/) unless the

@@ -55,22 +55,24 @@ _JIT_CACHE: "collections.OrderedDict[Tuple, Any]" = collections.OrderedDict()
 _JIT_CACHE_LOCK = __import__("threading").Lock()
 _JIT_CACHE_LIMIT = 4096
 
-# per-batch dispatch accounting (bench.py microbenchmark): every
+# per-batch dispatch accounting (benchmarks/metrics/ap_dispatches_per_stmt.py,
+# tp_dispatches_per_op.py and the tests' dispatch guards read it): every
 # streaming-program invocation on one batch bumps `dispatches` — FilterOp,
 # ProjectOp, a fused segment, the HashAgg partial, and the per-node MPP
 # filter/project/agg programs each count 1 per batch.  A "dispatch" is one
 # program-boundary crossing: an XLA dispatch on the device path, a host-np
 # program call on the TP path (no jax dispatch there, but the same
 # per-operator Python boundary the fuser removes).  Plain int adds: no device
-# sync, no lock (approximate under concurrency, exact in the bench loop).
+# sync, no lock (approximate under concurrency, exact under one client).
 DISPATCH_STATS = {"dispatches": 0}
 
 # XLA trace+compile accounting: `retraces` counts global_jit builder runs
 # (cache misses — each is a fresh program trace), `compile_ms` accumulates the
 # wall time of each fresh program's FIRST invocation, which is where jax
 # synchronously traces + compiles before dispatching.  Host-side plain adds;
-# bench.py snapshots these per query so compile-cache regressions surface in
-# the perf trajectory, and traced queries get one `compile` span per event.
+# benchmarks/metrics/programs_compiled.py, compile_s.py and
+# compiles_in_window.py read them, the statement summary snapshots them per
+# query, and traced queries get one `compile` span per event.
 COMPILE_STATS = {"retraces": 0, "compile_ms": 0.0, "cache_hits": 0}
 # the same first-invocation wall time split by program family (the key's
 # first element: agg_partial, join_pairs, mpp_agg, ...) with the number of
@@ -829,8 +831,7 @@ class HashAggOp(Operator):
     def _partial_fn(self, max_groups: int):
         domains = self._matmul_domains()
         prelude = self.prelude
-        key = ("agg_partial", exec_platform(), K.kernel_selector_key(),
-               self._cache_key(), max_groups,
+        key = ("agg_partial", exec_platform(), self._cache_key(), max_groups,
                tuple(domains) if domains is not None else None,
                prelude.key() if prelude is not None else None)
 
@@ -877,8 +878,7 @@ class HashAggOp(Operator):
                   merge_specs: Tuple[K.AggSpec, ...]):
         # shared across ALL aggregations: behavior depends only on the merge specs and
         # capacity (key/agg lane dtypes are part of jit's own trace signature)
-        key = ("agg_merge", exec_platform(), K.kernel_selector_key(),
-               max_groups, n_keys, merge_specs)
+        key = ("agg_merge", exec_platform(), max_groups, n_keys, merge_specs)
 
         def build():
             def run(key_lanes, input_lanes, live):
@@ -1227,8 +1227,7 @@ class HashJoinOp(Operator):
 
     def _pairs_fn(self, cap: int):
         prelude = self.probe_prelude
-        key = ("join_pairs", exec_platform(), K.kernel_selector_key(),
-               cap,
+        key = ("join_pairs", exec_platform(), cap,
                tuple(expr_cache_key(e) for e in self.build_keys),
                tuple(expr_cache_key(e) for e in self.probe_keys),
                prelude.key() if prelude is not None else None)
@@ -1258,8 +1257,7 @@ class HashJoinOp(Operator):
         whole join (the CSR is also reused across probe batches/retries)."""
         nb = build_batch.capacity
         M = 1 << max(4, int(nb * 4 - 1).bit_length())
-        key = ("join_build_slots", exec_platform(),
-               K.kernel_selector_key(), nb, M,
+        key = ("join_build_slots", exec_platform(), nb, M,
                tuple(expr_cache_key(e) for e in self.build_keys))
 
         def build_fn():
@@ -1279,8 +1277,7 @@ class HashJoinOp(Operator):
 
     def _probe_csr_fn(self, cap: int, M: int, nb: int):
         prelude = self.probe_prelude
-        key = ("join_probe_csr", exec_platform(),
-               K.kernel_selector_key(), cap, M, nb,
+        key = ("join_probe_csr", exec_platform(), cap, M, nb,
                tuple(expr_cache_key(e) for e in self.build_keys),
                tuple(expr_cache_key(e) for e in self.probe_keys),
                prelude.key() if prelude is not None else None)

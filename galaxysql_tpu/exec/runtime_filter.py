@@ -56,8 +56,9 @@ RF_PUBLISH_MAX_LANES = RF_PUBLISH_MAX_ROWS * 4  # transfer-size bail-out:
 # a padded/mostly-dead build keeps its filter as long as the key-lane
 # transfer stays bounded; above this even the transfer is not worth it
 
-# module-level accounting (bench.py probe-rows delta metric; the DISPATCH_STATS
-# idiom: plain int adds, no locks, reset around measured runs).  `enabled`
+# module-level accounting (read by tests/test_runtime_filter.py and
+# meta/statement_summary.py; the DISPATCH_STATS idiom: plain int adds, no
+# locks, reset around measured runs).  `enabled`
 # gates the one extra pre-bloom num_live() sync in HashJoinOp so the default
 # hot path pays nothing.
 RF_STATS = {"enabled": False, "probe_rows": 0, "rows_pruned": 0,

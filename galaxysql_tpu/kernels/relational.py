@@ -27,9 +27,6 @@ All kernels are fixed-shape: output capacity is a static argument and kernels re
 
 from __future__ import annotations
 
-import contextlib
-import os
-import threading
 from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -70,84 +67,6 @@ def hash_columns(cols: Sequence[Tuple[Any, Optional[Any]]]) -> Any:
         h = lane if h is None else _mix64(h * np.uint64(31) + lane + _GOLDEN)
     assert h is not None
     return h
-
-
-# ---------------------------------------------------------------------------
-# kernel-tier selector: Pallas vs reference formulation
-# ---------------------------------------------------------------------------
-# The hatch trio, outermost wins:  GALAXYSQL_PALLAS=0 env kills the tier for
-# the whole process; the ENABLE_PALLAS_KERNELS param (via `exec_kernel_mode`)
-# gates it per instance/session; the KERNEL(OFF|PALLAS|ON) hint per statement.
-# Selection happens at TRACE time (row counts are static shapes), so the mode
-# must ride the `global_jit` key (`kernel_selector_key`) — a flipped hint is a
-# DIFFERENT program, not a silent reuse of the wrong formulation.
-
-_PALLAS_ENV_OFF = os.environ.get("GALAXYSQL_PALLAS", "1") == "0"
-
-# trace-time selection counters — the dispatch-count guards in the `kernel`
-# test matrix prove a gated-off selector never even CONSIDERED Pallas for a
-# traced program (structurally off-path, not merely numerically equal)
-KERNEL_STATS = {"pallas": 0, "reference": 0}
-
-_KERNEL_TLS = threading.local()
-
-
-def kernel_mode() -> str:
-    """Current thread's selector mode: 'auto' | 'off' | 'pallas'."""
-    return getattr(_KERNEL_TLS, "mode", "auto")
-
-
-@contextlib.contextmanager
-def kernel_scope(mode: str):
-    """Scope the selector mode for one statement (thread-local: concurrent
-    sessions pick their own formulation without racing)."""
-    prev = getattr(_KERNEL_TLS, "mode", "auto")
-    _KERNEL_TLS.mode = mode
-    try:
-        yield
-    finally:
-        _KERNEL_TLS.mode = prev
-
-
-def kernel_selector_key() -> str:
-    """Token for `global_jit` keys of programs that trace through the
-    selector (join/agg operator and MPP programs)."""
-    return "k=" + kernel_mode()
-
-
-def use_pallas() -> bool:
-    """Trace-time formulation choice for one kernel call site.
-
-    Auto mode selects no Pallas kernel: on a TPU v5e at TPC-H SF1 shapes Mosaic
-    refused all four (chip run, PR 21 — `build_slots`, `hash_slots`,
-    `hash_place`: "64-bit types are not supported"; `expand_offsets`:
-    RecursionError in lowering; CHANGES.md has the record).  They stay
-    reachable through KERNEL(PALLAS), where a lowering failure is the user's
-    typed error; nothing swaps formulations at run time."""
-    if not _PALLAS_ENV_OFF and kernel_mode() == "pallas":
-        KERNEL_STATS["pallas"] += 1
-        return True
-    KERNEL_STATS["reference"] += 1
-    return False
-
-
-def exec_kernel_mode(hints, instance, session_overlay=None) -> str:
-    """Resolve the selector mode for one statement: KERNEL hint beats the
-    ENABLE_PALLAS_KERNELS param (session > instance > default); the env hatch
-    is enforced inside `use_pallas` and beats everything.  KERNEL(PALLAS)
-    forces the Pallas tier below the auto row floor; KERNEL(ON) restores
-    auto selection under a disabling param."""
-    h = (hints or {}).get("kernel")
-    if h == "off":
-        return "off"
-    if h == "pallas":
-        return "pallas"
-    if h == "on":
-        return "auto"
-    if instance is not None and getattr(instance, "config", None) is not None:
-        if not instance.config.get("ENABLE_PALLAS_KERNELS", session_overlay):
-            return "off"
-    return "auto"
 
 
 # ---------------------------------------------------------------------------
@@ -524,9 +443,8 @@ def _ident_lanes(keys):
 
 def _hash_place(ident: Sequence[Tuple[Any, Optional[Any]]], live: Any,
                 s0: Any, step: Any, M: int, max_rounds: int):
-    """Reference slot placement for `hash_groupby` — and the correctness
-    oracle the Pallas kernel (`pallas_agg.hash_place`) must match bit-for-bit.
-    Vectorized scatter-min election rounds with an early-exit while_loop."""
+    """Slot placement for `hash_groupby`: vectorized scatter-min election
+    rounds with an early-exit while_loop."""
     n = live.shape[0]
     rowid = jnp.arange(n, dtype=jnp.int32)
     sentinel = jnp.int32(n)
@@ -592,12 +510,7 @@ def hash_groupby(keys: Sequence[Tuple[Any, Optional[Any]]],
     step = ((h >> jnp.uint64(32)) << jnp.uint64(1)) | jnp.uint64(1)
 
     sentinel = jnp.int32(n)
-    if n > 0 and use_pallas():
-        from galaxysql_tpu.kernels import pallas_agg
-        rep, resolved, gid = pallas_agg.hash_place(ident, live, s0, step,
-                                                   M, max_rounds)
-    else:
-        rep, resolved, gid = _hash_place(ident, live, s0, step, M, max_rounds)
+    rep, resolved, gid = _hash_place(ident, live, s0, step, M, max_rounds)
     overflow = jnp.any(~resolved)
 
     placed = resolved & live
@@ -921,11 +834,7 @@ def _expand_offsets(counts, starts, npr: int, cap: int):
     """Ragged probe->pair expansion: scatter each non-empty probe row's id at
     its first pair slot, then forward-fill with cummax (starts are unique
     among non-empty rows) — ~10x faster than searchsorted(offsets,
-    arange(cap)) on XLA:CPU.  Selector-gated: the Pallas variant runs the
-    same scatter + running-max sweep in VMEM."""
-    if npr > 0 and cap > 0 and use_pallas():
-        from galaxysql_tpu.kernels import pallas_join
-        return pallas_join.expand_offsets(counts, starts, cap)
+    arange(cap)) on XLA:CPU."""
     scatter_at = jnp.where(counts > 0, starts, jnp.int64(cap))
     p_of = jnp.zeros(cap, jnp.int32).at[scatter_at].max(
         jnp.arange(npr, dtype=jnp.int32), mode="drop")
@@ -936,7 +845,7 @@ def _hash_join_pairs_table(build_keys, probe_keys, build_live, probe_live,
                            cap: int) -> JoinPairs:
     """CPU join: slot-table CSR over the build side, gather-probe, scatter
     expand.  Thin composition of `_device_csr` + `hash_join_probe_csr` — the
-    hybrid probe and the Pallas tier ride the exact same pipeline."""
+    hybrid probe rides the exact same pipeline."""
     nb = build_keys[0][0].shape[0]
     perm, slot_starts, slot_counts, M = _device_csr(build_keys, build_live, nb)
     return hash_join_probe_csr(build_keys, probe_keys, build_live, probe_live,
@@ -953,10 +862,6 @@ def hash_join_build_slots(build_keys: Sequence[Tuple[Any, Optional[Any]]],
     computes the slot id lane (hash + mask) that both sides must agree on.
     Dead/NULL-key rows get the scratch slot M."""
     b_live = _effective_live(build_keys, build_live)
-    nb = build_keys[0][0].shape[0]
-    if nb > 0 and use_pallas():
-        from galaxysql_tpu.kernels import pallas_join
-        return pallas_join.build_slots(build_keys, b_live, M)
     h_b = hash_columns(build_keys)
     s_b = (h_b & jnp.uint64(M - 1)).astype(jnp.int32)
     return jnp.where(b_live, s_b, jnp.int32(M))
@@ -976,12 +881,8 @@ def hash_join_probe_csr(build_keys, probe_keys, build_live, probe_live,
     nb = build_keys[0][0].shape[0]
     npr = probe_keys[0][0].shape[0]
 
-    if npr > 0 and use_pallas():
-        from galaxysql_tpu.kernels import pallas_join
-        s_p = pallas_join.hash_slots(probe_keys, M)
-    else:
-        h_p = hash_columns(probe_keys)
-        s_p = (h_p & jnp.uint64(M - 1)).astype(jnp.int32)
+    h_p = hash_columns(probe_keys)
+    s_p = (h_p & jnp.uint64(M - 1)).astype(jnp.int32)
     counts = jnp.where(p_live, slot_counts[s_p].astype(jnp.int64), 0)
 
     offsets = jnp.cumsum(counts)
@@ -1037,8 +938,8 @@ def hash_join_probe_hybrid(build_keys: Sequence[Tuple[Any, Optional[Any]]],
     fixed-shape/overflow contract.  Both lanes go through the same build-slot
     construction (`hash_join_build_slots` inside `_device_csr`), and the
     probe rides `hash_join_probe_csr` on EVERY backend — one implementation
-    shared with the batch-streamed CSR probe and the Pallas probe kernel
-    instead of a re-derived pair enumeration per entry point."""
+    shared with the batch-streamed CSR probe instead of a re-derived pair
+    enumeration per entry point."""
     nb = build_keys[0][0].shape[0]
     perm, slot_starts, slot_counts, M = _device_csr(build_keys, build_live, nb)
     return hash_join_probe_csr(build_keys, probe_keys, build_live, probe_live,

@@ -622,7 +622,7 @@ class MppExecutor:
 
     def _agg_round(self, groups, child, inputs, specs, merge_specs, G,
                    prelude=None):
-        key = ("mpp_agg", exec_platform(), K.kernel_selector_key(),
+        key = ("mpp_agg", exec_platform(),
                tuple((n, expr_cache_key(e)) for n, e in groups),
                tuple(expr_cache_key(e) for e in inputs), specs, G,
                child.replicated, self.S,
@@ -709,7 +709,7 @@ class MppExecutor:
 
     def _salted_agg_round(self, groups, child, inputs, specs, merge_specs,
                           G, factor, quota, prelude=None):
-        key = ("mpp_agg_salt", exec_platform(), K.kernel_selector_key(),
+        key = ("mpp_agg_salt", exec_platform(),
                tuple((n, expr_cache_key(e)) for n, e in groups),
                tuple(expr_cache_key(e) for e in inputs), specs, G, factor,
                self.S, quota,
@@ -953,7 +953,7 @@ class MppExecutor:
         cap = bucket_capacity(max(probe_R * 2, 1024))
         retries = 0
         while True:
-            key = ("mpp_bjoin", node.kind, K.kernel_selector_key(),
+            key = ("mpp_bjoin", node.kind,
                    tuple(expr_cache_key(e) for e in build_keys),
                    tuple(expr_cache_key(e) for e in probe_keys),
                    expr_cache_key(node.residual) if node.residual is not None else None,
@@ -1023,7 +1023,7 @@ class MppExecutor:
         cap = bucket_capacity(max(2 * quota_p * self.S, 1024))
         retries = 0
         while True:
-            key = ("mpp_sjoin", node.kind, K.kernel_selector_key(),
+            key = ("mpp_sjoin", node.kind,
                    tuple(expr_cache_key(e) for e in build_keys),
                    tuple(expr_cache_key(e) for e in probe_keys),
                    expr_cache_key(node.residual) if node.residual is not None else None,
@@ -1154,8 +1154,7 @@ class MppExecutor:
         cap = bucket_capacity(max(2 * quota_p * self.S, 1024))
         retries = 0
         while True:
-            key = ("mpp_hybrid_join", node.kind, K.kernel_selector_key(),
-                   active.orientation,
+            key = ("mpp_hybrid_join", node.kind, active.orientation,
                    tuple(expr_cache_key(e) for e in build_keys),
                    tuple(expr_cache_key(e) for e in probe_keys),
                    expr_cache_key(node.residual)

@@ -49,8 +49,8 @@ def exec_device():
 def exec_platform() -> str:
     """Platform of `exec_device()`.  Inside the CPU-pinned TP context
     `jax.default_backend()` still names the accelerator, so every
-    backend-adaptive choice (scatter vs sort formulations, the Pallas
-    selector, Mosaic vs interpret lowering, program keys) asks this instead."""
+    backend-adaptive choice (scatter vs sort formulations, program keys)
+    asks this instead."""
     return exec_device().platform
 
 

@@ -1240,14 +1240,7 @@ class Session:
                 int(self.instance.config.get("QUERY_MEM_BYTES", self.vars)
                     or (4 << 30)))
         try:
-            # kernel-tier selector mode for the statement (KERNEL hint >
-            # ENABLE_PALLAS_KERNELS param): thread-local scope, so programs
-            # traced below pick their join/agg formulation — and carry the
-            # mode in their global_jit keys — without racing other sessions
-            from galaxysql_tpu.kernels import relational as _K
-            with self.instance.mdl.shared(mdl_keys), \
-                    _K.kernel_scope(_K.exec_kernel_mode(
-                        ctx.hints, self.instance, self.vars)):
+            with self.instance.mdl.shared(mdl_keys):
                 return self._run_query_locked(plan, ctx, sql, t0, prof)
         finally:
             # per-query pool teardown: releases any bytes a failed operator
@@ -2496,11 +2489,8 @@ class Session:
             t0 = time.time()
             # statement-scope shared MDL: concurrent column DDL must not swap
             # partition lanes mid-execution (same torn-read class as SELECT)
-            from galaxysql_tpu.kernels import relational as _K
             with self.instance.mdl.shared(mdl_keys), \
-                    SEGMENT_TRACER.scoped(prof.segments), \
-                    _K.kernel_scope(_K.exec_kernel_mode(
-                        ctx.hints, self.instance, self.vars)):
+                    SEGMENT_TRACER.scoped(prof.segments):
                 # same engine dispatch as _run_query_locked: ANALYZE numbers
                 # must describe the engine users actually run — an AP query
                 # above the MPP threshold reports its SPMD stages (per-shard

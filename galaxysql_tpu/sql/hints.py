@@ -37,10 +37,6 @@ behind them:
   (exec/skew.py): OFF skips the planning pass entirely — no node carries a
   skew plan, so the hybrid/salted paths are structurally unreachable;
   JOIN/AGG restrict planting to that feature.  `=` syntax accepted.
-- KERNEL(OFF|PALLAS|ON)    per-statement control of the kernel-tier selector
-  (kernels/relational.py): OFF pins the reference join/agg formulations,
-  PALLAS forces the Pallas kernels below the auto row floor, ON restores
-  auto selection under a disabling ENABLE_PALLAS_KERNELS.  `=` accepted.
 - COLUMNAR(OFF|ON)         per-statement control of columnar-replica routing
   (storage/columnar.py): OFF pins the statement to the row store, ON forces
   the replica (enrolling + seeding the scanned tables synchronously) even
@@ -110,13 +106,6 @@ def parse_hints(comment: Optional[str]) -> Dict[str, object]:
             mode = arglist[0].lower()
             if mode in ("off", "join", "agg", "on"):
                 out["skew"] = mode
-        elif name == "KERNEL" and arglist:
-            # kernel-tier selector (kernels/relational.py): OFF pins the
-            # reference formulation, PALLAS forces the Pallas tier below the
-            # auto row floor, ON restores auto under a disabling param
-            mode = arglist[0].lower()
-            if mode in ("off", "pallas", "on"):
-                out["kernel"] = mode
         elif name == "COLUMNAR" and arglist:
             # columnar-replica routing (storage/columnar.py): OFF pins the
             # row store, ON forces the replica (synchronous enroll+seed)

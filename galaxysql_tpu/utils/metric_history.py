@@ -17,7 +17,7 @@ changed at each tick.  Most counters are idle most of the time, so a
 360-sample window costs far less than 360 full snapshots; trimming
 folds the evicted delta into ``_base`` so replay stays exact.
 
-Hatch duo (same convention as the statement summary / Pallas tiers):
+Hatch duo (same convention as the statement summary):
 
 * ``GALAXYSQL_METRIC_HISTORY=0`` env var — read once at import, kills
   sampling process-wide.

@@ -42,7 +42,7 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
-# Emergency hatch (same trio convention as GALAXYSQL_PALLAS / _COLUMNAR):
+# Emergency hatch (same trio convention as GALAXYSQL_COLUMNAR):
 # env kills always-on collection process-wide, read once at import so the
 # per-query check is one attribute load.
 ALWAYS_ON = os.environ.get("GALAXYSQL_TRACING", "1") != "0"

@@ -40,3 +40,22 @@ def _clear_compiled_caches():
     with GLOBAL_MESH_CACHE._lock:
         GLOBAL_MESH_CACHE._map.clear()
     jax.clear_caches()
+
+
+@pytest.fixture
+def chip_formulation(monkeypatch):
+    """The formulations a TPU traces (`K.prefer_scatter` false: sort group-by,
+    sorted join), for one test on this CPU.  Programs are keyed alike under
+    either formulation, so `_JIT_CACHE` is emptied for the test and put back
+    after it: none built under the patch outlives it, none built before it is
+    taken for the chip's."""
+    from galaxysql_tpu.exec import operators as ops
+    from galaxysql_tpu.kernels import relational as K
+    monkeypatch.setattr(K, "prefer_scatter", lambda: False)
+    with ops._JIT_CACHE_LOCK:
+        saved = dict(ops._JIT_CACHE)
+        ops._JIT_CACHE.clear()
+    yield
+    with ops._JIT_CACHE_LOCK:
+        ops._JIT_CACHE.clear()
+        ops._JIT_CACHE.update(saved)

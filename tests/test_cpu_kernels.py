@@ -1,10 +1,11 @@
-"""Backend-adaptive kernel parity: CPU scatter/hash formulations vs the
-sort/matmul reference kernels, and literal lifting (template compile keys).
+"""The CPU's scatter/hash formulations on their own contracts (float keys,
+exact int64 sums, the dense-slot layout against `matmul_groupby`, the join's
+overflow flag), and literal lifting (template compile keys).
 
 The CPU twins exist because XLA:CPU inverts TPU's cost model (scatters are
-native loops, comparator sorts are single-threaded): `scatter_groupby` /
-`hash_groupby` / `_hash_join_pairs_table` must agree bit-for-bit with the
-TPU-oriented formulations on every group/join contract the engine relies on.
+native loops, comparator sorts are single-threaded).  That `hash_groupby` and
+`_hash_join_pairs_table` give the relations of the TPU's sort formulations is
+the matrix of `tests/test_kernels.py::TestFormulationEquivalence`.
 """
 
 import jax
@@ -35,32 +36,6 @@ SPECS = [K.AggSpec("sum", 0), K.AggSpec("count", 0), K.AggSpec("count_star", -1)
 
 
 class TestHashGroupby:
-    def _mk(self, n, ndv, seed=7):
-        rng = np.random.default_rng(seed)
-        k1 = jnp.asarray(rng.integers(-ndv // 2, ndv // 2, n))
-        k1v = jnp.asarray(rng.random(n) > 0.1)
-        k2 = jnp.asarray(rng.integers(0, 7, n).astype(np.int32))
-        x = jnp.asarray(rng.integers(-10**12, 10**12, n))
-        xv = jnp.asarray(rng.random(n) > 0.2)
-        live = jnp.asarray(rng.random(n) > 0.15)
-        return [(k1, k1v), (k2, None)], [(x, xv)], live
-
-    def test_matches_sort_groupby(self):
-        keys, inputs, live = self._mk(30_000, 2000)
-        a = K.hash_groupby(keys, inputs, SPECS, live, 20_000)
-        b = K.sort_groupby(keys, inputs, SPECS, live, 20_000)
-        assert not bool(a.overflow) and not bool(b.overflow)
-        assert _groups(a) == _groups(b)
-        assert int(a.num_groups) == int(b.num_groups)
-
-    def test_overflow_when_capacity_exceeded(self):
-        n = 4096
-        kk = jnp.asarray(np.arange(n))
-        x = jnp.asarray(np.ones(n, np.int64))
-        r = K.hash_groupby([(kk, None)], [(x, None)], [K.AggSpec("sum", 0)],
-                           jnp.ones(n, bool), 128)
-        assert bool(r.overflow)
-
     def test_float_keys_nan_negzero_one_group(self):
         # SQL GROUP BY: all NaNs one group, -0.0 == 0.0
         f = jnp.asarray(np.array([np.nan, np.nan, -0.0, 0.0, 1.5, 1.5, np.nan]))
@@ -79,14 +54,6 @@ class TestHashGroupby:
                            jnp.ones(4, bool), 16)
         want = (np.int64(big) * 3 - 5).item()
         assert list(_groups(r).values())[0][0] == want
-
-    def test_empty_input(self):
-        n = 64
-        k = jnp.zeros(n, jnp.int64)
-        x = jnp.zeros(n, jnp.int64)
-        r = K.hash_groupby([(k, None)], [(x, None)], SPECS,
-                           jnp.zeros(n, bool), 16)
-        assert int(r.num_groups) == 0 and not bool(r.overflow)
 
 
 class TestScatterGroupby:
@@ -121,35 +88,6 @@ class TestScatterGroupby:
 
 
 class TestTableJoin:
-    def test_matches_sorted_join(self):
-        rng = np.random.default_rng(5)
-        nb, npr = 2048, 20_000
-        bk = jnp.asarray(rng.integers(0, 1500, nb))
-        bkv = jnp.asarray(rng.random(nb) > 0.1)
-        pk = jnp.asarray(rng.integers(0, 1500, npr))
-        pkv = jnp.asarray(rng.random(npr) > 0.1)
-        bl = jnp.asarray(rng.random(nb) > 0.2)
-        pl = jnp.asarray(rng.random(npr) > 0.2)
-        cap = 1 << 18
-        a = K._hash_join_pairs_table([(bk, bkv)], [(pk, pkv)], bl, pl, cap)
-        b = K._hash_join_pairs_sorted([(bk, bkv)], [(pk, pkv)], bl, pl, cap)
-        assert not bool(a.overflow) and not bool(b.overflow)
-
-        def pairs(r):
-            live = np.asarray(r.live)
-            return set(zip(np.asarray(r.build_idx)[live].tolist(),
-                           np.asarray(r.probe_idx)[live].tolist()))
-        assert pairs(a) == pairs(b)
-        assert (np.asarray(a.probe_matched) == np.asarray(b.probe_matched)).all()
-
-    def test_empty_build(self):
-        nb, npr = 64, 256
-        r = K._hash_join_pairs_table(
-            [(jnp.zeros(nb, jnp.int64), None)], [(jnp.zeros(npr, jnp.int64), None)],
-            jnp.zeros(nb, bool), jnp.ones(npr, bool), 1024)
-        assert int(np.asarray(r.live).sum()) == 0
-        assert not bool(r.overflow)
-
     def test_overflow_reported(self):
         # every probe row matches every build row: cap too small must flag
         nb, npr = 128, 128

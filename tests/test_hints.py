@@ -22,8 +22,9 @@ class TestParseHints:
         assert parse_hints("/* plain comment */") == {}
         assert parse_hints(None) == {}
 
-    def test_unknown_directive_ignored(self):
-        assert parse_hints("/*+TDDL: FROBNICATE(9) BASELINE_OFF*/") == \
+    @pytest.mark.parametrize("unknown", ["FROBNICATE(9)", "KERNEL(PALLAS)"])
+    def test_unknown_directive_ignored(self, unknown):
+        assert parse_hints(f"/*+TDDL: {unknown} BASELINE_OFF*/") == \
             {"baseline_off": True}
 
 

@@ -291,7 +291,7 @@ def test_traces_and_agrees_under_shard_map():
 
 
 @pytest.mark.parametrize("join_type", ["inner", "left", "semi", "anti"])
-def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, monkeypatch):
+def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, request):
     """`HashJoinOp` on the TPU's formulation: one count per probe batch in
     `JOIN_STATS`, "levels of full depth" on the span under the cursor, and the
     rows of the slot-table formulation."""
@@ -327,21 +327,13 @@ def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, monkeypa
     want = rows(join())  # this backend's own formulation, which counts nothing
     assert ops.JOIN_STATS == before
 
-    monkeypatch.setattr(K, "prefer_scatter", lambda: False)
-    with ops._JIT_CACHE_LOCK:  # programs keyed alike must not outlive the patch
-        saved = dict(ops._JIT_CACHE)
-        ops._JIT_CACHE.clear()
-    try:
-        tc = tracing.TraceContext(7)
-        span = tc.add("Join", kind="operator")
-        tc.cursor = span.span_id
-        op = join()
-        with tracing.activate(tc):
-            got = rows(op)
-    finally:
-        with ops._JIT_CACHE_LOCK:
-            ops._JIT_CACHE.clear()
-            ops._JIT_CACHE.update(saved)
+    request.getfixturevalue("chip_formulation")  # from here on, not before
+    tc = tracing.TraceContext(7)
+    span = tc.add("Join", kind="operator")
+    tc.cursor = span.span_id
+    op = join()
+    with tracing.activate(tc):
+        got = rows(op)
     assert got == want and len(want) > 0
     full = K.full_search_depth(ops.bucket_capacity(2000))
     assert ops.JOIN_STATS["probes"] == before["probes"] + 2

@@ -1,14 +1,16 @@
-"""Kernel tier: Pallas join/agg kernels + persistent AOT compile cache.
+"""Kernels: one formulation per operator per platform, and the persistent AOT
+compile cache.
 
-Coverage: Pallas-vs-reference bit-identity on the direct kernel matrix (NULL
-keys, empty build, duplicate keys, overflow-ladder doubling, both hybrid
-orientations) and on TPC-H Q5/Q9 end-to-end via the KERNEL hint; the
-escape-hatch trio proven structurally off-path with trace-time selection
-counters (`KERNEL_STATS`) and dispatch-count guards (the SHOW PROFILES
-unchanged-dispatch idiom extended to the kernel selector); persistent
-AOT-cache restart round trip (save -> boot -> same query with zero steady
-retraces and cache hits > 0), corrupted-entry recompile tolerance, and the
-compile_cache_* observability surfaces.  Fast target: make kernel-smoke.
+Coverage: the formulations the chip runs (`sort_groupby`,
+`_hash_join_pairs_sorted`) against the CPU's scatter formulations
+(`hash_groupby`, `_hash_join_pairs_table`), and the hybrid join's union-lane
+probe (one formulation on every backend) against the sorted join, as
+RELATIONS, since the layouts differ by design: NULL keys, empty input,
+duplicate keys, the overflow ladder's doubling, both skew orientations; TPC-H
+Q1/Q3/Q5/Q6/Q9 end to end under either formulation; the persistent AOT cache's
+restart round trip (save -> boot -> same query with zero steady retraces and
+cache hits > 0), corrupted-entry recompile tolerance, and the compile_cache_*
+observability surfaces.  Fast target: make kernel-smoke.
 """
 
 import numpy as np
@@ -31,62 +33,74 @@ def _lanes(pairs):
             for d, v in pairs]
 
 
-def _leaves(result):
-    return [np.asarray(x) for x in jax.tree_util.tree_leaves(result)]
+def _groups(r: R.GroupByResult):
+    """{key tuple: agg tuple} over live slots, NULL as None: a group-by's
+    result as a relation, whatever order and slots the formulation chose."""
+    def column(lane):
+        d, v = lane
+        d = np.asarray(d).tolist()
+        return d if v is None else \
+            [x if ok else None for x, ok in zip(d, np.asarray(v).tolist())]
+    slots = np.nonzero(np.asarray(r.live))[0].tolist()
+    keys, aggs = [column(k) for k in r.keys], [column(a) for a in r.aggs]
+    out = {tuple(k[i] for k in keys): tuple(a[i] for a in aggs) for i in slots}
+    assert len(out) == len(slots) == int(r.num_groups)  # one slot a group
+    return out
 
 
-def _assert_bit_identical(a, b):
-    la, lb = _leaves(a), _leaves(b)
-    assert len(la) == len(lb)
-    for x, y in zip(la, lb):
-        assert x.dtype == y.dtype and x.shape == y.shape
-        assert np.array_equal(x, y)
+def _pairs(r: R.JoinPairs):
+    """Verified (build row, probe row) pairs in order: a join's result as a
+    relation.  A list, not a set: a pair enumerated twice must show."""
+    live = np.asarray(r.live)
+    return sorted(zip(np.asarray(r.build_idx)[live].tolist(),
+                      np.asarray(r.probe_idx)[live].tolist()))
 
 
-def _groupby(mode, keys, inputs, specs, live, max_groups, max_rounds=64):
-    with R.kernel_scope(mode):
-        return R.hash_groupby(_lanes(keys), _lanes(inputs), specs,
-                              jnp.asarray(live), max_groups, max_rounds)
+def _both_groupbys(keys, inputs, specs, live, max_groups, max_rounds=64):
+    """(the CPU's scatter formulation: the oracle, the chip's sort one)."""
+    keys, inputs, live = _lanes(keys), _lanes(inputs), jnp.asarray(live)
+    specs = tuple(specs)  # one program each, as the operators build them
+
+    def scatter(keys, inputs, live):
+        return R.hash_groupby(keys, inputs, specs, live, max_groups, max_rounds)
+
+    def sort(keys, inputs, live):
+        return R.sort_groupby(keys, inputs, specs, live, max_groups)
+    return (jax.jit(scatter)(keys, inputs, live),
+            jax.jit(sort)(keys, inputs, live))
 
 
-def _join(mode, bk, pk, b_live, p_live, cap):
-    with R.kernel_scope(mode):
-        return R.hash_join_pairs(_lanes(bk), _lanes(pk), jnp.asarray(b_live),
-                                 jnp.asarray(p_live), cap)
+def _assert_same_join(oracle, subject):
+    assert not bool(oracle.overflow) and not bool(subject.overflow)
+    assert _pairs(oracle) == _pairs(subject)
+    assert np.array_equal(np.asarray(oracle.probe_matched),
+                          np.asarray(subject.probe_matched))
 
 
-def _hybrid(mode, bk, pk, b_live, p_live, cap):
-    with R.kernel_scope(mode):
-        return R.hash_join_probe_hybrid(_lanes(bk), _lanes(pk),
-                                        jnp.asarray(b_live),
-                                        jnp.asarray(p_live), cap)
+EVERY_KIND = [R.AggSpec("sum", 0), R.AggSpec("count", 0),
+              R.AggSpec("count_star", -1), R.AggSpec("min", 0),
+              R.AggSpec("max", 0)]
 
 
-# -- Pallas vs reference: direct kernel bit-identity matrix -------------------
+# -- the chip's formulations vs the CPU's: direct kernel matrix ---------------
 
 
-class TestPallasBitIdentity:
-    """`kernel_scope('pallas')` forces the Pallas formulation (interpret mode
-    on CPU); `'off'` forces the reference formulation, which is the
-    correctness oracle.  Everything — group placement order, pair slot
-    layout, overflow flags — must be BIT-identical, because the Pallas
-    kernels reimplement the same deterministic algorithm, not merely the
-    same relation."""
+class TestFormulationEquivalence:
+    """The oracle is the scatter formulation tier-1 runs everywhere else; the
+    subject is what `prefer_scatter()` false selects, which the benchmark's
+    cells run and tier-1 otherwise reaches for whole queries only."""
 
     def test_groupby_duplicate_keys(self):
         rng = np.random.default_rng(7)
         n = 1536
         k = rng.integers(0, 53, n).astype(np.int64)  # heavy duplication
         v = rng.integers(-1000, 1000, n).astype(np.int64)
-        keys = [(k, None)]
-        inputs = [(v, None), (k, None)]
         specs = [R.AggSpec("sum", 0), R.AggSpec("count_star", -1),
                  R.AggSpec("min", 1)]
-        live = np.ones(n, bool)
-        ref = _groupby("off", keys, inputs, specs, live, 256)
-        pal = _groupby("pallas", keys, inputs, specs, live, 256)
-        assert not bool(ref.overflow)
-        _assert_bit_identical(ref, pal)
+        ref, got = _both_groupbys([(k, None)], [(v, None), (k, None)], specs,
+                                  np.ones(n, bool), 256)
+        assert not bool(ref.overflow) and not bool(got.overflow)
+        assert _groups(ref) == _groups(got) and len(_groups(ref)) == 53
 
     def test_groupby_null_keys(self):
         rng = np.random.default_rng(8)
@@ -95,76 +109,85 @@ class TestPallasBitIdentity:
         k2 = rng.integers(0, 5, n).astype(np.int64)
         valid1 = rng.random(n) > 0.2  # NULLs form their own groups
         v = rng.integers(0, 100, n).astype(np.int64)
-        keys = [(k1, valid1), (k2, None)]
-        inputs = [(v, None)]
         specs = [R.AggSpec("sum", 0), R.AggSpec("count_star", -1)]
-        live = rng.random(n) > 0.1
-        ref = _groupby("off", keys, inputs, specs, live, 512)
-        pal = _groupby("pallas", keys, inputs, specs, live, 512)
-        _assert_bit_identical(ref, pal)
+        ref, got = _both_groupbys([(k1, valid1), (k2, None)], [(v, None)],
+                                  specs, rng.random(n) > 0.1, 512)
+        assert not bool(ref.overflow) and not bool(got.overflow)
+        assert _groups(ref) == _groups(got)
+        assert any(key[0] is None for key in _groups(got))
 
-    def test_groupby_empty_input(self):
+    def test_groupby_nullable_inputs_every_kind(self):
+        rng = np.random.default_rng(7)
+        n, ndv = 30_000, 2000
+        k1 = rng.integers(-ndv // 2, ndv // 2, n)
+        k1v = rng.random(n) > 0.1
+        k2 = rng.integers(0, 7, n).astype(np.int32)
+        x = rng.integers(-10**12, 10**12, n)
+        xv = rng.random(n) > 0.2
+        ref, got = _both_groupbys([(k1, k1v), (k2, None)], [(x, xv)],
+                                  EVERY_KIND, rng.random(n) > 0.15, 20_000)
+        assert not bool(ref.overflow) and not bool(got.overflow)
+        assert _groups(ref) == _groups(got)
+
+    @pytest.mark.parametrize("n,specs,max_groups", [
+        (256, [R.AggSpec("sum", 0)], 64), (64, EVERY_KIND, 16)])
+    def test_groupby_empty_input(self, n, specs, max_groups):
         # zero LIVE rows at positive static capacity — the engine's "empty"
-        n = 256
-        keys = [(np.zeros(n, np.int64), None)]
-        inputs = [(np.zeros(n, np.int64), None)]
-        specs = [R.AggSpec("sum", 0)]
-        live = np.zeros(n, bool)
-        ref = _groupby("off", keys, inputs, specs, live, 64)
-        pal = _groupby("pallas", keys, inputs, specs, live, 64)
-        assert int(ref.num_groups) == 0
-        _assert_bit_identical(ref, pal)
+        lane = [(np.zeros(n, np.int64), None)]
+        ref, got = _both_groupbys(lane, lane, specs, np.zeros(n, bool),
+                                  max_groups)
+        assert not bool(ref.overflow) and not bool(got.overflow)
+        assert _groups(ref) == _groups(got) == {}
 
-    def test_groupby_overflow_ladder_doubling(self):
+    @pytest.mark.parametrize("n,small,max_rounds,doubled", [
+        (512, 16, 8, 1024), (4096, 128, 64, 8192)])
+    def test_groupby_overflow_ladder_doubling(self, n, small, max_rounds,
+                                              doubled):
         """Overflow semantics ARE the ladder contract: both formulations must
-        overflow at the same undersized capacity and both must succeed —
-        bit-identically — after one doubling."""
+        overflow at the same undersized capacity and both must succeed, with
+        the same relation, after the ladder's doubling."""
         rng = np.random.default_rng(9)
-        n = 512
         k = rng.permutation(n).astype(np.int64)  # n distinct groups
-        keys = [(k, None)]
-        inputs = [(k, None)]
+        lane = [(k, None)]
         specs = [R.AggSpec("count_star", -1)]
         live = np.ones(n, bool)
-        ref_s = _groupby("off", keys, inputs, specs, live, 16, max_rounds=8)
-        pal_s = _groupby("pallas", keys, inputs, specs, live, 16, max_rounds=8)
-        assert bool(ref_s.overflow) and bool(pal_s.overflow)
-        ref_b = _groupby("off", keys, inputs, specs, live, 1024)
-        pal_b = _groupby("pallas", keys, inputs, specs, live, 1024)
-        assert not bool(ref_b.overflow) and not bool(pal_b.overflow)
-        _assert_bit_identical(ref_b, pal_b)
+        ref, got = _both_groupbys(lane, lane, specs, live, small, max_rounds)
+        assert bool(ref.overflow) and bool(got.overflow)
+        ref, got = _both_groupbys(lane, lane, specs, live, doubled)
+        assert not bool(ref.overflow) and not bool(got.overflow)
+        assert _groups(ref) == _groups(got) and len(_groups(got)) == n
 
-    def test_join_pairs_duplicates_and_nulls(self):
+    @pytest.mark.parametrize("nb,npr,ndv,dead,cap", [
+        (512, 1024, 37, 0.0, 16 * 1024), (2048, 20_000, 1500, 0.2, 1 << 18)])
+    def test_join_pairs_duplicates_and_nulls(self, nb, npr, ndv, dead, cap):
         rng = np.random.default_rng(10)
-        nb, npr = 512, 1024
-        bk = rng.integers(0, 37, nb).astype(np.int64)
-        pk = rng.integers(0, 50, npr).astype(np.int64)
+        bk = rng.integers(0, ndv, nb).astype(np.int64)
+        pk = rng.integers(0, ndv + 13, npr).astype(np.int64)
         bv = rng.random(nb) > 0.15  # NULL build keys never match
         pv = rng.random(npr) > 0.15
-        cap = 16 * npr
-        ref = _join("off", [(bk, bv)], [(pk, pv)], np.ones(nb, bool),
-                    np.ones(npr, bool), cap)
-        pal = _join("pallas", [(bk, bv)], [(pk, pv)], np.ones(nb, bool),
-                    np.ones(npr, bool), cap)
-        assert not bool(ref.overflow)
-        _assert_bit_identical(ref, pal)
+        args = (_lanes([(bk, bv)]), _lanes([(pk, pv)]),
+                jnp.asarray(rng.random(nb) >= dead),
+                jnp.asarray(rng.random(npr) >= dead), cap)
+        ref = R._hash_join_pairs_table(*args)
+        _assert_same_join(ref, R._hash_join_pairs_sorted(*args))
+        pairs = _pairs(ref)  # duplicates on both sides did pair up
+        assert len(pairs) > len({b for b, _ in pairs}) and \
+            len(pairs) > len({p for _, p in pairs})
 
-    def test_join_empty_build(self):
-        nb, npr = 128, 256
-        bk = np.zeros(nb, np.int64)
-        pk = np.zeros(npr, np.int64)
-        ref = _join("off", [(bk, None)], [(pk, None)], np.zeros(nb, bool),
-                    np.ones(npr, bool), npr)
-        pal = _join("pallas", [(bk, None)], [(pk, None)], np.zeros(nb, bool),
-                    np.ones(npr, bool), npr)
-        assert not np.asarray(ref.live).any()
-        _assert_bit_identical(ref, pal)
+    @pytest.mark.parametrize("nb,npr,cap", [(128, 256, 256), (64, 256, 1024)])
+    def test_join_empty_build(self, nb, npr, cap):
+        args = (_lanes([(np.zeros(nb, np.int64), None)]),
+                _lanes([(np.zeros(npr, np.int64), None)]),
+                jnp.zeros(nb, bool), jnp.ones(npr, bool), cap)
+        ref = R._hash_join_pairs_table(*args)
+        _assert_same_join(ref, R._hash_join_pairs_sorted(*args))
+        assert _pairs(ref) == [] and not np.asarray(ref.probe_matched).any()
 
     @pytest.mark.parametrize("orientation", ["skewed_probe", "skewed_build"])
     def test_hybrid_orientations(self, orientation):
-        """The hybrid entry now rides the CSR probe on every backend
-        (previously a bare `hash_join_pairs` delegation), so the Pallas
-        kernels must reproduce its layout for BOTH skew orientations."""
+        """The hybrid join's union-lane probe has one formulation on every
+        backend (the slot-table CSR); the chip runs it beside the sorted
+        join, so the two must enumerate the same pairs for BOTH skews."""
         rng = np.random.default_rng(11)
         if orientation == "skewed_probe":
             nb, npr, hot_side = 256, 2048, "p"
@@ -174,170 +197,16 @@ class TestPallasBitIdentity:
         pk = rng.integers(0, 40, npr).astype(np.int64)
         hot = bk if hot_side == "b" else pk
         hot[: len(hot) // 2] = 7  # one dominant key
-        cap = 8 * max(nb, npr)
-        ref = _hybrid("off", [(bk, None)], [(pk, None)], np.ones(nb, bool),
-                      np.ones(npr, bool), cap)
-        pal = _hybrid("pallas", [(bk, None)], [(pk, None)], np.ones(nb, bool),
-                      np.ones(npr, bool), cap)
-        assert not bool(ref.overflow)
-        _assert_bit_identical(ref, pal)
+        args = (_lanes([(bk, None)]), _lanes([(pk, None)]),
+                jnp.ones(nb, bool), jnp.ones(npr, bool), 8 * max(nb, npr))
+        _assert_same_join(R._hash_join_pairs_sorted(*args),
+                          R.hash_join_probe_hybrid(*args))
 
 
-# -- escape hatches + dispatch guards -----------------------------------------
+# -- TPC-H end to end under either formulation --------------------------------
 
-
-def _clear_jit_cache():
-    with ops._JIT_CACHE_LOCK:
-        ops._JIT_CACHE.clear()
-
-
-def _reset_kernel_stats():
-    R.KERNEL_STATS["pallas"] = 0
-    R.KERNEL_STATS["reference"] = 0
-
-
-class TestKernelSelector:
-    """The hatch trio must be STRUCTURALLY off-path: with a hatch engaged,
-    tracing a program never even consults the Pallas formulation
-    (`KERNEL_STATS['pallas']` stays zero) — not merely that results agree."""
-
-    def test_env_hatch_beats_forced_pallas(self, monkeypatch):
-        monkeypatch.setattr(R, "_PALLAS_ENV_OFF", True)
-        _clear_jit_cache()
-        _reset_kernel_stats()
-        n = 300
-        keys = [(np.arange(n, dtype=np.int64) % 11, None)]
-        specs = [R.AggSpec("count_star", -1)]
-        _groupby("pallas", keys, [], specs, np.ones(n, bool), 64)
-        assert R.KERNEL_STATS["pallas"] == 0
-        assert R.KERNEL_STATS["reference"] > 0
-
-    def test_mode_resolution_precedence(self):
-        inst = Instance()
-        assert R.exec_kernel_mode({"kernel": "off"}, inst) == "off"
-        assert R.exec_kernel_mode({"kernel": "pallas"}, inst) == "pallas"
-        assert R.exec_kernel_mode({}, inst) == "auto"
-        inst.config.set_instance("ENABLE_PALLAS_KERNELS", False)
-        assert R.exec_kernel_mode({}, inst) == "off"
-        # KERNEL(ON) restores auto selection under a disabling param
-        assert R.exec_kernel_mode({"kernel": "on"}, inst) == "auto"
-
-    def test_auto_mode_on_cpu_keeps_reference(self):
-        # CPU backend: auto never picks Pallas regardless of row count
-        _clear_jit_cache()
-        _reset_kernel_stats()
-        n = 400
-        keys = [(np.arange(n, dtype=np.int64) % 13, None)]
-        _groupby("auto", keys, [], [R.AggSpec("count_star", -1)],
-                 np.ones(n, bool), 64)
-        assert R.KERNEL_STATS["pallas"] == 0
-
-    def test_session_hatches_off_path_and_hint_engages(self):
-        # AP-scale rows (> AP_ROW_THRESHOLD): the query must reach the DEVICE
-        # aggregation kernels — a host-TP-path query never consults the
-        # selector and would prove nothing
-        inst = Instance()
-        s = Session(inst)
-        s.execute("CREATE DATABASE kt; USE kt")
-        s.execute("CREATE TABLE t (g BIGINT, v BIGINT) "
-                  "PARTITION BY HASH(g) PARTITIONS 4")
-        rng = np.random.default_rng(12)
-        n = 70_000
-        inst.store("kt", "t").insert_arrays(
-            {"g": rng.integers(0, 40, n).astype(np.int64),
-             "v": rng.integers(0, 1000, n).astype(np.int64)},
-            inst.tso.next_timestamp())
-        inst.config.set_instance("MPP_MIN_AP_ROWS", 1)  # force mesh execution
-        q = "SELECT g, SUM(v), COUNT(*) FROM t GROUP BY g ORDER BY g"
-
-        def fresh():
-            # every run must actually TRACE: drop compiled programs AND the
-            # fragment cache (a replayed fragment is bit-identical across
-            # formulations, so serving it is sound — but it would hide the
-            # selector from this structural guard)
-            _clear_jit_cache()
-            inst.frag_cache.clear()
-            _reset_kernel_stats()
-
-        fresh()
-        base = s.execute(q)  # default auto on CPU
-        assert R.KERNEL_STATS["pallas"] == 0
-
-        fresh()
-        off = s.execute("/*+TDDL:KERNEL(OFF)*/ " + q)
-        assert R.KERNEL_STATS["pallas"] == 0
-
-        inst.config.set_instance("ENABLE_PALLAS_KERNELS", False)
-        fresh()
-        param_off = s.execute(q)
-        assert R.KERNEL_STATS["pallas"] == 0
-        inst.config.set_instance("ENABLE_PALLAS_KERNELS", True)
-
-        fresh()
-        pal = s.execute("/*+TDDL:KERNEL(PALLAS)*/ " + q)
-        assert R.KERNEL_STATS["pallas"] > 0  # the hint reached the selector
-        assert base.rows == off.rows == param_off.rows == pal.rows
-        s.close()
-
-    def test_dispatch_count_kernel_off_equals_default(self):
-        """SKEW(OFF)-style guard: on CPU the default path IS the reference
-        formulation, so a KERNEL(OFF) hint compiles a twin program with the
-        exact same dispatch count."""
-        inst = Instance()
-        s = Session(inst)
-        s.execute("CREATE DATABASE kd; USE kd")
-        s.execute("CREATE TABLE t (g BIGINT, v BIGINT) "
-                  "PARTITION BY HASH(g) PARTITIONS 4")
-        rng = np.random.default_rng(13)
-        n = 70_000
-        inst.store("kd", "t").insert_arrays(
-            {"g": rng.integers(0, 20, n).astype(np.int64),
-             "v": rng.integers(0, 100, n).astype(np.int64)},
-            inst.tso.next_timestamp())
-        inst.config.set_instance("MPP_MIN_AP_ROWS", 1)  # force mesh execution
-        q = "SELECT g, SUM(v) FROM t GROUP BY g"
-
-        def dispatches(sql):
-            s.execute(sql)  # warmup/compile
-            ops.reset_dispatch_stats()
-            s.execute(sql)
-            return ops.DISPATCH_STATS["dispatches"]
-
-        assert dispatches(q) == dispatches("/*+TDDL:KERNEL(OFF)*/ " + q)
-        s.close()
-
-    def test_steady_dispatches_unchanged_after_pallas_run(self):
-        """The SHOW PROFILES unchanged-dispatch guard, extended to the kernel
-        selector: a KERNEL(PALLAS)-hinted run compiles a DIFFERENT program
-        (the mode rides the global_jit key) and must not perturb subsequent
-        default executions — same dispatch count, zero retraces."""
-        inst = Instance()
-        s = Session(inst)
-        s.execute("CREATE DATABASE kg; USE kg")
-        s.execute("CREATE TABLE t (g BIGINT, v BIGINT) "
-                  "PARTITION BY HASH(g) PARTITIONS 4")
-        rng = np.random.default_rng(14)
-        n = 70_000
-        inst.store("kg", "t").insert_arrays(
-            {"g": rng.integers(0, 16, n).astype(np.int64),
-             "v": rng.integers(0, 100, n).astype(np.int64)},
-            inst.tso.next_timestamp())
-        inst.config.set_instance("MPP_MIN_AP_ROWS", 1)  # force mesh execution
-        q = "SELECT g, COUNT(*) FROM t GROUP BY g"
-        s.execute(q)  # warmup
-        ops.reset_dispatch_stats()
-        s.execute(q)
-        baseline = ops.DISPATCH_STATS["dispatches"]
-        s.execute("/*+TDDL:KERNEL(PALLAS)*/ " + q)  # may dispatch differently
-        ops.reset_dispatch_stats()
-        ops.reset_compile_stats()
-        s.execute(q)
-        assert ops.DISPATCH_STATS["dispatches"] == baseline
-        assert ops.COMPILE_STATS["retraces"] == 0
-        s.close()
-
-
-# -- TPC-H end-to-end equivalence ---------------------------------------------
+TPCH_QIDS = [1, 3, 5, 6, 9]  # the cells' four queries, and Q9
+NO_FRAG = "/*+TDDL:FRAGMENT_CACHE(OFF)*/ "  # a replayed fragment runs nothing
 
 
 @pytest.fixture(scope="module")
@@ -356,15 +225,32 @@ def tpch_session():
     s.close()
 
 
-class TestTpchKernelEquivalence:
-    @pytest.mark.parametrize("qid", [5, 9])
-    def test_kernel_on_equals_off(self, tpch_session, qid):
+@pytest.fixture(scope="module")
+def cpu_formulation_rows(tpch_session):
+    """Module-scoped, so set up before any test's `chip_formulation`."""
+    from galaxysql_tpu.storage.tpch_queries import QUERIES
+    return {q: tpch_session.execute(NO_FRAG + QUERIES[q]).rows
+            for q in TPCH_QIDS}
+
+
+class TestTpchFormulationEquivalence:
+    @pytest.mark.parametrize("qid", TPCH_QIDS)
+    def test_chip_formulation_equals_cpu(self, tpch_session,
+                                         cpu_formulation_rows, qid,
+                                         chip_formulation):
         from galaxysql_tpu.storage.tpch_queries import QUERIES
-        s = tpch_session
-        off = s.execute("/*+TDDL:KERNEL(OFF)*/ " + QUERIES[qid])
-        default = s.execute(QUERIES[qid])
-        on = s.execute("/*+TDDL:KERNEL(PALLAS)*/ " + QUERIES[qid])
-        assert off.rows == default.rows == on.rows
+        got = tpch_session.execute(NO_FRAG + QUERIES[qid]).rows
+        assert got == cpu_formulation_rows[qid] and len(got) > 0
+        with ops._JIT_CACHE_LOCK:
+            built = {ops.program_family(k) for k in ops._JIT_CACHE}
+        assert "agg_partial" in built, built  # the device path ran it
+        if qid in (3, 5, 9):
+            assert "join_pairs" in built, built  # on the sorted join
+
+
+def _clear_jit_cache():
+    with ops._JIT_CACHE_LOCK:
+        ops._JIT_CACHE.clear()
 
 
 # -- persistent AOT compile cache ---------------------------------------------

@@ -7,10 +7,13 @@ witness: unit cycle-detection plus the failpoint-driven seeded
 append_lock/partition-lock inversion caught on a real engine insert ramp.
 """
 
+import ast
+import os
 import threading
 
 import pytest
 
+import galaxysql_tpu
 from galaxysql_tpu.devtools import lint as L
 from galaxysql_tpu.devtools.checkers import ALL_CHECKERS
 from galaxysql_tpu.devtools.checkers.hygiene import HygieneChecker
@@ -508,6 +511,45 @@ class TestTreeClean:
     def test_cli_exits_zero(self, capsys):
         assert L.main([]) == 0
         assert "0 finding(s)" in capsys.readouterr().out
+
+
+# -- layering: the bottom boxes of the drawing --------------------------------
+
+def imported_packages(box):
+    """The `galaxysql_tpu` packages (or top-level modules) that the modules of
+    `galaxysql_tpu/<box>/` import, at any depth of any function, read by `ast`;
+    the box itself left out."""
+    root = os.path.join(os.path.dirname(galaxysql_tpu.__file__), box)
+    found, modules = set(), 0
+    for base, _dirs, files in os.walk(root):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            modules += 1
+            with open(os.path.join(base, name)) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Import):
+                    names = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom):
+                    assert node.level == 0, (name, "imports are absolute here")
+                    names = [f"{node.module}.{a.name}" for a in node.names]
+                else:
+                    continue
+                found |= {n.split(".")[1] for n in names
+                          if n.startswith("galaxysql_tpu.")}
+    assert modules, root  # the walk found the box's source
+    return found - {box}
+
+
+@pytest.mark.parametrize("box,may_import", [
+    ("config", set()), ("kernels", {"runtime"}), ("types", {"utils"}),
+    ("chunk", {"types"})])
+def test_a_bottom_box_imports_only_what_lies_under_it(box, may_import):
+    """`kernels/` knows shapes and lanes and where a program runs, nothing of
+    the executor or the session above it (it imported `exec` until PR 29);
+    the other three arrows are what the tree does and nothing else checked."""
+    assert imported_packages(box) <= may_import
 
 
 # -- lockdep witness (runtime) -------------------------------------------------

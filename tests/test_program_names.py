@@ -137,5 +137,9 @@ def test_the_benchmarks_table_holds_every_family_the_source_can_build():
                 assert not unreadable, (name, unreadable)
                 families |= found
     assert len(families) > 20          # the walk found the program's source
-    assert families == set(FAMILY_GROUP)
+    # a family the source builds and the table lacks is unnamed device time.
+    # The table still lists the three `pallas_*` families, which nothing
+    # builds since PR 29 and which a PR may not take out of `benchmarks/`
+    # while it changes the program: ROADMAP D13 drops them and restores `==`.
+    assert families <= set(FAMILY_GROUP)
     assert set(FAMILY_GROUP.values()) == set(GROUPS)
