@@ -80,11 +80,15 @@ COMPILE_STATS = {"retraces": 0, "compile_ms": 0.0, "cache_hits": 0}
 COMPILE_MS_BY_PROGRAM: Dict[str, List[float]] = {}
 
 
-# the sorted (TPU) join's range search, one add per probe batch: levels the
-# bounded search ran against the levels a search of the whole build lane runs
-# (kernels/relational._probe_ranges).  Equal sums mean every build side was one
-# hot key; the slot-table (CPU) formulation searches nothing and adds nothing.
-JOIN_STATS = {"probes": 0, "search_levels": 0, "full_depth_levels": 0}
+# the sorted (TPU) join's two data-dependent loops, one add per probe batch:
+# levels the range search ran against the levels a search of the whole build lane
+# runs (kernels/relational._probe_ranges; equal sums mean every build side was
+# one hot key), and passes the expansion's running maximum took (`_expand_rows`:
+# none where no probe row has two pairs) against the levels a search of the
+# whole probe lane runs for a pair slot.  The slot-table (CPU) formulation
+# loops over nothing and adds nothing.
+JOIN_STATS = {"probes": 0, "search_levels": 0, "full_depth_levels": 0,
+              "expand_levels": 0, "expand_full_depth_levels": 0}
 
 
 def reset_dispatch_stats():
@@ -1167,8 +1171,9 @@ class HashJoinOp(Operator):
         # hash to disk and joins bucket pairs (HybridHashJoinExec analog)
         self.spill_threshold = spill_threshold
         self.grace_partitions = 0  # observable spill counter (tests)
-        # range-search depth of the sorted probes so far, against full depth
+        # depth of the sorted probes' two searches so far, against full depth
         self.search_levels = self.full_depth_levels = 0
+        self.expand_levels = self.expand_full_depth_levels = 0
         # per-query memory pool: accumulated build bytes charge it;
         # exhaustion or a squeeze revoke engages the grace path early
         self.mem_pool = mem_pool
@@ -1850,21 +1855,32 @@ class HashJoinOp(Operator):
         finally:
             charge.close()
 
-    def _note_search_depth(self, levels: int, full: int):
-        """Count one probe's search depth and, in a traced statement, write
-        "levels of full depth" so far onto the operator's span (the cursor is
-        this join's `op:Join` while its batches are pulled)."""
+    def _note_search_depth(self, pairs: K.JoinPairs, nb: int, npr: int):
+        """Count one probe's depths (the range search's levels of a search of
+        `nb` build slots, the expansion's passes of a search of `npr` probe
+        slots) and, in a traced statement, write "levels of full depth" so far
+        onto the operator's span (the cursor is this join's `op:Join` while its
+        batches are pulled)."""
+        levels, expand = (int(n) for n in jax.device_get(
+            (pairs.search_levels, pairs.expand_levels)))  # one read, not two
+        full, expand_full = K.full_search_depth(nb), K.full_search_depth(npr)
         JOIN_STATS["probes"] += 1
         JOIN_STATS["search_levels"] += levels
         JOIN_STATS["full_depth_levels"] += full
+        JOIN_STATS["expand_levels"] += expand
+        JOIN_STATS["expand_full_depth_levels"] += expand_full
         self.search_levels += levels
         self.full_depth_levels += full
+        self.expand_levels += expand
+        self.expand_full_depth_levels += expand_full
         from galaxysql_tpu.utils import tracing as _tr
         tc = _tr.current()
         sp = tc.span_at_cursor() if tc is not None else None
         if sp is not None:
             sp.attrs["search_levels"] = \
                 f"{self.search_levels} of {self.full_depth_levels}"
+            sp.attrs["expand_levels"] = \
+                f"{self.expand_levels} of {self.expand_full_depth_levels}"
 
     def _device_probe(self, build_batch: ColumnBatch, art,
                       stored: bool) -> Iterator[ColumnBatch]:
@@ -1914,8 +1930,7 @@ class HashJoinOp(Operator):
                     break
                 cap *= 2
             if pairs.search_levels is not None:
-                self._note_search_depth(int(pairs.search_levels),
-                                        K.full_search_depth(build_batch.capacity))
+                self._note_search_depth(pairs, build_batch.capacity, pb.capacity)
             if residual_pred is None and self.join_type in ("semi", "anti"):
                 matched = pairs.probe_matched
                 live = pb.live_mask() & (matched if self.join_type == "semi" else ~matched)

@@ -578,9 +578,11 @@ def prefer_scatter() -> bool:
     """Kernel-formulation choice is a backend property: XLA:CPU lowers scatters
     to fast native loops but its comparator sorts are single-threaded (measured
     1.3s to lexsort 1.2M rows vs ~10ms for a segment_sum); TPU is the inverse
-    (scatters serialize, bitonic sorts + MXU matmuls are fast; gathers are
-    its other slow thing, about 54-116M elements a second on a v5e, which is
-    why the TPU join counts its gather passes: PERF.md, PR 26).  Asks where
+    (bitonic sorts + MXU matmuls are fast; gathers are its slow thing, 8-17 ns
+    a 32-bit word on a v5e and a 64-bit lane is two words, which is why the
+    TPU join counts its gathered words: PERF.md, PR 26 and PR 30; scatters
+    that combine into shared slots serialize, one of distinct 32-bit updates
+    measured about 5 ns an update).  Asks where
     the program being traced will RUN, not what the default backend is: a
     program under the TP path's CPU pin gets the CPU formulation."""
     return exec_platform() == "cpu"
@@ -640,6 +642,9 @@ class JoinPairs(NamedTuple):
     # scalar int32 — levels the range search ran (sorted formulation only;
     # read against `full_search_depth(nb)`)
     search_levels: Any = None
+    # scalar int32 — passes the expansion's running maximum took (sorted
+    # formulation only; `bit_length(most pairs of a probe row - 1)`)
+    expand_levels: Any = None
 
 
 def _effective_live(keys, live):
@@ -660,10 +665,13 @@ def hash_join_pairs(build_keys: Sequence[Tuple[Any, Optional[Any]]],
     NULL join keys never match (SQL semantics): rows with any NULL key are masked out of
     both sides before hashing.  Backend-adaptive: the TPU formulation sorts the
     build hashes and finds each probe's range through a prefix directory, a
-    bounded search and run lengths (`_probe_ranges`; sorts vectorize, scatters
-    serialize, and a gather pass over 6.3M probe slots costs 54-117 ms on a
-    v5e, so the passes are what it saves: 7-10 where two whole-lane searches
-    made 36-44); the CPU formulation buckets the build side into a slot-table CSR
+    bounded search and run lengths (`_probe_ranges`; sorts vectorize, and a
+    gather pass over 6.3M probe slots costs 54-117 ms on a v5e, so the passes
+    are what it saves: 7-10 where two whole-lane searches made 36-44), then
+    expands the ranges into pair slots with one scatter of row ids and a
+    running maximum (`_expand_rows`; two gathered words a pair slot where a
+    whole-lane search of the 64-bit running count makes about 50); the CPU
+    formulation buckets the build side into a slot-table CSR
     and probes by direct gather (XLA:CPU searchsorted costs ~200ms per 1.2M
     probes — 18 full gather passes — while scatters are native loops)."""
     if prefer_scatter():
@@ -754,11 +762,49 @@ def _probe_ranges(h_sorted, h_p):
     return left, run, levels
 
 
+def _expand_rows(starts, offsets, cap: int):
+    """The probe row that owns each of `cap` pair slots, as
+    `searchsorted(offsets, arange(cap), "right")` clipped to a row gives it,
+    which slots hold a pair, and the passes it took:
+    `(p_of, pair_live, passes)`.
+
+    Each probe row with a pair writes its id at its first pair slot, in one
+    32-bit scatter (rows without one aim at `cap` and are dropped, so the
+    slots written are distinct; about 5 ns an update on a v5e, where that
+    search gathers two words a level at 8-17 ns each), and a running
+    maximum carries the ids forward over the rows' other slots: by
+    doubling strides, shifts and no gathers, until every slot with a pair
+    has seen its row, which is `bit_length(most pairs of a row - 1)` passes,
+    a device scalar (the loop of `_run_ends`, looking back; `lax.cummax` is
+    the reduce-window that costs the chip's compiler half a minute).  None
+    for a key-to-foreign-key join.  Slots that hold no pair read `npr - 1`."""
+    npr = offsets.shape[0]
+    lane = jnp.int32 if cap <= np.iinfo(np.int32).max else jnp.int64
+    first_slot = jnp.where(offsets > starts, jnp.minimum(starts, cap), cap)
+    nothing = jnp.full(cap, -1, jnp.int32)
+    marks = nothing.at[first_slot.astype(lane)].set(
+        jnp.arange(npr, dtype=jnp.int32), mode="drop")
+    pair_live = jnp.arange(cap, dtype=lane) < jnp.minimum(offsets[-1], cap).astype(lane)
+    marks = jnp.where(pair_live, marks, npr - 1)
+
+    def look_back(state):
+        stride, passes, rows = state
+        behind = jax.lax.dynamic_slice(jnp.concatenate([nothing, rows]),
+                                       (cap - stride,), (cap,))
+        return stride * 2, passes + 1, jnp.maximum(rows, behind)
+
+    _, passes, p_of = jax.lax.while_loop(
+        lambda state: jnp.any(state[2] < 0), look_back,
+        (jnp.int32(1), jnp.int32(0), marks))
+    return p_of, pair_live, passes
+
+
 def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
                             cap: int) -> JoinPairs:
     """TPU join: sort the build hashes, find every probe hash's range of
-    candidates (`_probe_ranges`), expand the ranges into `cap` pair slots,
-    verify the pairs on the key lanes."""
+    candidates (`_probe_ranges`), expand the ranges into `cap` pair slots
+    (`_expand_rows` says which probe row owns a slot; the slot's build position
+    is one gathered word more), verify the pairs on the key lanes."""
     b_live = _effective_live(build_keys, build_live)
     p_live = _effective_live(probe_keys, probe_live)
     nb = build_keys[0][0].shape[0]
@@ -768,7 +814,7 @@ def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
         ends = jnp.zeros(npr, jnp.int64)
         return JoinPairs(none, none, jnp.zeros(cap, jnp.bool_),
                          jnp.zeros(npr, jnp.bool_), ends, ends,
-                         jnp.bool_(False), jnp.int32(0))
+                         jnp.bool_(False), jnp.int32(0), jnp.int32(0))
 
     with jax.named_scope("join_pairs/sort"):
         h_b = jnp.minimum(hash_columns(build_keys), _TOP_LIVE_HASH)
@@ -788,14 +834,14 @@ def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
         starts = offsets - counts
 
     with jax.named_scope("join_pairs/expand"):
-        # ragged expansion: slot j -> probe row p, k-th candidate
-        slots = jnp.arange(cap, dtype=jnp.int64)
-        p_of = jnp.searchsorted(offsets, slots, side="right").astype(jnp.int32)
-        p_of = jnp.clip(p_of, 0, npr - 1)
-        k = slots - starts[p_of]
-        pair_live = slots < jnp.minimum(total, cap)
-        bpos = jnp.clip(left[p_of] + k.astype(jnp.int32), 0, nb - 1)
-        b_of = perm[bpos].astype(jnp.int32)
+        # ragged expansion: slot j -> probe row p, k-th candidate.  `left` less
+        # the row's first slot is one 32-bit lane, so slot j's build position
+        # is one gathered word plus j (wrapping as `left + int32(j - start)`)
+        p_of, pair_live, expand_levels = _expand_rows(starts, offsets, cap)
+        first_candidate = left - starts.astype(jnp.int32)
+        bpos = jnp.clip(first_candidate[p_of] + jnp.arange(cap).astype(jnp.int32),
+                        0, nb - 1)
+        b_of = perm.astype(jnp.int32)[bpos]  # `argsort` counts in 64 bits
 
     with jax.named_scope("join_pairs/verify"):
         # verify candidate pairs on the actual key lanes (hash collisions filtered here)
@@ -810,7 +856,7 @@ def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
         probe_matched = probe_matched_from(verified, starts, offsets)
 
     return JoinPairs(b_of, p_of, verified, probe_matched, starts, offsets,
-                     overflow, levels)
+                     overflow, levels, expand_levels)
 
 
 def _device_csr(build_keys, build_live, nb: int):

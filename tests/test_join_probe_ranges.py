@@ -1,10 +1,14 @@
 """The TPU join's range lookup (`K._probe_ranges`: prefix directory, bounded
 search, run lengths) against a NumPy oracle that searches the whole sorted
 build lane twice, as the formulation did before: every leaf of `JoinPairs` equal,
-the depth it reports, and the same function under `shard_map`.
+the depth it reports, and the same function under `shard_map`; and its ragged
+expansion (`K._expand_rows`: one scatter, a running maximum by doubling
+strides) against the same oracle's full-depth search for every pair slot.
 
 The sorted formulation is called directly: on this backend `hash_join_pairs`
 picks the slot-table one."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +73,23 @@ def _widest_bucket(h_sorted, nb):
     if not live.size:
         return 0
     return np.bincount((live >> np.uint64(64 - k_bits)).astype(np.int64)).max()
+
+
+def expand_passes(starts, offsets, cap) -> np.int32:
+    """Passes `K._expand_rows` has to report: `bit_length` of the farthest a
+    slot with a pair lies from its row's first slot."""
+    starts, offsets = np.asarray(starts), np.asarray(offsets)
+    if not offsets.size:
+        return np.int32(0)
+    filled = min(int(offsets[-1]), cap)
+    farthest = np.minimum(offsets, filled) - 1 - starts  # below 0: no pair, or past `cap`
+    return np.int32(max(int(farthest.max()), 0).bit_length())
+
+
+def with_expand_passes(want: K.JoinPairs, cap) -> K.JoinPairs:
+    """The oracle's leaves, with the one it does not build."""
+    return want._replace(expand_levels=expand_passes(
+        want.probe_starts, want.probe_offsets, cap))
 
 
 def _lane(rng, n, ndv, null_share=0.0, dtype=np.int64):
@@ -150,6 +171,51 @@ def _one_dead_build_slot(rng):
         np.zeros(1, bool), np.ones(50, bool), 64
 
 
+def _fk(rng, nb, probe_keys, plive=None, cap=None):
+    """Unique build keys `0..nb-1`: a probe row has one pair or none."""
+    pk = np.asarray(probe_keys, np.int64)
+    plive = np.ones(pk.shape[0], bool) if plive is None else plive
+    return [(jnp.asarray(rng.permutation(nb).astype(np.int64)), None)], \
+        [(jnp.asarray(pk), None)], np.ones(nb, bool), plive, cap
+
+
+def _long_empty_run(rng):
+    # two matches, 5,000 probe rows without a pair between them and 3,000 behind
+    pk = np.full(8002, 999, np.int64)
+    pk[0], pk[5001] = 3, 4
+    return _fk(rng, 64, pk, cap=1 << 10)
+
+
+def _many_pairs_beside_none(rng):
+    # rows with 300 pairs, with 2 and with none, next to each other
+    nb, npr = 1000, 400
+    bk = np.concatenate([np.zeros(300), np.ones(2), np.arange(2, 700)]).astype(np.int64)
+    pk = rng.choice(np.array([0, 1, 5000, 6000, 7], np.int64), npr)
+    return [(jnp.asarray(bk), None)], [(jnp.asarray(pk), None)], np.ones(nb, bool), \
+        rng.random(npr) > 0.1, 1 << 15
+
+
+def _no_pair_at_all(rng):
+    return _fk(rng, 256, rng.integers(1000, 2000, 700), cap=1 << 10)
+
+
+def _total_is_cap(rng):
+    # every live probe row has one pair: 777 pairs in 777 slots
+    plive = np.arange(1000) % 9 != 0
+    return _fk(rng, 512, rng.integers(0, 512, 1000), plive, cap=int(plive.sum()))
+
+
+def _quarter_step_cap(rng):
+    # 5 x 2^8 slots, as `bucket_capacity` makes them, two thirds full
+    return _fk(rng, 512, rng.integers(0, 640, 1000), cap=1280)
+
+
+def _a_few_probe_rows(rng):
+    bk = jnp.asarray(np.repeat(np.arange(8), 5).astype(np.int64))
+    return [(bk, None)], [(jnp.asarray(np.array([3, 9, 3, 0, 7], np.int64)), None)], \
+        np.ones(40, bool), np.array([1, 1, 0, 1, 1], bool), 64
+
+
 CASES = {
     "unique_build_keys": _unique,
     "heavy_duplicates": _duplicates,
@@ -163,6 +229,12 @@ CASES = {
     "npr_0": _no_probe_rows,
     "nb_1": _one_build_slot,
     "nb_1_dead": _one_dead_build_slot,
+    "long_run_of_empty_probe_rows": _long_empty_run,
+    "many_pairs_beside_none": _many_pairs_beside_none,
+    "total_0": _no_pair_at_all,
+    "total_is_cap": _total_is_cap,
+    "quarter_step_cap": _quarter_step_cap,
+    "npr_5": _a_few_probe_rows,
 }
 
 
@@ -194,16 +266,31 @@ def test_every_leaf_equals_the_full_search_oracle(case, collide, monkeypatch):
     # a fresh function each time: a trace cached under the other hash must not answer
     got = jax.jit(lambda *a: K._hash_join_pairs_sorted(*a, cap))(
         bkeys, pkeys, jnp.asarray(blive), jnp.asarray(plive))
-    want = oracle(bkeys, pkeys, blive, plive, cap)
+    want = with_expand_passes(oracle(bkeys, pkeys, blive, plive, cap), cap)
     _assert_equal_pairs(got, want)
     nb = blive.shape[0]
     assert 0 <= int(got.search_levels) <= K.full_search_depth(nb)
+    assert 0 <= int(got.expand_levels) <= K.full_search_depth(cap - 1)
     if case == "cap_too_small":
         assert bool(got.overflow) != collide  # 16,384 candidates in 256 slots
     if case == "one_hot_key":
         assert int(got.search_levels) == K.full_search_depth(nb) == 10
     if case == "all_dead_build":
         assert int(got.search_levels) == 0 and not np.asarray(got.live).any()
+    if collide:
+        return
+    total = int(np.asarray(got.probe_offsets)[-1]) if plive.shape[0] else 0
+    if case == "total_0":
+        assert total == 0 and int(got.expand_levels) == 0
+        assert (np.asarray(got.probe_idx) == plive.shape[0] - 1).all()
+    if case == "total_is_cap":
+        assert total == cap == int(np.asarray(got.live).sum())
+        assert not bool(got.overflow)
+    if case == "long_run_of_empty_probe_rows":
+        assert np.asarray(got.probe_idx)[:3].tolist() == [0, 5001, 8001]
+        assert int(got.expand_levels) == 0
+    if case == "many_pairs_beside_none":
+        assert int(got.expand_levels) == 9  # a row's 300th pair, 299 slots on
 
 
 def test_verified_pairs_are_the_equal_keys():
@@ -249,10 +336,27 @@ def test_uniform_keys_search_a_few_levels_of_the_full_depth():
     assert int(np.asarray(r.live).sum()) == npr and not bool(r.overflow)
 
 
+@pytest.mark.parametrize("pairs_a_row,passes", [(1, 0), (2, 1), (5, 3), (64, 6)])
+def test_expansion_fills_forward_as_far_as_the_most_pairs_of_a_row(pairs_a_row, passes):
+    """None on a key-to-foreign-key join, however many rows lack a pair, and
+    `bit_length(pairs - 1)` otherwise: never the depth of a search over the
+    probe lane."""
+    keys, npr = 4096, 50_000
+    rng = np.random.default_rng(31)
+    bk = jnp.asarray(rng.permutation(np.repeat(np.arange(keys), pairs_a_row)))
+    pk = jnp.asarray(rng.integers(0, 4 * keys, npr))  # three rows in four: no pair
+    cap = 1 << 20
+    r = jax.jit(lambda *a: K._hash_join_pairs_sorted(*a, cap))(
+        [(bk, None)], [(pk, None)], jnp.ones(bk.shape[0], bool), jnp.ones(npr, bool))
+    assert int(r.expand_levels) == passes < K.full_search_depth(npr) == 16
+    assert int(np.asarray(r.live).sum()) == \
+        pairs_a_row * int((np.asarray(pk) < keys).sum())
+
+
 def test_slot_table_formulation_reports_no_depth():
     one = [(jnp.zeros(8, jnp.int64), None)]
     r = K._hash_join_pairs_table(one, one, jnp.ones(8, bool), jnp.ones(8, bool), 128)
-    assert r.search_levels is None
+    assert r.search_levels is None and r.expand_levels is None
 
 
 def test_traces_and_agrees_under_shard_map():
@@ -274,7 +378,8 @@ def test_traces_and_agrees_under_shard_map():
     def block(bk, pk, blive, plive):
         r = K._hash_join_pairs_sorted([(bk, None)], [(pk, None)], blive, plive, cap)
         return r._replace(overflow=r.overflow[None],
-                          search_levels=r.search_levels[None])
+                          search_levels=r.search_levels[None],
+                          expand_levels=r.expand_levels[None])
 
     mesh = Mesh(np.array(jax.devices()[:shards]), ("x",))
     fn = jax.jit(shard_map(block, mesh=mesh, in_specs=(P("x"),) * 4,
@@ -282,19 +387,26 @@ def test_traces_and_agrees_under_shard_map():
     flat = [jnp.asarray(a.reshape(-1)) for a in (bk, pk, blive, plive)]
     got = fn(*flat)
     for s in range(shards):
-        want = oracle([(bk[s], None)], [(pk[s], None)], blive[s], plive[s], cap)
+        want = with_expand_passes(
+            oracle([(bk[s], None)], [(pk[s], None)], blive[s], plive[s], cap), cap)
         mine = K.JoinPairs(*(np.asarray(leaf).reshape(shards, -1)[s].reshape(
             np.shape(w)) for leaf, w in zip(got, want)))
         _assert_equal_pairs(mine, want)
     levels = np.asarray(got.search_levels).tolist()
     assert levels[0] == K.full_search_depth(nb) and levels[3] == 0
+    # the expansion's loop likewise: 512 pairs a row on shard 0, a handful on
+    # shards 1 and 2, none on shard 3
+    passes = np.asarray(got.expand_levels).tolist()
+    assert passes[0] == 9 and 1 <= passes[1] <= 4 and 1 <= passes[2] <= 4
+    assert passes[3] == 0
 
 
 @pytest.mark.parametrize("join_type", ["inner", "left", "semi", "anti"])
 def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, request):
     """`HashJoinOp` on the TPU's formulation: one count per probe batch in
-    `JOIN_STATS`, "levels of full depth" on the span under the cursor, and the
-    rows of the slot-table formulation."""
+    `JOIN_STATS`, "levels of full depth" on the span under the cursor for the
+    range search and for the expansion, and the rows of the slot-table
+    formulation."""
     from galaxysql_tpu.chunk.batch import Column, ColumnBatch
     from galaxysql_tpu.exec import operators as ops
     from galaxysql_tpu.expr import ir
@@ -306,7 +418,8 @@ def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, request)
         return ColumnBatch({name: col}, None if live is None else jnp.asarray(live))
 
     rng = np.random.default_rng(29)
-    build = batch("k", rng.permutation(3000)[:2000])
+    keys = rng.permutation(3000)[:1000]
+    build = batch("k", np.concatenate([keys, keys]))  # two pairs a matching row
     probes = [batch("a", rng.integers(0, 3000, 700), rng.random(700) > 0.1)
               for _ in range(2)]
     bk, pk = [ir.ColRef("k", dt.BIGINT, None)], [ir.ColRef("a", dt.BIGINT, None)]
@@ -342,3 +455,69 @@ def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, request)
     assert 2 <= levels <= 2 * 8 < 2 * full
     assert span.attrs["search_levels"] == f"{levels} of {2 * full}"
     assert f"search_levels={levels} of {2 * full}" in "\n".join(tc.tree_lines())
+    # the expansion: one pass a probe carries a row's id to its second pair,
+    # where a search over the 700 probe slots would run ten levels
+    expand_full = K.full_search_depth(700)
+    assert ops.JOIN_STATS["expand_levels"] == before["expand_levels"] + 2
+    assert ops.JOIN_STATS["expand_full_depth_levels"] == \
+        before["expand_full_depth_levels"] + 2 * expand_full
+    assert span.attrs["expand_levels"] == f"2 of {2 * expand_full}"
+    assert f"expand_levels=2 of {2 * expand_full}" in "\n".join(tc.tree_lines())
+
+
+def _located(text):
+    """Every `stablehlo` operation of a lowered module's text as `(name, its
+    line, its location spelled out)`: `loc(#loc7)` is followed through the
+    aliases at the text's end, and an operation that holds a region (`while`,
+    `scatter`, `reduce_window`) has its types and its location where the region
+    closes, so its line is the first and the last."""
+    alias = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, re.M))
+
+    def spell(ref, depth=0):
+        body = alias.get(ref, "")
+        return body if depth > 20 else re.sub(
+            r"#loc\d+", lambda m: spell(m.group(0), depth + 1), body)
+
+    open_ = []  # operations (or functions: None) whose region is still open
+    for line in text.splitlines():
+        op = re.search(r"stablehlo\.\w+", line)
+        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        closes = re.match(r"\s*\}", line)
+        if closes and at and open_:
+            started = open_.pop()
+            if started is not None:
+                yield started[0], started[1] + line, spell(at.group(1))
+        elif op and at and not closes:
+            yield op.group(0), line, spell(at.group(1))
+        elif op and "stablehlo.while(" in line:  # `cond {`, `} do {`, `} loc(..)` follow
+            open_.append((op.group(0), line))
+        elif not closes and line.rstrip().endswith("{") and line.strip() != "cond {":
+            open_.append((op.group(0), line) if op else None)
+
+
+def test_expand_scope_gathers_no_64_bit_lane_and_nothing_runs_a_window():
+    """What the chip's compiler is handed (lowered for a TPU here, no chip):
+    inside `join_pairs/expand` no gather reads a 64-bit lane (two words a
+    gathered element on the chip), and no running maximum or minimum came back
+    as a reduce-window (33-40 s of compile a program, PERF.md PR 26): the only
+    windows are `jnp.cumsum`'s two running sums, which stood before."""
+    def run(bk, pk, blive, plive):
+        return K._hash_join_pairs_sorted([(bk, None)], [(pk, None)], blive, plive, 640)
+
+    shapes = [jax.ShapeDtypeStruct((n,), t) for n, t in
+              ((256, jnp.int64), (1000, jnp.int64), (256, jnp.bool_), (1000, jnp.bool_))]
+    text = jax.jit(run).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    ops_ = list(_located(text))
+    in_expand = [(op, line) for op, line, where in ops_ if "join_pairs/expand" in where]
+    assert {"stablehlo.scatter", "stablehlo.gather", "stablehlo.while"} <= \
+        {op for op, _ in in_expand}  # the scope is found, and is the expansion
+    gathers = [line for op, line in in_expand if op == "stablehlo.gather"]
+    assert len(gathers) == 2
+    for line in gathers:
+        operand = re.search(r": \(tensor<\d+x(\w+)>", line).group(1)
+        assert operand in ("i32", "ui32"), line
+    assert not [line for op, line in in_expand if op == "stablehlo.scatter"
+                and not re.search(r"\}\) : \(tensor<\d+xi32>", line)]
+    windows = [where for op, _, where in ops_ if op == "stablehlo.reduce_window"]
+    assert len(windows) == 2 and all("reduce_window_sum" in w for w in windows), windows
