@@ -65,6 +65,35 @@ EXCHANGE_STATS = {"statements": 0, "all_to_all_calls": 0, "all_to_all_bytes": 0,
 _COST_KEYS = ("all_to_all_calls", "all_to_all_bytes", "all_gather_calls",
               "all_gather_bytes", "slots")
 
+# The equi-joins that ran to their end, cumulative since process start, by the
+# plan's join kind and the exchange the executor gave it (`<kind>_<exchange>`,
+# once a join whatever its ladder took), and `shuffle_build_rows`: the live
+# build rows a shuffle or hybrid join's `all_to_all` delivered (over all
+# shards, the rung that settled; a hybrid join's cold rows).  Read like EXCHANGE_STATS: a per-statement
+# value is a ratio with the statements sent.
+JOIN_KINDS = ("inner", "left", "semi", "anti")
+JOIN_EXCHANGES = ("broadcast", "shuffle", "hybrid", "replicated")
+MPP_JOIN_STATS = dict({f"{k}_{x}": 0 for k in JOIN_KINDS
+                       for x in JOIN_EXCHANGES}, shuffle_build_rows=0)
+
+# Where a capacity ladder settled: the key of its first rung's program (the
+# plan's join, its sides' columns and slots, the sizes the formulas start
+# from) -> the sizes that ran without an overflow.  The next statement with
+# that key starts there, so a join whose pairs or whose co-located rows
+# outgrow the formulas (an EXISTS over a fact table; a second shuffle on the
+# key the first one dealt by) climbs once a process and not once a statement.
+# Only a ladder that climbed is kept; sides of other sizes make another key.
+_SETTLED: Dict[tuple, tuple] = {}
+_SETTLED_LIMIT = 4096
+
+
+def _ladder_settled(first_key: tuple, rung: tuple, retries: int):
+    if not retries:
+        return
+    if len(_SETTLED) >= _SETTLED_LIMIT:
+        _SETTLED.clear()
+    _SETTLED[first_key] = rung
+
 
 def _exchange_report(costs, *lives):
     """Inside a shard_map block, what a program returns beside its overflow
@@ -929,6 +958,16 @@ class MppExecutor:
         self.ctx.trace.append(f"mpp-rf-publish filters={len(specs)}")
         return specs
 
+    @staticmethod
+    def _join_key(node, build_keys, probe_keys, build_ids, probe_ids):
+        """What a join program's key says of the plan's join: its kind, key
+        expressions, residual and the two sides' columns."""
+        return (node.kind,
+                tuple(expr_cache_key(e) for e in build_keys),
+                tuple(expr_cache_key(e) for e in probe_keys),
+                expr_cache_key(node.residual) if node.residual is not None else None,
+                tuple(build_ids), tuple(probe_ids))
+
     def _join_key_fns(self, build_keys, probe_keys):
         comp = ExprCompiler(jnp)
         bk, pk = [], []
@@ -951,13 +990,15 @@ class MppExecutor:
                         build_ids, probe_ids):
         probe_R = int(probe.live.shape[0]) // self.S
         cap = bucket_capacity(max(probe_R * 2, 1024))
+
+        join = self._join_key(node, build_keys, probe_keys, build_ids,
+                              probe_ids)
+        first = ("mpp_bjoin", *join, build.replicated, self.S, cap,
+                 int(build.live.shape[0]), probe_R)
+        cap, = _SETTLED.get(first, (cap,))
         retries = 0
         while True:
-            key = ("mpp_bjoin", node.kind,
-                   tuple(expr_cache_key(e) for e in build_keys),
-                   tuple(expr_cache_key(e) for e in probe_keys),
-                   expr_cache_key(node.residual) if node.residual is not None else None,
-                   tuple(build_ids), tuple(probe_ids), build.replicated, self.S, cap)
+            key = ("mpp_bjoin", *join, build.replicated, self.S, cap)
 
             def builder():
                 bk, pk = self._join_key_fns(build_keys, probe_keys)
@@ -997,6 +1038,7 @@ class MppExecutor:
             over, (vec, counts) = jax.device_get(res)
             live, slots = _note_exchange(vec, counts[:, 1:], over)
             if not bool(over):
+                _ladder_settled(first, (cap,), retries)
                 attrs = {"exchange": "replicated"} if build.replicated else {
                     "exchange": "broadcast", "build_slots": slots // self.S,
                     "build_rows": live // self.S, "fill": _fill(live, slots)}
@@ -1021,13 +1063,14 @@ class MppExecutor:
             int(build.live.shape[0]) // self.S,
             int(probe.live.shape[0]) // self.S)
         cap = bucket_capacity(max(2 * quota_p * self.S, 1024))
+
+        join = self._join_key(node, build_keys, probe_keys, build_ids,
+                              probe_ids)
+        first = ("mpp_sjoin", *join, self.S, quota_b, quota_p, cap)
+        quota_b, quota_p, cap = _SETTLED.get(first, (quota_b, quota_p, cap))
         retries = 0
         while True:
-            key = ("mpp_sjoin", node.kind,
-                   tuple(expr_cache_key(e) for e in build_keys),
-                   tuple(expr_cache_key(e) for e in probe_keys),
-                   expr_cache_key(node.residual) if node.residual is not None else None,
-                   tuple(build_ids), tuple(probe_ids), self.S, quota_b, quota_p, cap)
+            key = ("mpp_sjoin", *join, self.S, quota_b, quota_p, cap)
 
             def builder():
                 bk, pk = self._join_key_fns(build_keys, probe_keys)
@@ -1078,6 +1121,7 @@ class MppExecutor:
             moved, slots = _note_exchange(vec, counts[:, :2],
                                           over_b or over_p or over_cap)
             if not (over_b or over_p or over_cap):
+                _ladder_settled(first, (quota_b, quota_p, cap), retries)
                 return out, counts[:, 2], {
                     "exchange": "shuffle", "quota_b": quota_b,
                     "quota_p": quota_p, "cap": cap, "retries": retries,
@@ -1152,15 +1196,17 @@ class MppExecutor:
         # BALANCED across shards (that is the point), so the fair-share bound
         # holds where the plain shuffle's hot shard overflows it
         cap = bucket_capacity(max(2 * quota_p * self.S, 1024))
+
+        kind, *join = self._join_key(node, build_keys, probe_keys, build_ids,
+                                     probe_ids)
+        first = ("mpp_hybrid_join", kind, active.orientation, *join, self.S,
+                 H, hot_quota, loc_quota, quota_b, quota_p, cap, bR, pR)
+        hot_quota, loc_quota, quota_b, quota_p, cap = _SETTLED.get(
+            first, (hot_quota, loc_quota, quota_b, quota_p, cap))
         retries = 0
         while True:
-            key = ("mpp_hybrid_join", node.kind, active.orientation,
-                   tuple(expr_cache_key(e) for e in build_keys),
-                   tuple(expr_cache_key(e) for e in probe_keys),
-                   expr_cache_key(node.residual)
-                   if node.residual is not None else None,
-                   tuple(build_ids), tuple(probe_ids), self.S, H,
-                   hot_quota, loc_quota, quota_b, quota_p, cap)
+            key = ("mpp_hybrid_join", kind, active.orientation, *join, self.S,
+                   H, hot_quota, loc_quota, quota_b, quota_p, cap)
 
             def builder():
                 bk, pk = self._join_key_fns(build_keys, probe_keys)
@@ -1301,10 +1347,14 @@ class MppExecutor:
             overflowed = over_h or over_l or over_b or over_p or over_cap
             moved, slots = _note_exchange(vec, counts[:, :3], overflowed)
             if not overflowed:
+                _ladder_settled(
+                    first, (hot_quota, loc_quota, quota_b, quota_p, cap),
+                    retries)
                 return out, counts[:, 3], {
                     "exchange": "hybrid", "quota_b": quota_b,
                     "quota_p": quota_p, "hot_quota": hot_quota, "cap": cap,
-                    "retries": retries, "fill": _fill(moved, slots)}
+                    "retries": retries, "fill": _fill(moved, slots),
+                    "build_rows": int(counts[:, 0].sum())}
             retries += 1
             if over_h:
                 hot_quota *= 2
@@ -1322,6 +1372,13 @@ class MppExecutor:
 
     def _join_result(self, node, out, build, probe) -> DistBatch:
         (cols, live), out_rows, attrs = out
+        MPP_JOIN_STATS[f"{node.kind}_{attrs['exchange']}"] += 1
+        if attrs["exchange"] in ("shuffle", "hybrid"):
+            MPP_JOIN_STATS["shuffle_build_rows"] += attrs["build_rows"]
+        attrs["kind"] = node.kind
+        attrs["residual"] = int(node.residual is not None)
+        if node.kind in ("semi", "anti"):
+            attrs["matched"] = int(out_rows.sum())  # probe rows kept
         for name, side in (("compact_b", build), ("compact_p", probe)):
             if side.compacted is not None:
                 attrs[name] = "%d/%d" % side.compacted  # slots a shard, in/out
