@@ -1860,7 +1860,9 @@ class HashJoinOp(Operator):
         `nb` build slots, the expansion's passes of a search of `npr` probe
         slots) and, in a traced statement, write "levels of full depth" so far
         onto the operator's span (the cursor is this join's `op:Join` while its
-        batches are pulled)."""
+        batches are pulled), and beside them the width of the prefix directory
+        this probe's program was built with (`K.directory_bits`, from the two
+        shapes: nothing is read from the device for it)."""
         levels, expand = (int(n) for n in jax.device_get(
             (pairs.search_levels, pairs.expand_levels)))  # one read, not two
         full, expand_full = K.full_search_depth(nb), K.full_search_depth(npr)
@@ -1881,6 +1883,7 @@ class HashJoinOp(Operator):
                 f"{self.search_levels} of {self.full_depth_levels}"
             sp.attrs["expand_levels"] = \
                 f"{self.expand_levels} of {self.expand_full_depth_levels}"
+            sp.attrs["dir_bits"] = K.directory_bits_note(nb, npr)
 
     def _device_probe(self, build_batch: ColumnBatch, art,
                       stored: bool) -> Iterator[ColumnBatch]:

@@ -667,7 +667,11 @@ def hash_join_pairs(build_keys: Sequence[Tuple[Any, Optional[Any]]],
     build hashes and finds each probe's range through a prefix directory, a
     bounded search and run lengths (`_probe_ranges`; sorts vectorize, and a
     gather pass over 6.3M probe slots costs 54-117 ms on a v5e, so the passes
-    are what it saves: 7-10 where two whole-lane searches made 36-44), then
+    are what it saves: 7-10 where two whole-lane searches made 36-44, at
+    `tpch_sf1.join`'s shape, a probe side larger than its build side; the
+    directory's width is chosen from BOTH static shapes, `directory_bits(nb,
+    npr)`, so a probe side a hundredth of its build side indexes that build
+    side with a few thousand full-depth searches and not half a million), then
     expands the ranges into pair slots with one scatter of row ids and a
     running maximum (`_expand_rows`; two gathered words a pair slot where a
     whole-lane search of the 64-bit running count makes about 50); the CPU
@@ -719,6 +723,35 @@ def _run_ends(h_sorted):
                               look_ahead, (jnp.int32(1), ends))[1]
 
 
+def directory_bits(nb: int, npr: int) -> int:
+    """Bits K of hash prefix that `_probe_ranges` indexes `nb` sorted build
+    slots by to serve `npr` probe slots: a pure function of the two static
+    shapes (a span reads it on the host, `dir_bits=`).
+
+    In gathered 32-bit words (a 64-bit lane is two on a v5e), with `D =
+    bit_length(nb)` and `L ~ D - K + 2` levels of the bounded search on uniform
+    hashes:
+
+        words(K) = 2 * D * (2^K + 1)       # the directory: full-depth searches
+                 + npr * (2 + 2 * L + 3)   # a probe slot: dir[b], dir[b + 1], L
+                                           # two-word steps, h_sorted[at], run_end[at]
+
+    which is least near `2^K = npr / (D ln 2)`, about `bit_length(npr) - 4` or
+    `- 5`, and flat around it.  A directory finer than 8-16 slots a bucket
+    saves no level, so `bit_length(nb) - 4` bounds it: that is the width
+    wherever the probe side is the larger one (`npr >= nb`; `tpch_sf1.join`'s
+    6M-slot probes), and a build side of 4,194,304 slots probed by 65,536
+    takes 13 bits where it took 19 (25.1M gathered words become 2.3M)."""
+    return max(1, min(int(nb).bit_length() - 4, int(npr).bit_length() - 4))
+
+
+def directory_bits_note(nb: int, npr: int) -> str:
+    """`dir_bits=` as a join's span carries it: the width the program was
+    built with, of the width the build side alone would give ("13 of 19": a
+    narrowed directory; "9 of 9": the probe side is no smaller)."""
+    return f"{directory_bits(nb, npr)} of {directory_bits(nb, nb)}"
+
+
 def _probe_ranges(h_sorted, h_p):
     """Range of equal hashes in the sorted build lane for every probe hash:
     `(left, run, levels)`, `left` as `searchsorted(h_sorted, h_p, "left")`
@@ -729,12 +762,16 @@ def _probe_ranges(h_sorted, h_p):
     (`dir[b]` = first slot whose bucket is >= b; dead rows take bucket 2^K, so
     `dir[2^K]` is the live count and padding is never searched) bounds a
     binary search whose trip count, `bit_length(widest bucket)`, is a device
-    scalar read off the directory: 3-6 levels on uniform hashes, the full
-    depth only when the build side is one hot key.  The range's end needs no
-    second search: the run of equal hashes that starts at `left` ends where
-    `_run_ends` says."""
+    scalar read off the directory: 3-6 levels on uniform hashes where K is
+    the build side's `bit_length(nb) - 4`, the full depth only when the build
+    side is one hot key.  K is `directory_bits(nb, npr)`, which reads both
+    shapes: the directory costs `2^K + 1` full-depth searches whoever asks,
+    so a probe side much smaller than the build side takes a narrower one and
+    its wider buckets make the loop run that much longer by itself.  The
+    range's end needs no second search: the run of equal hashes that starts
+    at `left` ends where `_run_ends` says."""
     nb = h_sorted.shape[0]
-    k_bits = max(nb.bit_length() - 4, 1)  # 8-16 slots a bucket when all are live
+    k_bits = directory_bits(nb, h_p.shape[0])
     shift = jnp.uint64(64 - k_bits)
     # 2^K + 1 full-depth queries, where every probe slot made two
     bounds = jnp.concatenate([jnp.arange(1 << k_bits, dtype=jnp.uint64) << shift,

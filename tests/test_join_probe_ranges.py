@@ -3,7 +3,9 @@ search, run lengths) against a NumPy oracle that searches the whole sorted
 build lane twice, as the formulation did before: every leaf of `JoinPairs` equal,
 the depth it reports, and the same function under `shard_map`; and its ragged
 expansion (`K._expand_rows`: one scatter, a running maximum by doubling
-strides) against the same oracle's full-depth search for every pair slot.
+strides) against the same oracle's full-depth search for every pair slot; the
+directory's width (`K.directory_bits`, from both sides' shapes) alone, in the
+lowered text, and as `dir_bits=` on the spans of a join.
 
 The sorted formulation is called directly: on this backend `hash_join_pairs`
 picks the slot-table one."""
@@ -60,16 +62,17 @@ def oracle(build_keys, probe_keys, build_live, probe_live, cap) -> K.JoinPairs:
         verified = verified & (np.asarray(bd)[b_of] == np.asarray(pd)[p_of])
     c = np.concatenate([[0], np.cumsum(verified)])
     matched = (c[np.clip(offsets, 0, cap)] - c[np.clip(starts, 0, cap)]) > 0
-    widest = _widest_bucket(h_sorted, nb)
+    widest = _widest_bucket(h_sorted, nb, npr)
     return K.JoinPairs(b_of, p_of, verified, matched, starts, offsets,
                        np.bool_(total > cap), np.int32(int(widest).bit_length()))
 
 
-def _widest_bucket(h_sorted, nb):
-    """Most live hashes that share their top `bit_length(nb) - 4` bits (one bit
-    at least)."""
+def _widest_bucket(h_sorted, nb, npr):
+    """Most live hashes that share the top bits the directory indexes: the
+    smaller of `bit_length(nb) - 4` and `bit_length(npr) - 4` (one bit at
+    least)."""
     live = h_sorted[h_sorted != DEAD]
-    k_bits = max(int(nb).bit_length() - 4, 1)
+    k_bits = max(min(int(nb).bit_length(), int(npr).bit_length()) - 4, 1)
     if not live.size:
         return 0
     return np.bincount((live >> np.uint64(64 - k_bits)).astype(np.int64)).max()
@@ -216,6 +219,31 @@ def _a_few_probe_rows(rng):
         np.ones(40, bool), np.array([1, 1, 0, 1, 1], bool), 64
 
 
+RATIO_NB = 2048
+BUILD_KINDS = {
+    # build keys, live build rows, distinct keys a probe row draws from
+    "unique": lambda rng: (rng.permutation(RATIO_NB), np.ones(RATIO_NB, bool), 2 * RATIO_NB),
+    # runs of 1-7 rows a key, as `l_orderkey`
+    "runs_of_1_to_7": lambda rng: (
+        np.repeat(np.arange(RATIO_NB), rng.integers(1, 8, RATIO_NB))[:RATIO_NB],
+        np.ones(RATIO_NB, bool), RATIO_NB // 2),
+    "live_35_percent": lambda rng: (rng.permutation(RATIO_NB),
+                                    rng.random(RATIO_NB) < 0.35, 2 * RATIO_NB),
+    "one_hot_key": lambda rng: (np.full(RATIO_NB, 7), np.ones(RATIO_NB, bool), 40),
+}
+# probe slots for one build slot: `npr` = nb / 64, nb / 8, nb, 8 x nb
+RATIOS = {"nb_over_64": 1 / 64, "nb_over_8": 1 / 8, "nb": 1, "nb_times_8": 8}
+
+
+def _ratio_case(kind, ratio):
+    def make(rng):
+        bk, blive, domain = BUILD_KINDS[kind](rng)
+        npr = int(RATIO_NB * RATIOS[ratio])
+        return [(jnp.asarray(bk.astype(np.int64)), None)], [_lane(rng, npr, domain)], \
+            blive, rng.random(npr) > 0.1, 1 << 16
+    return make
+
+
 CASES = {
     "unique_build_keys": _unique,
     "heavy_duplicates": _duplicates,
@@ -236,6 +264,8 @@ CASES = {
     "quarter_step_cap": _quarter_step_cap,
     "npr_5": _a_few_probe_rows,
 }
+CASES.update({f"{kind}_build_npr_{ratio}": _ratio_case(kind, ratio)
+              for kind in BUILD_KINDS for ratio in RATIOS})
 
 
 def _three_bit_hash(cols):
@@ -463,6 +493,168 @@ def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, request)
         before["expand_full_depth_levels"] + 2 * expand_full
     assert span.attrs["expand_levels"] == f"2 of {2 * expand_full}"
     assert f"expand_levels=2 of {2 * expand_full}" in "\n".join(tc.tree_lines())
+    # the directory's width, from the shapes alone: 2,048 build slots probed
+    # by 700 take six bits of the eight the build side alone would give
+    assert span.attrs["dir_bits"] == K.directory_bits_note(
+        ops.bucket_capacity(2000), 700) == "6 of 8"
+    assert "dir_bits=6 of 8" in "\n".join(tc.tree_lines())
+
+
+@pytest.mark.parametrize("ratio", list(RATIOS))
+@pytest.mark.parametrize("kind", list(BUILD_KINDS))
+def test_left_and_run_are_searchsorteds(kind, ratio):
+    """`_probe_ranges` alone over a sorted lane of hashes, dead rows behind the
+    live ones: `left` and `run` bit for bit what two searches of the whole
+    lane give, at every width the two shapes choose."""
+    bkeys, pkeys, blive, _plive, _cap = _ratio_case(kind, ratio)(np.random.default_rng(37))
+    npr = pkeys[0][0].shape[0]
+    h_b = np.minimum(np.asarray(K.hash_columns(bkeys)), DEAD - np.uint64(1))
+    h_sorted = jnp.asarray(np.sort(np.where(blive, h_b, DEAD)))
+    h_p = jnp.minimum(K.hash_columns(pkeys), DEAD - np.uint64(1))
+    left, run, levels = jax.jit(K._probe_ranges)(h_sorted, h_p)
+    want_left = jnp.searchsorted(h_sorted, h_p, side="left")
+    assert (np.asarray(left) == np.asarray(want_left)).all()
+    assert (np.asarray(run) == np.asarray(
+        jnp.searchsorted(h_sorted, h_p, side="right") - want_left)).all()
+    assert int(levels) == int(_widest_bucket(np.asarray(h_sorted), RATIO_NB,
+                                             npr)).bit_length()
+    assert bool((np.asarray(run) > 0).any()) and bool((np.asarray(run) == 0).any())
+
+
+def _bucket_capacities():
+    from galaxysql_tpu.exec.operators import bucket_capacity
+    return sorted({bucket_capacity(n) for e in range(0, 25)
+                   for n in (1 << e, 5 << e >> 2, 3 << e >> 1, 7 << e >> 2)})
+
+
+def test_directory_bits_are_the_build_sides_own_where_the_probe_is_no_smaller():
+    """What keeps every program whose probe side is the larger one as it was:
+    over the capacities a batch can have, `npr >= nb` gives
+    `bit_length(nb) - 4` (one bit at least)."""
+    caps = _bucket_capacities()
+    assert len(caps) >= 40 and caps[-1] >= 1 << 24  # powers of two, then quarter steps
+    for nb in caps + [1, 2, 3, 1000, 4097]:
+        today = max(nb.bit_length() - 4, 1)
+        for npr in [n for n in caps if n >= nb] + [nb, nb + 1, 8 * nb]:
+            assert K.directory_bits(nb, npr) == today, (nb, npr)
+        assert K.directory_bits_note(nb, nb) == f"{today} of {today}"
+
+
+def test_directory_bits_never_fall_below_one_and_never_fall_as_the_probe_grows():
+    caps = _bucket_capacities()
+    for nb in caps:
+        widths = [K.directory_bits(nb, npr) for npr in [0, 1, 2, 15, 16, 17] + caps]
+        assert widths[0] == 1 and min(widths) >= 1
+        assert widths == sorted(widths), nb
+        assert widths[-1] == max(nb.bit_length() - 4, 1) == max(widths)
+
+
+@pytest.mark.parametrize("join,nb,npr,now,before", [
+    # `tpch_sf1_mpp4_subq.semi_anti`'s five joins, slots a shard (PERF.md section 5)
+    ("q21_semi", 4_194_304, 65_536, 13, 19),
+    ("q21_anti", 2_097_152, 131_072, 14, 18),
+    ("q4_semi", 2_097_152, 32_768, 12, 18),
+    ("orders_broadcast", 2_097_152, 65_536, 13, 18),
+    ("supplier_broadcast", 4_096, 1_048_576, 9, 9),
+    # `tpch_sf1_mpp4.join_q3`'s shuffle join
+    ("join_q3_shuffle", 131_072, 32_768, 12, 14),
+])
+def test_directory_bits_at_the_mesh_cells_shapes(join, nb, npr, now, before):
+    assert K.directory_bits(nb, npr) == now
+    assert K.directory_bits_note(nb, npr) == f"{now} of {before}"
+    # the model the width comes from, in gathered 32-bit words: the width
+    # chosen costs no more than the build side's own, and is within a fifth
+    # of the best width there is
+    depth = nb.bit_length()
+
+    def words(k):
+        return 2 * depth * ((1 << k) + 1) + npr * (2 + 2 * (depth - k + 2) + 3)
+    assert words(now) <= words(before)
+    assert words(now) <= 1.2 * min(words(k) for k in range(1, before + 1))
+
+
+@pytest.mark.parametrize("exchange", ["broadcast", "shuffle"])
+def test_stage_span_carries_the_directory_bits_its_program_was_built_with(
+        exchange, monkeypatch, chip_formulation):
+    """A semi join through `MppExecutor` on four virtual devices, a fact
+    table on its build side: `dir_bits=` on `stage:Join` is the width the
+    traced kernel chose from the slots a shard joined, a narrowed directory
+    here; with the sides swapped, the build side's own.  The slot-table
+    formulation builds no directory and says nothing."""
+    from galaxysql_tpu.parallel import mpp as M
+    from galaxysql_tpu.parallel.mesh import make_mesh
+    from galaxysql_tpu.plan.physical import ExecContext
+    from galaxysql_tpu.server.instance import Instance
+    from galaxysql_tpu.server.session import Session
+    from galaxysql_tpu.utils import tracing
+
+    shards = 4
+    inst = Instance()
+    inst._mesh = make_mesh(shards)
+    s = Session(inst)
+    s.execute("CREATE DATABASE d")
+    s.execute("USE d")
+    for table in ("fact", "few"):
+        s.execute(f"CREATE TABLE {table} (id BIGINT NOT NULL PRIMARY KEY, "
+                  f"k BIGINT NOT NULL) PARTITION BY HASH(id) PARTITIONS 8")
+    rng = np.random.default_rng(5)
+    fact = {"id": np.arange(20_000), "k": rng.integers(0, 5000, 20_000)}
+    few = {"id": np.arange(300), "k": rng.integers(0, 10_000, 300)}
+    inst.store("d", "fact").insert_arrays(fact, inst.tso.next_timestamp())
+    inst.store("d", "few").insert_arrays(few, inst.tso.next_timestamp())
+    s.execute("ANALYZE TABLE fact, few")
+    if exchange == "shuffle":
+        monkeypatch.setattr(M, "BROADCAST_BUILD_LIMIT", 0)
+
+    traced = []  # (build slots, probe slots) of every range lookup traced
+    real = K._probe_ranges
+    monkeypatch.setattr(K, "_probe_ranges", lambda h_sorted, h_p: (
+        traced.append((h_sorted.shape[0], h_p.shape[0])), real(h_sorted, h_p))[1])
+
+    def run(outer, inner):
+        inst.frag_cache.clear()
+        plan = inst.planner.plan_select(
+            f"SELECT COUNT(*) FROM {outer} WHERE EXISTS "
+            f"(SELECT * FROM {inner} WHERE {inner}.k = {outer}.k)", "d")
+        ctx = ExecContext(inst.stores, inst.tso.next_timestamp(), [],
+                          archive=inst.archive, archive_instance=inst)
+        tc = tracing.TraceContext(32, node="t")
+        del traced[:]
+        with tracing.activate(tc):
+            batch = M.MppExecutor(ctx, make_mesh(shards)).execute(plan.rel)
+        join, = [sp for sp in tc.spans if sp.kind == "stage" and sp.name == "mpp:Join"]
+        assert join.attrs["exchange"] == exchange and join.attrs["kind"] == "semi"
+        return batch.to_pylist()[0][0], join.attrs, "\n".join(tc.tree_lines())
+
+    try:
+        count, attrs, tree = run("few", "fact")
+        assert count == int(np.isin(few["k"], fact["k"]).sum())
+        (nb, npr), = set(traced)
+        if exchange == "shuffle":
+            assert (nb, npr) == (shards * attrs["quota_b"], shards * attrs["quota_p"])
+        else:
+            assert nb == attrs["build_slots"]
+        assert nb >= 16 * npr
+        assert attrs["dir_bits"] == K.directory_bits_note(nb, npr)
+        narrowed, full = (int(n) for n in attrs["dir_bits"].split(" of "))
+        assert 1 <= narrowed == npr.bit_length() - 4 < full == nb.bit_length() - 4
+        assert f"dir_bits={narrowed} of {full}" in tree
+
+        count, attrs, _ = run("fact", "few")  # the fact table probes
+        assert count == int(np.isin(fact["k"], few["k"]).sum())
+        (nb, npr), = set(traced)
+        assert npr >= nb
+        assert attrs["dir_bits"] == f"{nb.bit_length() - 4} of {nb.bit_length() - 4}"
+
+        monkeypatch.setattr(K, "prefer_scatter", lambda: True)
+        from galaxysql_tpu.exec import operators as ops
+        with ops._JIT_CACHE_LOCK:
+            ops._JIT_CACHE.clear()  # keyed alike under both formulations
+        count, attrs, _ = run("few", "fact")
+        assert count == int(np.isin(few["k"], fact["k"]).sum())
+        assert "dir_bits" not in attrs and not traced
+    finally:
+        s.close()
 
 
 def _located(text):
@@ -521,3 +713,34 @@ def test_expand_scope_gathers_no_64_bit_lane_and_nothing_runs_a_window():
                 and not re.search(r"\}\) : \(tensor<\d+xi32>", line)]
     windows = [where for op, _, where in ops_ if op == "stablehlo.reduce_window"]
     assert len(windows) == 2 and all("reduce_window_sum" in w for w in windows), windows
+
+
+def test_probe_scope_builds_the_narrow_directory_where_the_probe_is_small():
+    """Lowered for a TPU here, no chip: 1,048,576 build slots probed by 4,096.
+    The build side alone would take 17 bits, a directory of 131,073 full-depth
+    searches; the probe side's 9 make it 513, and nothing of 2^16 + 1 or
+    2^17 + 1 elements is left in `join_pairs/probe`.  With the sides swapped
+    the directory is the build side's own, as it was."""
+    def lowered(nb, npr):
+        def run(bk, pk, blive, plive):
+            return K._hash_join_pairs_sorted([(bk, None)], [(pk, None)], blive,
+                                             plive, 8192)
+        shapes = [jax.ShapeDtypeStruct((n,), t) for n, t in
+                  ((nb, jnp.int64), (npr, jnp.int64), (nb, jnp.bool_), (npr, jnp.bool_))]
+        text = jax.jit(run).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+        return [(op, line) for op, line, where in _located(text)
+                if "join_pairs/probe" in where]
+
+    def lanes_of(in_probe, elements):
+        return [line for _, line in in_probe if f"tensor<{elements}x" in line]
+
+    in_probe = lowered(1 << 20, 1 << 12)
+    assert {"stablehlo.while", "stablehlo.gather"} <= {op for op, _ in in_probe}
+    assert K.directory_bits(1 << 20, 1 << 12) == 9
+    assert lanes_of(in_probe, (1 << 9) + 1)
+    assert not lanes_of(in_probe, (1 << 17) + 1) and not lanes_of(in_probe, (1 << 16) + 1)
+
+    swapped = lowered(1 << 12, 1 << 20)
+    assert K.directory_bits(1 << 12, 1 << 20) == 9
+    assert lanes_of(swapped, (1 << 9) + 1)
