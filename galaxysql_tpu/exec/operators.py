@@ -88,7 +88,36 @@ COMPILE_MS_BY_PROGRAM: Dict[str, List[float]] = {}
 # whole probe lane runs for a pair slot.  The slot-table (CPU) formulation
 # loops over nothing and adds nothing.
 JOIN_STATS = {"probes": 0, "search_levels": 0, "full_depth_levels": 0,
-              "expand_levels": 0, "expand_full_depth_levels": 0}
+              "expand_levels": 0, "expand_full_depth_levels": 0,
+              # equi-joins that went through `HashJoinOp._device_probe`, by the
+              # plan's kind, once a join whatever its batches and its ladder
+              # took; and the runs of a pair program that overflowed their
+              # capacity and ran again (benchmarks/harness/local_joins.py)
+              "inner": 0, "left": 0, "semi": 0, "anti": 0, "cap_climbs": 0}
+
+# Where a join's capacity ladder settled: the key of its first rung (the
+# join's keys, its sides' slots, the capacity twice its live probe rows gave)
+# -> the capacity that held its pairs.  The next statement with that key
+# starts there, so a join whose pairs outnumber twice its probe rows (a left
+# join from the one side into the many) climbs once a process and not once a
+# statement, as `parallel/mpp.py:_SETTLED` does for the mesh.  Only a ladder
+# that climbed is kept.
+_SETTLED_CAPS: Dict[tuple, int] = {}
+_SETTLED_CAPS_LIMIT = 4096
+
+# The same for an aggregate's group capacity: the key of its first rung (its
+# keys and calls, the capacity the planner's estimate gave) -> the capacity
+# that held its groups.  A climb re-iterates the CHILD (Q13's 100,000
+# customers with an order against an estimate of 13,000: the left join below
+# ran three times a statement), so it happens once a process and not once a
+# statement.
+_SETTLED_GROUPS: Dict[tuple, int] = {}
+
+
+def _settle(memo: Dict[tuple, int], first_key: tuple, rung: int):
+    if len(memo) >= _SETTLED_CAPS_LIMIT:
+        memo.clear()
+    memo[first_key] = rung
 
 
 def reset_dispatch_stats():
@@ -898,7 +927,9 @@ class HashAggOp(Operator):
     def batches(self) -> Iterator[ColumnBatch]:
         inputs, lanes = self._partial_specs()
         lane_names = tuple(name for name, _ in lanes)
-        mg = self.max_groups
+        first = (exec_platform(), self._cache_key(), self.max_groups,
+                 self.prelude.key() if self.prelude is not None else None)
+        mg = _SETTLED_GROUPS.get(first, self.max_groups)
         from galaxysql_tpu.exec.memory import PoolCharge
         from galaxysql_tpu.exec.spill import Spiller
         # capacity under-estimates retry the whole aggregation with doubled output
@@ -941,6 +972,8 @@ class HashAggOp(Operator):
                 mg *= 2
                 if mg > self.MAX_GROUPS_CEILING:
                     raise RuntimeError("group cardinality exceeds engine ceiling")
+            if mg != self.max_groups:
+                _settle(_SETTLED_GROUPS, first, mg)
 
             # hierarchical merge: consume spilled partials in threshold-bounded waves
             # so peak host memory stays ~spill_threshold + merged-state size
@@ -1152,8 +1185,12 @@ class HashJoinOp(Operator):
                  enable_bloom: bool = True, probe_prelude=None,
                  rf_publish=None, rf_manager=None,
                  frag_cache=None, frag_key=None, frag_note=None,
-                 skew_watch=None, mem_pool=None):
+                 skew_watch=None, mem_pool=None, output=None):
         assert join_type in ("inner", "left", "semi", "anti")
+        # the columns the plan's parent reads of this join's output (None:
+        # all of them); the fused tail of a join that is not a plain inner
+        # one gathers no other lane at the pair slots
+        self.output = output
         # filter-only fused segment (exec/fusion.FusedSegment) ANDed into the
         # probe live mask INSIDE the probe kernels: the WHERE above the probe
         # scan costs no separate program dispatch per batch.  Inner joins only:
@@ -1762,6 +1799,24 @@ class HashJoinOp(Operator):
             mask = live if c.valid is None else (live & c.np_valid())
             _stats.observe_build_keys(tm, colname, c.np_data()[mask])
 
+    def _materialize_build(self, parts: List[ColumnBatch]) -> ColumnBatch:
+        """The build side in one batch.  Read to the host and compacted there
+        (`concat_batches`), unless it is one batch whose lanes are on the
+        device already, too large for the host to build even a bloom filter
+        from (`BLOOM_MAX_BUILD` slots), in a bucket's worth of slots more than
+        half of them live: compaction would buy it little, and its lanes (35 MB
+        of `orders`, 100 MB of late `lineitem` rows at SF1) would cross the
+        host link twice a statement for it.  Such a batch stays where it is."""
+        if not K.prefer_scatter() and len(parts) == 1:
+            b = parts[0]
+            on_device = isinstance(b.live, (jax.Array, type(None))) and all(
+                isinstance(c.data, jax.Array) for c in b.columns.values())
+            if on_device and b.capacity > self.BLOOM_MAX_BUILD and \
+                    b.capacity == bucket_capacity(b.capacity) and \
+                    2 * b.num_live() > b.capacity:
+                return b
+        return concat_batches(parts)
+
     def _empty_build_batches(self) -> Iterator[ColumnBatch]:
         # empty build: inner/semi yield nothing; anti passes probe rows through;
         # left null-extends using the declared build schema
@@ -1819,7 +1874,7 @@ class HashJoinOp(Operator):
                     charge.to(0)
                     yield from self._grace_batches(build_parts, build_iter)
                     return
-            build_batch = concat_batches(build_parts)
+            build_batch = self._materialize_build(build_parts)
             # planned runtime filters publish HERE — before any probe pull, so
             # probe-side scans (lazy generators) see the filter on first batch.
             # An empty build publishes pass-NOTHING filters, never pass-all.
@@ -1847,56 +1902,198 @@ class HashJoinOp(Operator):
             if K.prefer_scatter() and _native.AVAILABLE:
                 yield from self._native_batches(build_batch, art)
                 return
-            build_batch = build_batch.pad_to(
-                bucket_capacity(build_batch.capacity))
+            if _is_host_batch(build_batch):
+                build_batch = build_batch.pad_to(
+                    bucket_capacity(build_batch.capacity))
             if art is not None:
                 art.batch = build_batch  # cache the padded device form
             yield from self._device_probe(build_batch, art, stored=False)
         finally:
             charge.close()
 
-    def _note_search_depth(self, pairs: K.JoinPairs, nb: int, npr: int):
+    def _note_probe(self, nb: int, npr: int, levels, expand=None):
         """Count one probe's depths (the range search's levels of a search of
-        `nb` build slots, the expansion's passes of a search of `npr` probe
-        slots) and, in a traced statement, write "levels of full depth" so far
-        onto the operator's span (the cursor is this join's `op:Join` while its
-        batches are pulled), and beside them the width of the prefix directory
-        this probe's program was built with (`K.directory_bits`, from the two
-        shapes: nothing is read from the device for it)."""
-        levels, expand = (int(n) for n in jax.device_get(
-            (pairs.search_levels, pairs.expand_levels)))  # one read, not two
-        full, expand_full = K.full_search_depth(nb), K.full_search_depth(npr)
+        `nb` build slots and, where pairs were enumerated, the expansion's
+        passes of a search of `npr` probe slots) and, in a traced statement,
+        write "levels of full depth" so far onto the operator's span (the
+        cursor is this join's `op:Join` while its batches are pulled), and
+        beside them the width of the prefix directory this probe's program was
+        built with (`K.directory_bits`, from the two shapes: nothing is read
+        from the device for it)."""
+        full = K.full_search_depth(nb)
         JOIN_STATS["probes"] += 1
         JOIN_STATS["search_levels"] += levels
         JOIN_STATS["full_depth_levels"] += full
-        JOIN_STATS["expand_levels"] += expand
-        JOIN_STATS["expand_full_depth_levels"] += expand_full
         self.search_levels += levels
         self.full_depth_levels += full
-        self.expand_levels += expand
-        self.expand_full_depth_levels += expand_full
-        from galaxysql_tpu.utils import tracing as _tr
-        tc = _tr.current()
-        sp = tc.span_at_cursor() if tc is not None else None
+        if expand is not None:
+            expand_full = K.full_search_depth(npr)
+            JOIN_STATS["expand_levels"] += expand
+            JOIN_STATS["expand_full_depth_levels"] += expand_full
+            self.expand_levels += expand
+            self.expand_full_depth_levels += expand_full
+        sp = self._span()
         if sp is not None:
             sp.attrs["search_levels"] = \
                 f"{self.search_levels} of {self.full_depth_levels}"
-            sp.attrs["expand_levels"] = \
-                f"{self.expand_levels} of {self.expand_full_depth_levels}"
+            if expand is not None:
+                sp.attrs["expand_levels"] = \
+                    f"{self.expand_levels} of {self.expand_full_depth_levels}"
             sp.attrs["dir_bits"] = K.directory_bits_note(nb, npr)
+
+    @staticmethod
+    def _span():
+        """This join's `op:Join` span while its batches are pulled, if the
+        statement is traced."""
+        from galaxysql_tpu.utils import tracing as _tr
+        tc = _tr.current()
+        return tc.span_at_cursor() if tc is not None else None
+
+    def _matched_fn(self, csr: bool, probe_slots: Optional[int]):
+        """The semi or anti join without a residual: the probe batch's live
+        mask after the join, the rows it keeps and the range search's levels,
+        in one program with no pair slot (`K.hash_join_matched`).  With
+        `probe_slots`, the probe keys are searched in that many slots, the
+        live rows moved to the front, and not in the batch's own."""
+        keep_matched = self.join_type == "semi"
+        key = ("join_pairs", exec_platform(), "matched", keep_matched, csr,
+               tuple(expr_cache_key(e) for e in self.build_keys),
+               tuple(expr_cache_key(e) for e in self.probe_keys), probe_slots)
+
+        def build_fn():
+            bk, pk = self._key_compilers()
+
+            def run(build: ColumnBatch, probe: ColumnBatch, csr_lanes):
+                benv, penv = batch_env(build), batch_env(probe)
+                bkeys = [f(benv) for f in bk]
+                pkeys = [f(penv) for f in pk]
+                plive = probe.live_mask()
+                if csr_lanes is None:
+                    matched, levels = K.hash_join_matched(
+                        bkeys, pkeys, build.live_mask(), plive, probe_slots)
+                else:
+                    perm, starts, counts = csr_lanes
+                    matched, levels = K.hash_join_matched_csr(
+                        bkeys, pkeys, build.live_mask(), plive, perm, starts,
+                        counts, starts.shape[0]), None
+                live = plive & (matched if keep_matched else ~matched)
+                return live, jnp.sum(live, dtype=jnp.int32), levels
+            return jit_program(run)
+        return global_jit(key, build_fn)
+
+    def _tail_fn(self, build_batch: ColumnBatch, pb: ColumnBatch, cap: int):
+        """What follows the pair enumeration of a join that is not a plain
+        inner one, in one program of the gather's family: both sides' lanes at
+        the pair slots, the residual on them, the probe rows with a pair that
+        passed it, and from those the kind's output: the pairs (inner, left),
+        the unmatched probe rows over ONE all-NULL lane a build column's type
+        (left), or the probe batch's live mask (semi, anti; the lanes the
+        residual does not read are gathered by nobody).  Returns `(pairs
+        batch or None, probe-side live mask or None, rows under that mask or
+        None, {dtype: NULL lane})`."""
+        kind, residual = self.join_type, self.residual
+        lanes_sig = tuple(
+            tuple((n, str(c.data.dtype), c.valid is not None)
+                  for n, c in b.columns.items()) for b in (build_batch, pb))
+        # what the parent reads (all of it where the plan does not say, or
+        # reads no column at all: a batch has its slots from its lanes)
+        names = set(build_batch.columns) | set(pb.columns)
+        wanted = names & self.output if self.output else names
+        wanted = frozenset(wanted or names)
+        key = ("join_gather", exec_platform(), kind,
+               expr_cache_key(residual) if residual is not None else None,
+               build_batch.capacity, pb.capacity, cap, lanes_sig,
+               tuple(sorted(wanted)))
+
+        def build_fn():
+            residual_pred = (ExprCompiler(jnp).compile_predicate(residual)
+                             if residual is not None else None)
+
+            def at(batch: ColumnBatch, idx):
+                return {n: Column(c.data[idx],
+                                  None if c.valid is None else c.valid[idx],
+                                  c.dtype, c.dictionary)
+                        for n, c in batch.columns.items()}
+
+            def run(build: ColumnBatch, probe: ColumnBatch, pairs: K.JoinPairs):
+                cols = {**at(build, pairs.build_idx),
+                        **at(probe, pairs.probe_idx)}
+                live, matched = pairs.live, pairs.probe_matched
+                if residual_pred is not None:
+                    live = live & residual_pred(
+                        batch_env(ColumnBatch(cols, pairs.live)))
+                    # a probe row is matched by a pair that ALSO passed
+                    matched = K.probe_matched_from(live, pairs.probe_starts,
+                                                   pairs.probe_offsets)
+                # a lane only the residual read is gathered for it and for
+                # nobody else: what is not returned the compiler drops
+                out = ColumnBatch({n: c for n, c in cols.items()
+                                   if n in wanted}, live)
+                if kind == "inner":
+                    return out, None, None, {}
+                plive = probe.live_mask()
+                if kind == "left":
+                    unmatched = plive & ~matched
+                    nulls = {str(c.data.dtype): jnp.zeros(probe.capacity,
+                                                          c.data.dtype)
+                             for n, c in build.columns.items() if n in wanted}
+                    nulls["valid"] = jnp.zeros(probe.capacity, jnp.bool_)
+                    return (out, unmatched,
+                            jnp.sum(unmatched, dtype=jnp.int32), nulls)
+                kept = plive & (matched if kind == "semi" else ~matched)
+                return None, kept, jnp.sum(kept, dtype=jnp.int32), {}
+            return jit_program(run)
+        return global_jit(key, build_fn)
+
+    def _pairs(self, build_batch: ColumnBatch, pb: ColumnBatch, csr, plits):
+        """The pair enumeration at a capacity that holds it, and that capacity
+        and the runs that overflowed on the way: `(pairs, cap, climbs)`.  The
+        first rung is twice the live probe rows, or where this join's ladder
+        settled before (`_SETTLED_CAPS`); an overflowed run tells how many
+        candidate pairs there are, so the next rung is the bucket that holds
+        them and no rung between is built.  No answer is made from an
+        overflowed run."""
+        # with a probe prelude the count predates the fused WHERE (counting
+        # the post-filter mask would cost the dispatch the fusion saves):
+        # cap is conservative, overflow-retry semantics unchanged
+        first_cap = bucket_capacity(max(pb.num_live() * 2, MIN_BUCKET))
+        prelude = self.probe_prelude
+        first = (exec_platform(), first_cap, build_batch.capacity, pb.capacity,
+                 tuple(expr_cache_key(e) for e in self.build_keys),
+                 tuple(expr_cache_key(e) for e in self.probe_keys),
+                 prelude.key() if prelude is not None else None)
+        cap, climbs = _SETTLED_CAPS.get(first, first_cap), 0
+        while True:
+            if csr is not None:
+                perm, starts, counts, M = csr
+                pairs = self._probe_csr_fn(cap, M, build_batch.capacity)(
+                    build_batch, pb, perm, starts, counts, plits)
+            else:
+                pairs = self._pairs_fn(cap)(build_batch, pb, plits)
+            over, levels, expand = jax.device_get(  # one read, not three
+                (pairs.overflow, pairs.search_levels, pairs.expand_levels))
+            if not bool(over):
+                break
+            climbs += 1
+            cap = bucket_capacity(int(np.asarray(pairs.probe_offsets)[-1]))
+        JOIN_STATS["cap_climbs"] += climbs
+        if climbs:
+            _settle(_SETTLED_CAPS, first, cap)
+        if levels is not None:
+            self._note_probe(build_batch.capacity, pb.capacity, int(levels),
+                             int(expand))
+        return pairs, cap, climbs
 
     def _device_probe(self, build_batch: ColumnBatch, art,
                       stored: bool) -> Iterator[ColumnBatch]:
-        residual_pred = (ExprCompiler(jnp).compile_predicate(self.residual)
-                         if self.residual is not None else None)
-
+        kind = self.join_type
         # runtime bloom filter (reference: RuntimeFilterBuilderExec -> scan pushdown,
         # SURVEY.md §2.7): for inner/semi joins with one key, probe rows that cannot
         # match are masked out before pair enumeration.  Bloom-negative rows are
         # provably unmatched, so semantics are exact for inner/semi; left/anti must
         # keep unmatched rows and skip the filter.
         bloom_filter = None
-        if self.enable_bloom and self.join_type in ("inner", "semi") and \
+        if self.enable_bloom and kind in ("inner", "semi") and \
                 len(self.build_keys) == 1:
             _, pk = self._key_compilers()
             bloom_filter = self._build_bloom(build_batch, pk[0])
@@ -1909,6 +2106,17 @@ class HashJoinOp(Operator):
             art.csr = csr
             self._frag_store(art)
         plits = self._plits()
+        JOIN_STATS[kind] += 1
+        sp = self._span()
+        if sp is not None:
+            sp.attrs.update(kind=kind, residual=int(self.residual is not None),
+                            cap=0, climbs=0)
+        # a semi or anti join without a residual asks only WHETHER a probe row
+        # has a match: no pair is enumerated, so there is no capacity to climb
+        matched_only = self.residual is None and kind in ("semi", "anti")
+        plain_inner = self.residual is None and kind == "inner"
+        cap_max = climbs = 0
+        counted = []  # device scalars read once, after the last batch is taken
         for pb in self.probe.batches():
             if RF_STATS["enabled"]:
                 # probe rows REACHING the join (post scan-side runtime-filter
@@ -1917,53 +2125,55 @@ class HashJoinOp(Operator):
                 RF_STATS["probe_rows"] += int(pb.num_live())
             if bloom_filter is not None:
                 pb = bloom_filter(pb)
-            # with a probe prelude the count predates the fused WHERE (counting
-            # the post-filter mask would cost the dispatch the fusion saves):
-            # cap is conservative, overflow-retry semantics unchanged
-            n_live = pb.num_live()
-            cap = bucket_capacity(max(n_live * 2, MIN_BUCKET))
-            while True:
-                if csr is not None:
-                    perm, starts, counts, M = csr
-                    pairs = self._probe_csr_fn(cap, M, build_batch.capacity)(
-                        build_batch, pb, perm, starts, counts, plits)
-                else:
-                    pairs = self._pairs_fn(cap)(build_batch, pb, plits)
-                if not bool(pairs.overflow):
-                    break
-                cap *= 2
-            if pairs.search_levels is not None:
-                self._note_search_depth(pairs, build_batch.capacity, pb.capacity)
-            if residual_pred is None and self.join_type in ("semi", "anti"):
-                matched = pairs.probe_matched
-                live = pb.live_mask() & (matched if self.join_type == "semi" else ~matched)
+            if matched_only:
+                # a probe side that a filter left mostly dead (Q4: 57K orders
+                # of a quarter in 1,572,864 slots) is searched in the bucket
+                # its live rows fill: the range search pays by the slot
+                slots = bucket_capacity(max(pb.num_live(), MIN_BUCKET))
+                if csr is not None or 2 * slots > pb.capacity:
+                    slots = None
+                live, rows, levels = self._matched_fn(csr is not None, slots)(
+                    build_batch, pb, None if csr is None else csr[:3])
+                counted.append((slots or pb.capacity, rows, levels))
                 yield ColumnBatch(pb.columns, live)
                 continue
-            bcols = self._gather(build_batch, pairs.build_idx, pairs.live)
-            pcols = self._gather(pb, pairs.probe_idx, pairs.live)
-            out = ColumnBatch({**bcols, **pcols}, pairs.live)
-            if residual_pred is not None:
-                mask = residual_pred(batch_env(out))
-                out = ColumnBatch(out.columns, out.live_mask() & mask)
-            if self.join_type in ("left", "semi", "anti"):
-                # matched flags must reflect pairs that ALSO passed the residual
-                matched = K.probe_matched_from(out.live_mask(), pairs.probe_starts,
-                                               pairs.probe_offsets)
-            if self.join_type in ("semi", "anti"):
-                live = pb.live_mask() & (matched if self.join_type == "semi" else ~matched)
-                yield ColumnBatch(pb.columns, live)
+            pairs, cap, climbed = self._pairs(build_batch, pb, csr, plits)
+            cap_max, climbs = max(cap_max, cap), climbs + climbed
+            if sp is not None:
+                sp.attrs.update(cap=cap_max, climbs=climbs)
+            if plain_inner:
+                bcols = self._gather(build_batch, pairs.build_idx, pairs.live)
+                pcols = self._gather(pb, pairs.probe_idx, pairs.live)
+                yield ColumnBatch({**bcols, **pcols}, pairs.live)
+                continue
+            out, side_live, rows, nulls = self._tail_fn(build_batch, pb, cap)(
+                build_batch, pb, pairs)
+            if rows is not None:
+                counted.append((pb.capacity, rows, None))
+            if kind in ("semi", "anti"):
+                yield ColumnBatch(pb.columns, side_live)
                 continue
             yield out
-            if self.join_type == "left":
-                # null-extended unmatched probe rows
-                unmatched = pb.live_mask() & ~matched
-                ncols = {}
-                for name, c in build_batch.columns.items():
-                    z = jnp.zeros(pb.capacity, dtype=c.data.dtype)
-                    ncols[name] = Column(z, jnp.zeros(pb.capacity, jnp.bool_),
-                                         c.dtype, c.dictionary)
-                ncols.update(pb.columns)
-                yield ColumnBatch(ncols, unmatched)
+            if kind == "left":
+                # null-extended unmatched probe rows: every build column of a
+                # lane type shares that type's one all-NULL lane
+                ncols = {name: Column(nulls[str(c.data.dtype)], nulls["valid"],
+                                      c.dtype, c.dictionary)
+                         for name, c in build_batch.columns.items()
+                         if name in out.columns}
+                ncols.update((name, c) for name, c in pb.columns.items()
+                             if name in out.columns)
+                yield ColumnBatch(ncols, side_live)
+        if counted:
+            # the consumer has taken every batch and dispatched its own work:
+            # these scalars are read behind it and hold nothing up
+            got = jax.device_get([(rows, levels) for _, rows, levels in counted])
+            for (npr, _, _), (rows, levels) in zip(counted, got):
+                if levels is not None:
+                    self._note_probe(build_batch.capacity, npr, int(levels))
+            if sp is not None:
+                sp.attrs["unmatched" if kind == "left" else "matched"] = \
+                    int(sum(rows for rows, _ in got))
 
 
 class CrossJoinOp(Operator):
@@ -1982,6 +2192,27 @@ class CrossJoinOp(Operator):
         # scalar subquery semantics: empty build NULL-extends, >1 rows errors
         self.scalar = scalar
         self.build_schema = build_schema
+
+    @staticmethod
+    def _one_row_lanes(build: ColumnBatch, capacity: int) -> Dict[str, Column]:
+        """A one-row build side (a scalar subquery's answer) as lanes of
+        `capacity` slots, in one named program of the join's gather family:
+        eager `broadcast_to` is two unnamed modules a column."""
+        lanes = {n: (c.data, c.valid) for n, c in build.columns.items()}
+        key = ("join_gather", exec_platform(), "one_row", capacity,
+               tuple((n, str(d.dtype), v is not None)
+                     for n, (d, v) in lanes.items()))
+
+        def build_fn():
+            def run(lanes):
+                return {n: (jnp.broadcast_to(d[0], (capacity,)),
+                            None if v is None
+                            else jnp.broadcast_to(v[0], (capacity,)))
+                        for n, (d, v) in lanes.items()}
+            return jit_program(run)
+        out = global_jit(key, build_fn)(lanes)
+        return {n: Column(*out[n], c.dtype, c.dictionary)
+                for n, c in build.columns.items()}
 
     def batches(self) -> Iterator[ColumnBatch]:
         build = concat_batches(list(self.build.batches()))
@@ -2005,12 +2236,7 @@ class CrossJoinOp(Operator):
             if nb == 0:
                 return  # empty build: cross join is empty
             if nb == 1:
-                cols = {}
-                for name, c in build.columns.items():
-                    data = jnp.broadcast_to(c.data[0], (pb.capacity,))
-                    valid = (jnp.broadcast_to(c.valid[0], (pb.capacity,))
-                             if c.valid is not None else None)
-                    cols[name] = Column(data, valid, c.dtype, c.dictionary)
+                cols = self._one_row_lanes(build, pb.capacity)
                 cols.update(pb.columns)
                 yield ColumnBatch(cols, pb.live)
                 continue

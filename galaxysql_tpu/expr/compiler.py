@@ -730,17 +730,19 @@ class ExprCompiler:
         rx = re.compile(like_to_regex(str(pat.value)), re.DOTALL)
         codes = d.codes_matching(lambda s: rx.fullmatch(s) is not None)
         f = self._compile(col)
-        table = np.sort(codes)
+        # membership by code: one flag a dictionary entry and one gathered
+        # word a row (a search of the matching codes is seven or eight a row
+        # for a few dozen of them: 87 ms over `orders`' 1.5M comments where
+        # this is a tenth of that; PERF.md, PR 33).  A program is keyed by
+        # its dictionaries' lengths (`expr_cache_key`), so a code past this
+        # table belongs to a later program.
+        member = np.zeros(max(len(d), 1), dtype=np.bool_)
+        member[codes] = True
         neg = e.op == "not_like"
 
         def run(env: Env) -> Value:
             data, valid = f(env)
-            if table.size == 0:
-                hit = xp.zeros(data.shape, dtype=xp.bool_)
-            else:
-                t = xp.asarray(table)
-                pos = xp.clip(xp.searchsorted(t, data), 0, t.shape[0] - 1)
-                hit = t[pos] == data
+            hit = xp.asarray(member)[xp.clip(data, 0, member.shape[0] - 1)]
             return (~hit if neg else hit), valid
         return run
 

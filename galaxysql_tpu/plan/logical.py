@@ -198,6 +198,9 @@ class Join(RelNode):
         self.residual = residual
         # scalar cross join (uncorrelated scalar subquery): exactly-one-row build
         self.scalar = False
+        # the output fields the parent reads, once `prune_columns` has run
+        # (None before: all of them)
+        self.required: Optional[set] = None
         # runtime-filter producer edges (exec/runtime_filter.RuntimeFilterPlan):
         # equi pairs whose build side publishes a bloom/min-max filter
         self.rf_plans: List[Any] = []

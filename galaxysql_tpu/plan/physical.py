@@ -1009,7 +1009,8 @@ def _build_join(node: L.Join, ctx: ExecContext) -> ops.Operator:
                               rf_publish=rf_specs, rf_manager=rf_mgr,
                               frag_cache=cache, frag_key=fkey, frag_note=note,
                               skew_watch=_skew_watch(node.right, rkeys, ctx),
-                              mem_pool=ctx.mem_pool)
+                              mem_pool=ctx.mem_pool,
+                              output=node.required)
     # inner: build the smaller estimated side
     l_est = estimate_rows(node.left)
     r_est = estimate_rows(node.right)
@@ -1035,4 +1036,5 @@ def _build_join(node: L.Join, ctx: ExecContext) -> ops.Operator:
                           rf_publish=rf_specs, rf_manager=rf_mgr,
                           frag_cache=cache, frag_key=fkey, frag_note=note,
                           skew_watch=_skew_watch(build_node, build_keys, ctx),
-                          mem_pool=ctx.mem_pool)
+                          mem_pool=ctx.mem_pool,
+                          output=node.required)

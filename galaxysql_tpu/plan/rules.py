@@ -488,8 +488,30 @@ def _rewrite_children(node: L.RelNode, fn) -> L.RelNode:
 # filter pushdown (through Project / into Join sides)
 # ---------------------------------------------------------------------------
 
+def _push_left_join_on(node: L.Join) -> L.Join:
+    """A conjunct of a LEFT join's ON clause that reads the null-supplying
+    side only is a filter of that side: a right row that fails it (or for
+    which it is NULL) pairs with no left row, and a left row left without a
+    pair is NULL-extended either way.  It is applied to the right side before
+    the join, once a right row and not once a pair; conjuncts that read both
+    sides, or the preserved side, stay the join's residual."""
+    right_ids = set(node.right.field_ids())
+    keep: List[ir.Expr] = []
+    rpush: List[ir.Expr] = []
+    for c in conjuncts(node.residual):
+        refs = set(ir.referenced_columns(c))
+        (rpush if refs and refs <= right_ids else keep).append(c)
+    if rpush:
+        node.children[1] = push_filters(L.Filter(node.right, ir.and_(*rpush)))
+        node.residual = ir.and_(*keep) if keep else None
+    return node
+
+
 def push_filters(node: L.RelNode) -> L.RelNode:
     node = _rewrite_children(node, push_filters)
+    if isinstance(node, L.Join) and node.kind == "left" and \
+            node.residual is not None:
+        return _push_left_join_on(node)
     if not isinstance(node, L.Filter):
         return node
     child = node.child
@@ -556,6 +578,9 @@ def prune_columns(node: L.RelNode, required: Optional[Set[str]] = None) -> L.Rel
         node.children = [prune_columns(node.child, need)]
         return node
     if isinstance(node, L.Join):
+        # what the parent reads of this join's output: the executor's fused
+        # tail gathers no other lane at the pair slots (exec/operators.py)
+        node.required = set(required)
         need = set(required)
         for a, b in node.equi:
             need.update(ir.referenced_columns(a))

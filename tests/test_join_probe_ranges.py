@@ -485,6 +485,13 @@ def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, request)
     assert 2 <= levels <= 2 * 8 < 2 * full
     assert span.attrs["search_levels"] == f"{levels} of {2 * full}"
     assert f"search_levels={levels} of {2 * full}" in "\n".join(tc.tree_lines())
+    if join_type in ("semi", "anti"):
+        # no residual: which probe rows match is asked of the ranges
+        # themselves, no pair is enumerated and nothing is expanded
+        assert ops.JOIN_STATS["expand_levels"] == before["expand_levels"]
+        assert "expand_levels" not in span.attrs
+        assert span.attrs["dir_bits"] == "6 of 8"
+        return
     # the expansion: one pass a probe carries a row's id to its second pair,
     # where a search over the 700 probe slots would run ten levels
     expand_full = K.full_search_depth(700)

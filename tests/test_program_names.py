@@ -3,7 +3,9 @@ benchmark's table of families (`benchmarks/harness/spans.py`) knows every family
 the program's source can build."""
 
 import ast
+import logging
 import os
+import re
 
 import jax
 import pytest
@@ -108,6 +110,71 @@ def test_no_cached_program_keeps_its_closures_name(modules_by_family):
         assert not CLOSURE_NAMES & set(names), (family, names)
         assert all(family_of(n) == family for n in names), (family, names)
         assert family in FAMILY_GROUP, family
+
+
+@pytest.fixture(scope="module")
+def tpch_session():
+    data = tpch.generate(0.01, seed=33)
+    inst = Instance()
+    s = Session(inst)
+    s.execute("CREATE DATABASE tpch")
+    s.execute("USE tpch")
+    for t in tpch.TABLE_ORDER:
+        s.execute(tpch.TPCH_DDL[t])
+        inst.store("tpch", t).insert_arrays(data[t], inst.tso.next_timestamp())
+    s.execute("ANALYZE TABLE " + ", ".join(tpch.TABLE_ORDER))
+    yield s
+    s.close()
+
+
+class CompiledModules(logging.Handler):
+    """The name of every module JAX compiles while it is attached: with
+    `jax.clear_caches()` before it, every module a statement dispatches, the
+    one-primitive modules of eager `jnp` calls among them."""
+
+    LOGGER = "jax._src.interpreters.pxla"
+
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        self.names = []
+
+    def emit(self, record):
+        m = re.match(r"Compiling (\S+) with global shapes", record.getMessage())
+        if m:
+            self.names.append(m.group(1))
+
+    def __enter__(self):
+        self.logger = logging.getLogger(self.LOGGER)
+        self.level = self.logger.level
+        self.logger.setLevel(logging.DEBUG)
+        self.logger.addHandler(self)
+        return self
+
+    def __exit__(self, *exc):
+        self.logger.removeHandler(self)
+        self.logger.setLevel(self.level)
+
+
+@pytest.mark.parametrize("q", [13, 22, 4])
+def test_every_module_a_non_inner_join_dispatches_is_named_after_a_family(
+        tpch_session, chip_formulation, monkeypatch, q):
+    """Q13's left join with its residual and its NULL-extended rows, Q22's
+    scalar cross join and anti join, Q4's semi join, as the chip runs them (AP
+    plans over device lanes, build sides that stay on the device): a first
+    execution that climbs and a settled one dispatch `jit_<family>` modules
+    only, no eager `jnp` call among them."""
+    from galaxysql_tpu.plan import planner
+    monkeypatch.setattr(planner, "AP_ROW_THRESHOLD", 1)
+    monkeypatch.setattr(ops.HashJoinOp, "BLOOM_MAX_BUILD", 1024)
+    ops._SETTLED_CAPS.clear()
+    jax.clear_caches()
+    with CompiledModules() as seen:
+        for _ in range(2):
+            tpch_session.execute(HINT + QUERIES[q])
+    ops._SETTLED_CAPS.clear()
+    families = {family_of(n.replace("(", "_").rstrip(")")) for n in seen.names}
+    assert {"join_pairs", "agg_partial"} <= families, seen.names
+    assert families <= set(FAMILY_GROUP), seen.names
 
 
 def test_jit_program_refuses_a_call_outside_a_builder():
