@@ -80,15 +80,13 @@ COMPILE_STATS = {"retraces": 0, "compile_ms": 0.0, "cache_hits": 0}
 COMPILE_MS_BY_PROGRAM: Dict[str, List[float]] = {}
 
 
-# the sorted (TPU) join's two data-dependent loops, one add per probe batch:
-# levels the range search ran against the levels a search of the whole build lane
-# runs (kernels/relational._probe_ranges; equal sums mean every build side was
-# one hot key), and passes the expansion's running maximum took (`_expand_rows`:
-# none where no probe row has two pairs) against the levels a search of the
-# whole probe lane runs for a pair slot.  The slot-table (CPU) formulation
-# loops over nothing and adds nothing.
-JOIN_STATS = {"probes": 0, "search_levels": 0, "full_depth_levels": 0,
-              "expand_levels": 0, "expand_full_depth_levels": 0,
+# the sorted (TPU) join, one add per probe batch: `probes`, and where pairs
+# were enumerated the passes the expansion's running maximum took
+# (`kernels/relational._expand_rows`: none where no probe row has two pairs)
+# against the levels a search of the whole probe lane runs for a pair slot.
+# (The range lookup sorts and searches nothing: it has no depth to count.)
+# The slot-table (CPU) formulation loops over nothing and adds nothing.
+JOIN_STATS = {"probes": 0, "expand_levels": 0, "expand_full_depth_levels": 0,
               # equi-joins that went through `HashJoinOp._device_probe`, by the
               # plan's kind, once a join whatever its batches and its ladder
               # took; and the runs of a pair program that overflowed their
@@ -1208,8 +1206,7 @@ class HashJoinOp(Operator):
         # hash to disk and joins bucket pairs (HybridHashJoinExec analog)
         self.spill_threshold = spill_threshold
         self.grace_partitions = 0  # observable spill counter (tests)
-        # depth of the sorted probes' two searches so far, against full depth
-        self.search_levels = self.full_depth_levels = 0
+        # passes of the sorted probes' expansions so far, against full depth
         self.expand_levels = self.expand_full_depth_levels = 0
         # per-query memory pool: accumulated build bytes charge it;
         # exhaustion or a squeeze revoke engages the grace path early
@@ -1911,35 +1908,22 @@ class HashJoinOp(Operator):
         finally:
             charge.close()
 
-    def _note_probe(self, nb: int, npr: int, levels, expand=None):
-        """Count one probe's depths (the range search's levels of a search of
-        `nb` build slots and, where pairs were enumerated, the expansion's
-        passes of a search of `npr` probe slots) and, in a traced statement,
-        write "levels of full depth" so far onto the operator's span (the
-        cursor is this join's `op:Join` while its batches are pulled), and
-        beside them the width of the prefix directory this probe's program was
-        built with (`K.directory_bits`, from the two shapes: nothing is read
-        from the device for it)."""
-        full = K.full_search_depth(nb)
+    def _note_probe(self, npr: int, expand: int):
+        """Count one probe of the sorted formulation that enumerated pairs,
+        and its expansion's passes of the depth a search of `npr` probe slots
+        runs; in a traced statement, write "passes of full depth" so far onto
+        the operator's span (the cursor is this join's `op:Join` while its
+        batches are pulled)."""
         JOIN_STATS["probes"] += 1
-        JOIN_STATS["search_levels"] += levels
-        JOIN_STATS["full_depth_levels"] += full
-        self.search_levels += levels
-        self.full_depth_levels += full
-        if expand is not None:
-            expand_full = K.full_search_depth(npr)
-            JOIN_STATS["expand_levels"] += expand
-            JOIN_STATS["expand_full_depth_levels"] += expand_full
-            self.expand_levels += expand
-            self.expand_full_depth_levels += expand_full
+        expand_full = K.full_search_depth(npr)
+        JOIN_STATS["expand_levels"] += expand
+        JOIN_STATS["expand_full_depth_levels"] += expand_full
+        self.expand_levels += expand
+        self.expand_full_depth_levels += expand_full
         sp = self._span()
         if sp is not None:
-            sp.attrs["search_levels"] = \
-                f"{self.search_levels} of {self.full_depth_levels}"
-            if expand is not None:
-                sp.attrs["expand_levels"] = \
-                    f"{self.expand_levels} of {self.expand_full_depth_levels}"
-            sp.attrs["dir_bits"] = K.directory_bits_note(nb, npr)
+            sp.attrs["expand_levels"] = \
+                f"{self.expand_levels} of {self.expand_full_depth_levels}"
 
     @staticmethod
     def _span():
@@ -1951,10 +1935,10 @@ class HashJoinOp(Operator):
 
     def _matched_fn(self, csr: bool, probe_slots: Optional[int]):
         """The semi or anti join without a residual: the probe batch's live
-        mask after the join, the rows it keeps and the range search's levels,
-        in one program with no pair slot (`K.hash_join_matched`).  With
-        `probe_slots`, the probe keys are searched in that many slots, the
-        live rows moved to the front, and not in the batch's own."""
+        mask after the join and the rows it keeps, in one program with no
+        pair slot (`K.hash_join_matched`).  With `probe_slots`, the probe keys
+        are looked up in that many slots, the live rows moved to the front,
+        and not in the batch's own."""
         keep_matched = self.join_type == "semi"
         key = ("join_pairs", exec_platform(), "matched", keep_matched, csr,
                tuple(expr_cache_key(e) for e in self.build_keys),
@@ -1969,15 +1953,15 @@ class HashJoinOp(Operator):
                 pkeys = [f(penv) for f in pk]
                 plive = probe.live_mask()
                 if csr_lanes is None:
-                    matched, levels = K.hash_join_matched(
+                    matched = K.hash_join_matched(
                         bkeys, pkeys, build.live_mask(), plive, probe_slots)
                 else:
                     perm, starts, counts = csr_lanes
-                    matched, levels = K.hash_join_matched_csr(
+                    matched = K.hash_join_matched_csr(
                         bkeys, pkeys, build.live_mask(), plive, perm, starts,
-                        counts, starts.shape[0]), None
+                        counts, starts.shape[0])
                 live = plive & (matched if keep_matched else ~matched)
-                return live, jnp.sum(live, dtype=jnp.int32), levels
+                return live, jnp.sum(live, dtype=jnp.int32)
             return jit_program(run)
         return global_jit(key, build_fn)
 
@@ -2070,8 +2054,8 @@ class HashJoinOp(Operator):
                     build_batch, pb, perm, starts, counts, plits)
             else:
                 pairs = self._pairs_fn(cap)(build_batch, pb, plits)
-            over, levels, expand = jax.device_get(  # one read, not three
-                (pairs.overflow, pairs.search_levels, pairs.expand_levels))
+            over, expand = jax.device_get(  # one read, not two
+                (pairs.overflow, pairs.expand_levels))
             if not bool(over):
                 break
             climbs += 1
@@ -2079,9 +2063,8 @@ class HashJoinOp(Operator):
         JOIN_STATS["cap_climbs"] += climbs
         if climbs:
             _settle(_SETTLED_CAPS, first, cap)
-        if levels is not None:
-            self._note_probe(build_batch.capacity, pb.capacity, int(levels),
-                             int(expand))
+        if expand is not None:  # the sorted formulation's
+            self._note_probe(pb.capacity, int(expand))
         return pairs, cap, climbs
 
     def _device_probe(self, build_batch: ColumnBatch, art,
@@ -2116,7 +2099,7 @@ class HashJoinOp(Operator):
         matched_only = self.residual is None and kind in ("semi", "anti")
         plain_inner = self.residual is None and kind == "inner"
         cap_max = climbs = 0
-        counted = []  # device scalars read once, after the last batch is taken
+        counted = []  # row counts, read once after the last batch is taken
         for pb in self.probe.batches():
             if RF_STATS["enabled"]:
                 # probe rows REACHING the join (post scan-side runtime-filter
@@ -2127,14 +2110,17 @@ class HashJoinOp(Operator):
                 pb = bloom_filter(pb)
             if matched_only:
                 # a probe side that a filter left mostly dead (Q4: 57K orders
-                # of a quarter in 1,572,864 slots) is searched in the bucket
-                # its live rows fill: the range search pays by the slot
+                # of a quarter in 1,572,864 slots) is looked up in the bucket
+                # its live rows fill: the lookup and the comparison of the
+                # candidates pay by the slot
                 slots = bucket_capacity(max(pb.num_live(), MIN_BUCKET))
                 if csr is not None or 2 * slots > pb.capacity:
                     slots = None
-                live, rows, levels = self._matched_fn(csr is not None, slots)(
+                live, rows = self._matched_fn(csr is not None, slots)(
                     build_batch, pb, None if csr is None else csr[:3])
-                counted.append((slots or pb.capacity, rows, levels))
+                counted.append(rows)
+                if csr is None:
+                    JOIN_STATS["probes"] += 1  # the sorted formulation's
                 yield ColumnBatch(pb.columns, live)
                 continue
             pairs, cap, climbed = self._pairs(build_batch, pb, csr, plits)
@@ -2149,7 +2135,7 @@ class HashJoinOp(Operator):
             out, side_live, rows, nulls = self._tail_fn(build_batch, pb, cap)(
                 build_batch, pb, pairs)
             if rows is not None:
-                counted.append((pb.capacity, rows, None))
+                counted.append(rows)
             if kind in ("semi", "anti"):
                 yield ColumnBatch(pb.columns, side_live)
                 continue
@@ -2164,16 +2150,11 @@ class HashJoinOp(Operator):
                 ncols.update((name, c) for name, c in pb.columns.items()
                              if name in out.columns)
                 yield ColumnBatch(ncols, side_live)
-        if counted:
+        if counted and sp is not None:
             # the consumer has taken every batch and dispatched its own work:
             # these scalars are read behind it and hold nothing up
-            got = jax.device_get([(rows, levels) for _, rows, levels in counted])
-            for (npr, _, _), (rows, levels) in zip(counted, got):
-                if levels is not None:
-                    self._note_probe(build_batch.capacity, npr, int(levels))
-            if sp is not None:
-                sp.attrs["unmatched" if kind == "left" else "matched"] = \
-                    int(sum(rows for rows, _ in got))
+            sp.attrs["unmatched" if kind == "left" else "matched"] = \
+                int(sum(jax.device_get(counted)))
 
 
 class CrossJoinOp(Operator):

@@ -9,16 +9,18 @@ TPU-friendly primitives:
   boundaries are detected by comparing adjacent rows, and aggregates are `jax.ops.segment_*`
   reductions.  The reference's sort-based fallback for huge-NDV aggs (`SpillableAggHashMap`)
   is here the *primary* strategy because sort is what the hardware does well.
-- **hash join = hash + sort + directory probe.**  The build side is sorted by a 64-bit key
-  hash.  A probe reads its bucket's bounds from a prefix directory over the hashes' top bits,
-  finishes with a binary search inside the bucket (3-6 levels on uniform hashes, as many as
-  the widest bucket needs) and takes the range's end from the run lengths of the sorted
-  lane; every candidate pair is then verified against the actual key columns, so hash
-  collisions cost duplicates-filtered work, never correctness.  This is the flat-array
-  open-addressing idea of `ConcurrentRawHashTable` (Appendix A) re-expressed without
-  scatter contention.  (Two `searchsorted` passes over the whole lane, which this replaced,
-  were 6.14 of Q3's 9.30 s on a v5e: a uint64 lane is two uint32 lanes there, and each of a
-  search's 18-22 levels a dependent gather of both over every probe slot; PERF.md, PR 26.)
+- **hash join = hash + sort + merged range lookup.**  The build rows are ordered by a 64-bit
+  key hash, and every probe slot is given its range of candidates in that order by a MERGE:
+  both sides' hashes are sorted as one lane, a running count of the build slots along it
+  gives each probe slot its range, a second sort hands the ranges back in probe-row order,
+  and nothing is gathered (`_merge_ranges`).  Every candidate pair is then verified against
+  the actual key columns, so hash collisions cost duplicates-filtered work, never
+  correctness.  This is the flat-array open-addressing idea of `ConcurrentRawHashTable`
+  (Appendix A) re-expressed without scatter contention.  (Sorts vectorize on the chip and
+  dependent gathers do not: a uint64 lane is two uint32 lanes there, a sort of 6.3M hashes
+  with their ids is 0.025 s and ONE gather pass over as many probe slots 0.05-0.12 s, of
+  which a search of the sorted lane makes seven, behind a prefix directory, to forty.
+  PERF.md, PR 26 and PR 34.)
 
 All kernels are fixed-shape: output capacity is a static argument and kernels report
 `overflow` so the host can re-bucket and retry (the dynamic-shape escape hatch, SURVEY.md
@@ -639,9 +641,6 @@ class JoinPairs(NamedTuple):
     probe_starts: Any   # [n_probe] int64 — first pair slot of each probe row
     probe_offsets: Any  # [n_probe] int64 — end pair slot of each probe row
     overflow: Any       # scalar bool
-    # scalar int32 — levels the range search ran (sorted formulation only;
-    # read against `full_search_depth(nb)`)
-    search_levels: Any = None
     # scalar int32 — passes the expansion's running maximum took (sorted
     # formulation only; `bit_length(most pairs of a probe row - 1)`)
     expand_levels: Any = None
@@ -663,15 +662,11 @@ def hash_join_pairs(build_keys: Sequence[Tuple[Any, Optional[Any]]],
     """Equi-join match enumeration: returns verified (build, probe) index pairs.
 
     NULL join keys never match (SQL semantics): rows with any NULL key are masked out of
-    both sides before hashing.  Backend-adaptive: the TPU formulation sorts the
-    build hashes and finds each probe's range through a prefix directory, a
-    bounded search and run lengths (`_probe_ranges`; sorts vectorize, and a
-    gather pass over 6.3M probe slots costs 54-117 ms on a v5e, so the passes
-    are what it saves: 7-10 where two whole-lane searches made 36-44, at
-    `tpch_sf1.join`'s shape, a probe side larger than its build side; the
-    directory's width is chosen from BOTH static shapes, `directory_bits(nb,
-    npr)`, so a probe side a hundredth of its build side indexes that build
-    side with a few thousand full-depth searches and not half a million), then
+    both sides before hashing.  Backend-adaptive: the TPU formulation orders the
+    build rows by their hash (one `argsort` of `nb` slots), finds each probe
+    slot's range of candidates by sorting both sides' hashes as one lane
+    (`_merge_ranges`: two more sorts, of `nb + npr` slots, and streaming
+    passes between them, no gather), then
     expands the ranges into pair slots with one scatter of row ids and a
     running maximum (`_expand_rows`; two gathered words a pair slot where a
     whole-lane search of the 64-bit running count makes about 50); the CPU
@@ -694,109 +689,66 @@ _TOP_LIVE_HASH = np.uint64(0xfffffffffffffffe)
 
 def full_search_depth(nb: int) -> int:
     """Levels a binary search over a whole `nb`-slot lane runs (what
-    `jnp.searchsorted` pays for every query): the depth `search_levels` of
+    `jnp.searchsorted` pays for every query): the depth `expand_levels` of
     `JoinPairs` is read against."""
     return int(nb).bit_length()
 
 
-def _run_ends(h_sorted):
-    """For every slot of a sorted lane, one past the last slot of its run of
-    equal values: a reverse running minimum over the run boundaries, by
-    doubling strides, that stops once every slot has seen its boundary —
-    `bit_length(longest run - 1)` passes, none when the values are unique.
-    (`lax.cummin` gives the same lane; its reduce-window took the chip's
-    compiler 33-40 s at 163,840 and 229,376 slots, this loop about 1 s.)"""
-    nb = h_sorted.shape[0]
-    unseen = jnp.int32(nb + 1)
-    last_of_run = jnp.concatenate(
-        [h_sorted[1:] != h_sorted[:-1], jnp.ones(1, jnp.bool_)])
-    ends = jnp.where(last_of_run, jnp.arange(1, nb + 1, dtype=jnp.int32), unseen)
-    beyond = jnp.full(nb, unseen)
+def _merge_ranges(h_b, h_p):
+    """For every probe hash its range of equal hashes among the live build
+    hashes in sorted order, `(left, run)`: `left` as `searchsorted(sorted live
+    build hashes, h_p, "left")` gives it, `run` the build slots that hold
+    `h_p`, both over the hashes' upper 63 bits, with no search and no gather.
 
-    def look_ahead(state):
-        stride, ends = state
-        ahead = jax.lax.dynamic_slice(jnp.concatenate([ends, beyond]),
-                                      (stride,), (nb,))
-        return stride * 2, jnp.minimum(ends, ahead)
+    The build hashes and the probe hashes are sorted as ONE lane whose lowest
+    bit says which side a slot is of, so at equal 63 bits build slots come
+    before probe slots; the live build slots counted up to a probe slot are
+    then `left + run`, and those counted before its run of equal hashes are
+    `left` (dead rows keep `_DEAD_HASH`, sort behind every live one and are
+    not counted).  Two hashes that differ in the 64th bit alone share a range:
+    one more collision for `verify`, as two keys with one hash are.  The count
+    at a run's first slot is carried over the run by a running maximum (the
+    counts never fall): doubling strides, shifts, until every PROBE slot has
+    seen its run's start, `bit_length(longest run - 1)` passes of a 32-bit
+    lane (the loop of `_expand_rows`; `lax.cummax` is a reduce-window that
+    costs the chip's compiler half a minute, `associative_scan` 717 s).  A
+    second sort, by slot id, hands both lanes back in probe-row order.
 
-    return jax.lax.while_loop(lambda state: jnp.any(state[1] == unseen),
-                              look_ahead, (jnp.int32(1), ends))[1]
+    It pays by the slot of BOTH sides, whichever is the larger.  Prices on a
+    v5e (PERF.md, PR 34), at 1,572,864 + 6,291,456 slots: this sort 22 ms (33
+    with the id as a second key in place of the tag bit), the sort back 21
+    (two scatters to the probe rows, random targets, 101: 6.4 ns an update),
+    the count 2.  A bounded search behind a prefix directory, some fifteen
+    gathered 32-bit words a probe slot at 6.5-17 ns, took 1,136 ms where this
+    takes 50 with the build side's `argsort`, and lost at every ratio of the
+    two sides a cell has (202 against 61 at 6,291,456 x 65,536)."""
+    nb, npr = h_b.shape[0], h_p.shape[0]
+    n = nb + npr
+    probe_side = jnp.uint64(1)
+    lane = jnp.concatenate([jnp.where(h_b == _DEAD_HASH, h_b, h_b & ~probe_side),
+                            h_p | probe_side])
+    lane, ids = jax.lax.sort((lane, jnp.arange(n, dtype=jnp.int32)),
+                             num_keys=1, is_stable=False)
+    # a dead build row's lowest bit is set like a probe slot's: its id tells
+    counted = (ids < nb) & ((lane & probe_side) == 0)
+    upto = jnp.cumsum(counted.astype(jnp.int32))  # live build slots up to and at
+    first_of_run = jnp.concatenate(
+        [jnp.ones(1, jnp.bool_), (lane[1:] | probe_side) != (lane[:-1] | probe_side)])
+    nothing = jnp.full(n, -1, jnp.int32)
+    before = jnp.where(first_of_run, upto - counted, nothing)
 
+    def look_back(state):
+        stride, before = state
+        behind = jax.lax.dynamic_slice(jnp.concatenate([nothing, before]),
+                                       (n - stride,), (n,))
+        return stride * 2, jnp.maximum(before, behind)
 
-def directory_bits(nb: int, npr: int) -> int:
-    """Bits K of hash prefix that `_probe_ranges` indexes `nb` sorted build
-    slots by to serve `npr` probe slots: a pure function of the two static
-    shapes (a span reads it on the host, `dir_bits=`).
-
-    In gathered 32-bit words (a 64-bit lane is two on a v5e), with `D =
-    bit_length(nb)` and `L ~ D - K + 2` levels of the bounded search on uniform
-    hashes:
-
-        words(K) = 2 * D * (2^K + 1)       # the directory: full-depth searches
-                 + npr * (2 + 2 * L + 3)   # a probe slot: dir[b], dir[b + 1], L
-                                           # two-word steps, h_sorted[at], run_end[at]
-
-    which is least near `2^K = npr / (D ln 2)`, about `bit_length(npr) - 4` or
-    `- 5`, and flat around it.  A directory finer than 8-16 slots a bucket
-    saves no level, so `bit_length(nb) - 4` bounds it: that is the width
-    wherever the probe side is the larger one (`npr >= nb`; `tpch_sf1.join`'s
-    6M-slot probes), and a build side of 4,194,304 slots probed by 65,536
-    takes 13 bits where it took 19 (25.1M gathered words become 2.3M)."""
-    return max(1, min(int(nb).bit_length() - 4, int(npr).bit_length() - 4))
-
-
-def directory_bits_note(nb: int, npr: int) -> str:
-    """`dir_bits=` as a join's span carries it: the width the program was
-    built with, of the width the build side alone would give ("13 of 19": a
-    narrowed directory; "9 of 9": the probe side is no smaller)."""
-    return f"{directory_bits(nb, npr)} of {directory_bits(nb, nb)}"
-
-
-def _probe_ranges(h_sorted, h_p):
-    """Range of equal hashes in the sorted build lane for every probe hash:
-    `(left, run, levels)`, `left` as `searchsorted(h_sorted, h_p, "left")`
-    gives it and `run` the number of build slots that hold `h_p`.
-
-    Live build hashes are uniform 64-bit values, so their top K bits say
-    within a few slots where a hash sorts.  A prefix directory over those bits
-    (`dir[b]` = first slot whose bucket is >= b; dead rows take bucket 2^K, so
-    `dir[2^K]` is the live count and padding is never searched) bounds a
-    binary search whose trip count, `bit_length(widest bucket)`, is a device
-    scalar read off the directory: 3-6 levels on uniform hashes where K is
-    the build side's `bit_length(nb) - 4`, the full depth only when the build
-    side is one hot key.  K is `directory_bits(nb, npr)`, which reads both
-    shapes: the directory costs `2^K + 1` full-depth searches whoever asks,
-    so a probe side much smaller than the build side takes a narrower one and
-    its wider buckets make the loop run that much longer by itself.  The
-    range's end needs no second search: the run of equal hashes that starts
-    at `left` ends where `_run_ends` says."""
-    nb = h_sorted.shape[0]
-    k_bits = directory_bits(nb, h_p.shape[0])
-    shift = jnp.uint64(64 - k_bits)
-    # 2^K + 1 full-depth queries, where every probe slot made two
-    bounds = jnp.concatenate([jnp.arange(1 << k_bits, dtype=jnp.uint64) << shift,
-                              jnp.full(1, _DEAD_HASH)])
-    directory = jnp.searchsorted(h_sorted, bounds, side="left").astype(jnp.int32)
-    widest = jnp.max(directory[1:] - directory[:-1])
-    levels = (32 - jax.lax.clz(widest)).astype(jnp.int32)  # its bit_length
-
-    b_p = (h_p >> shift).astype(jnp.int32)
-    lo, hi = directory[b_p], directory[b_p + 1]
-
-    def level(_, lo_hi):
-        lo, hi = lo_hi
-        mid = lo + ((hi - lo) >> 1)
-        below = h_sorted[jnp.minimum(mid, nb - 1)] < h_p
-        open_ = lo < hi
-        return (jnp.where(open_ & below, mid + 1, lo),
-                jnp.where(open_ & ~below, mid, hi))
-
-    left, _ = jax.lax.fori_loop(0, levels, level, (lo, hi))
-
-    run_end = _run_ends(h_sorted)
-    at = jnp.minimum(left, nb - 1)  # left == nb: every slot is below h_p
-    run = jnp.where(h_sorted[at] == h_p, run_end[at] - left, 0)
-    return left, run, levels
+    before = jax.lax.while_loop(
+        lambda state: jnp.any((state[1] < 0) & (ids >= nb)), look_back,
+        (jnp.int32(1), before))[1]
+    _, left, end = jax.lax.sort((ids, before, upto), num_keys=1, is_stable=False)
+    left = left[nb:]
+    return left, end[nb:] - left
 
 
 def _expand_rows(starts, offsets, cap: int):
@@ -812,9 +764,8 @@ def _expand_rows(starts, offsets, cap: int):
     maximum carries the ids forward over the rows' other slots: by
     doubling strides, shifts and no gathers, until every slot with a pair
     has seen its row, which is `bit_length(most pairs of a row - 1)` passes,
-    a device scalar (the loop of `_run_ends`, looking back; `lax.cummax` is
-    the reduce-window that costs the chip's compiler half a minute).  None
-    for a key-to-foreign-key join.  Slots that hold no pair read `npr - 1`."""
+    a device scalar (`lax.cummax` is the reduce-window that costs the chip's
+    compiler half a minute).  None for a key-to-foreign-key join.  Slots that hold no pair read `npr - 1`."""
     npr = offsets.shape[0]
     lane = jnp.int32 if cap <= np.iinfo(np.int32).max else jnp.int64
     first_slot = jnp.where(offsets > starts, jnp.minimum(starts, cap), cap)
@@ -836,21 +787,26 @@ def _expand_rows(starts, offsets, cap: int):
     return p_of, pair_live, passes
 
 
-def _sorted_build_hashes(build_keys, b_live):
-    """`(perm, h_sorted)`: the build rows ordered by their 64-bit key hash,
-    and that lane."""
+def _candidate_ranges(build_keys, probe_keys, b_live):
+    """`(perm, left, run)`: the build rows ordered by their 64-bit key hash
+    (dead rows behind every live one) and, for every probe slot, the range
+    `perm[left : left + run]` of build rows that hold its hash
+    (`_merge_ranges`; inside a range the order of equal hashes is `argsort`'s
+    and does not matter)."""
     with jax.named_scope("join_pairs/sort"):
         h_b = jnp.minimum(hash_columns(build_keys), _TOP_LIVE_HASH)
         # dead build rows get a sentinel hash sorted to the end and never matched
         h_b = jnp.where(b_live, h_b, _DEAD_HASH)
         perm = jnp.argsort(h_b)
-        return perm, h_b[perm]
+    with jax.named_scope("join_pairs/probe"):
+        h_p = jnp.minimum(hash_columns(probe_keys), _TOP_LIVE_HASH)
+        return (perm, *_merge_ranges(h_b, h_p))
 
 
 def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
                             cap: int) -> JoinPairs:
-    """TPU join: sort the build hashes, find every probe hash's range of
-    candidates (`_probe_ranges`), expand the ranges into `cap` pair slots
+    """TPU join: order the build rows by hash, find every probe hash's range of
+    candidates (`_candidate_ranges`), expand the ranges into `cap` pair slots
     (`_expand_rows` says which probe row owns a slot; the slot's build position
     is one gathered word more), verify the pairs on the key lanes."""
     b_live = _effective_live(build_keys, build_live)
@@ -862,13 +818,11 @@ def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
         ends = jnp.zeros(npr, jnp.int64)
         return JoinPairs(none, none, jnp.zeros(cap, jnp.bool_),
                          jnp.zeros(npr, jnp.bool_), ends, ends,
-                         jnp.bool_(False), jnp.int32(0), jnp.int32(0))
+                         jnp.bool_(False), jnp.int32(0))
 
-    perm, h_sorted = _sorted_build_hashes(build_keys, b_live)
+    perm, left, run = _candidate_ranges(build_keys, probe_keys, b_live)
 
     with jax.named_scope("join_pairs/probe"):
-        h_p = jnp.minimum(hash_columns(probe_keys), _TOP_LIVE_HASH)
-        left, run, levels = _probe_ranges(h_sorted, h_p)
         counts = jnp.where(p_live, run.astype(jnp.int64), 0)
 
         offsets = jnp.cumsum(counts)
@@ -895,11 +849,12 @@ def _hash_join_pairs_sorted(build_keys, probe_keys, build_live, probe_live,
         verified = verified & b_live[b_of] & p_live[p_of]
 
         # pair slots are ordered by probe row, so per-probe-row "any verified" is a
-        # prefix-sum range query — no scatter (TPU scatters serialize)
+        # prefix-sum range query (a scatter that ORs into shared slots would
+        # serialize; one of distinct updates costs about 5 ns each, PERF.md PR 30)
         probe_matched = probe_matched_from(verified, starts, offsets)
 
     return JoinPairs(b_of, p_of, verified, probe_matched, starts, offsets,
-                     overflow, levels, expand_levels)
+                     overflow, expand_levels)
 
 
 def _any_candidate_equal(build_keys, probe_keys, b_live, order, first, count):
@@ -943,42 +898,39 @@ def _front_rows(live, slots: int):
 def hash_join_matched(build_keys: Sequence[Tuple[Any, Optional[Any]]],
                       probe_keys: Sequence[Tuple[Any, Optional[Any]]],
                       build_live: Any, probe_live: Any,
-                      probe_slots: Optional[int] = None) -> Tuple[Any, Any]:
+                      probe_slots: Optional[int] = None) -> Any:
     """What a semi or anti join without a residual needs of `hash_join_pairs`:
-    `(probe_matched, search_levels)`, exact, with no pair slot, no capacity
-    and so no ladder, whatever the fan-out.  Same sort and range search (or
-    slot table) as the pair enumeration; the candidates are compared in place
+    `probe_matched`, exact, with no pair slot, no capacity and so no ladder,
+    whatever the fan-out.  Same sort and range lookup (`_candidate_ranges`) as
+    the pair enumeration; the candidates are compared in place
     (`_any_candidate_equal`).  This is the sorted (TPU) formulation; the slot
     table's is `hash_join_matched_csr`, over the CSR its caller holds.
 
-    The range search pays by the probe SLOT (some twenty gathered words each),
-    so a caller that has counted the live probe rows and found them few gives
-    `probe_slots`, a bucket that holds them: the live rows' keys are moved to
-    the front of that many slots (`_front_rows`), searched there, and the
-    answers scattered back."""
+    The lookup and the comparison pay by the probe SLOT, live or dead (the
+    comparison gathers both sides' keys for every one), so a caller that has
+    counted the live probe rows and found them few gives `probe_slots`, a
+    bucket that holds them: the live rows' keys are moved to the front of
+    that many slots (`_front_rows`), looked up there, and the answers
+    scattered back."""
     b_live = _effective_live(build_keys, build_live)
     p_live = _effective_live(probe_keys, probe_live)
     nb = build_keys[0][0].shape[0]
     npr = probe_keys[0][0].shape[0]
     if nb == 0 or npr == 0:
-        return jnp.zeros(npr, jnp.bool_), jnp.int32(0)
+        return jnp.zeros(npr, jnp.bool_)
     if probe_slots is not None and probe_slots < npr:
         with jax.named_scope("join_pairs/front"):
             ids, held = _front_rows(p_live, probe_slots)
             front = [(d[ids], None) for d, _ in probe_keys]  # NULLs are not live
-        matched, levels = hash_join_matched(build_keys, front, build_live, held)
+        matched = hash_join_matched(build_keys, front, build_live, held)
         with jax.named_scope("join_pairs/front"):
             return jnp.zeros(npr, jnp.bool_).at[
-                jnp.where(held, ids, npr)].set(matched, mode="drop"), levels
-    perm, h_sorted = _sorted_build_hashes(build_keys, b_live)
-    with jax.named_scope("join_pairs/probe"):
-        h_p = jnp.minimum(hash_columns(probe_keys), _TOP_LIVE_HASH)
-        left, run, levels = _probe_ranges(h_sorted, h_p)
+                jnp.where(held, ids, npr)].set(matched, mode="drop")
+    perm, left, run = _candidate_ranges(build_keys, probe_keys, b_live)
     with jax.named_scope("join_pairs/verify"):
-        matched = _any_candidate_equal(build_keys, probe_keys, b_live,
-                                       perm.astype(jnp.int32), left,
-                                       jnp.where(p_live, run, 0))
-    return matched, levels
+        return _any_candidate_equal(build_keys, probe_keys, b_live,
+                                    perm.astype(jnp.int32), left,
+                                    jnp.where(p_live, run, 0))
 
 
 def hash_join_matched_csr(build_keys, probe_keys, build_live, probe_live,

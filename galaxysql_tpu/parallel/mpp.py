@@ -138,16 +138,6 @@ def _fill(live_rows: int, slots: int) -> float:
     return round(live_rows / slots, 4) if slots else 0.0
 
 
-def _dir_bits(nb: int, npr: int) -> Dict[str, str]:
-    """`dir_bits=` of a join stage: the width of the prefix directory its
-    program was built with, from the slots a shard joins (`nb` build, `npr`
-    probe: the shapes the program was keyed by), through the function the
-    kernel calls.  Nothing where the slot-table formulation runs: it builds no
-    directory."""
-    return {} if K.prefer_scatter() else {
-        "dir_bits": K.directory_bits_note(nb, npr)}
-
-
 def _shard_skew_ratio(per_shard) -> Optional[float]:
     """max/mean live rows per shard, or None for an empty stage."""
     total = float(np.sum(per_shard))
@@ -1052,9 +1042,7 @@ class MppExecutor:
                 attrs = {"exchange": "replicated"} if build.replicated else {
                     "exchange": "broadcast", "build_slots": slots // self.S,
                     "build_rows": live // self.S, "fill": _fill(live, slots)}
-                return out, counts[:, 0], dict(
-                    attrs, cap=cap, retries=retries,
-                    **_dir_bits(int(build.live.shape[0]), probe_R))
+                return out, counts[:, 0], dict(attrs, cap=cap, retries=retries)
             retries += 1
             cap *= 2
             if cap > (1 << 24):
@@ -1140,8 +1128,7 @@ class MppExecutor:
                     "build_rows": int(counts[:, 0].sum()),
                     "probe_rows": int(counts[:, 1].sum()),
                     "probe_skew": _shard_skew_ratio(counts[:, 1]),
-                    "fill": _fill(moved, slots),
-                    **_dir_bits(quota_b * self.S, quota_p * self.S)}
+                    "fill": _fill(moved, slots)}
             retries += 1
             if over_b:
                 quota_b *= 2

@@ -1,11 +1,13 @@
-"""The TPU join's range lookup (`K._probe_ranges`: prefix directory, bounded
-search, run lengths) against a NumPy oracle that searches the whole sorted
-build lane twice, as the formulation did before: every leaf of `JoinPairs` equal,
-the depth it reports, and the same function under `shard_map`; and its ragged
+"""The TPU join's range lookup, `K._merge_ranges` (both sides' hashes sorted as
+one lane, a running count of the build slots, a sort back), against a NumPy
+oracle that searches the whole sorted build lane twice, as the formulation
+once did: every leaf of `JoinPairs` equal, the lookup alone bit for bit
+`np.searchsorted`'s left and right at every ratio of the two sides a cell of
+the benchmark has, the pair-less probe (`K.hash_join_matched`) whole and
+through a front bucket, and the same function under `shard_map`; the ragged
 expansion (`K._expand_rows`: one scatter, a running maximum by doubling
-strides) against the same oracle's full-depth search for every pair slot; the
-directory's width (`K.directory_bits`, from both sides' shapes) alone, in the
-lowered text, and as `dir_bits=` on the spans of a join.
+strides) against the same oracle's full-depth search for every pair slot; and
+what the chip's compiler is handed: a probe scope of two sorts and no gather.
 
 The sorted formulation is called directly: on this backend `hash_join_pairs`
 picks the slot-table one."""
@@ -20,8 +22,6 @@ import pytest
 from galaxysql_tpu.kernels import relational as K
 
 DEAD = np.uint64(0xffffffffffffffff)
-
-
 def _np_live(keys, live):
     m = np.asarray(live)
     for _, v in keys:
@@ -37,14 +37,13 @@ def oracle(build_keys, probe_keys, build_live, probe_live, cap) -> K.JoinPairs:
     if nb == 0 or npr == 0:  # no candidate: every slot dead and zero
         none, ends = np.zeros(cap, np.int32), np.zeros(npr, np.int64)
         return K.JoinPairs(none, none, np.zeros(cap, bool), np.zeros(npr, bool),
-                           ends, ends, np.bool_(False), np.int32(0))
+                           ends, ends, np.bool_(False))
     top = DEAD - np.uint64(1)
     h_b = np.where(b_live, np.minimum(np.asarray(K.hash_columns(build_keys)), top), DEAD)
     h_p = np.minimum(np.asarray(K.hash_columns(probe_keys)), top)
     perm = np.argsort(h_b, kind="stable")
     h_sorted = h_b[perm]
-    left = np.searchsorted(h_sorted, h_p, side="left")
-    right = np.searchsorted(h_sorted, h_p, side="right")
+    left, right = _searchsorteds(h_sorted, h_p)
     counts = np.where(p_live, right - left, 0).astype(np.int64)
     offsets = np.cumsum(counts)
     total = offsets[-1]
@@ -62,20 +61,18 @@ def oracle(build_keys, probe_keys, build_live, probe_live, cap) -> K.JoinPairs:
         verified = verified & (np.asarray(bd)[b_of] == np.asarray(pd)[p_of])
     c = np.concatenate([[0], np.cumsum(verified)])
     matched = (c[np.clip(offsets, 0, cap)] - c[np.clip(starts, 0, cap)]) > 0
-    widest = _widest_bucket(h_sorted, nb, npr)
     return K.JoinPairs(b_of, p_of, verified, matched, starts, offsets,
-                       np.bool_(total > cap), np.int32(int(widest).bit_length()))
+                       np.bool_(total > cap))
 
 
-def _widest_bucket(h_sorted, nb, npr):
-    """Most live hashes that share the top bits the directory indexes: the
-    smaller of `bit_length(nb) - 4` and `bit_length(npr) - 4` (one bit at
-    least)."""
-    live = h_sorted[h_sorted != DEAD]
-    k_bits = max(min(int(nb).bit_length(), int(npr).bit_length()) - 4, 1)
-    if not live.size:
-        return 0
-    return np.bincount((live >> np.uint64(64 - k_bits)).astype(np.int64)).max()
+def _searchsorteds(h_sorted, h_p):
+    """`np.searchsorted` left and right of the sorted build hashes, over the
+    upper 63 bits of the live rows (the lookup's lane keeps the lowest bit for
+    the side; the dead rows sort behind the live ones, so a position among the
+    live rows is the position in the whole lane)."""
+    h_sorted, h_p = h_sorted[h_sorted != DEAD] >> np.uint64(1), h_p >> np.uint64(1)
+    return (np.searchsorted(h_sorted, h_p, side="left"),
+            np.searchsorted(h_sorted, h_p, side="right"))
 
 
 def expand_passes(starts, offsets, cap) -> np.int32:
@@ -162,6 +159,21 @@ def _cap_too_small(rng):
 def _no_probe_rows(rng):
     return [_lane(rng, 64, 10)], [(jnp.zeros(0, jnp.int64), None)], \
         np.ones(64, bool), np.zeros(0, bool), 32
+
+
+def _no_build_rows(rng):
+    return [(jnp.zeros(0, jnp.int64), None)], [_lane(rng, 64, 10)], \
+        np.zeros(0, bool), np.ones(64, bool), 32
+
+
+def _one_probe_slot(rng):
+    return [_lane(rng, 50, 6)], [(jnp.full(1, 3, jnp.int64), None)], \
+        rng.random(50) > 0.1, np.ones(1, bool), 64
+
+
+def _one_slot_a_side(rng):
+    one = [(jnp.full(1, 3, jnp.int64), None)]
+    return one, one, np.ones(1, bool), np.ones(1, bool), 16
 
 
 def _one_build_slot(rng):
@@ -255,6 +267,9 @@ CASES = {
     "two_column_keys": _two_columns,
     "cap_too_small": _cap_too_small,
     "npr_0": _no_probe_rows,
+    "nb_0": _no_build_rows,
+    "npr_1": _one_probe_slot,
+    "nb_1_npr_1": _one_slot_a_side,
     "nb_1": _one_build_slot,
     "nb_1_dead": _one_dead_build_slot,
     "long_run_of_empty_probe_rows": _long_empty_run,
@@ -287,8 +302,8 @@ def _assert_equal_pairs(got: K.JoinPairs, want: K.JoinPairs):
 def test_every_leaf_equals_the_full_search_oracle(case, collide, monkeypatch):
     if collide:
         # eight distinct hashes: nearly every candidate pair is a collision
-        # that `verify` has to drop, and the widest bucket holds an eighth of
-        # the build side
+        # that `verify` has to drop, and a run of equal hashes holds an eighth
+        # of the build side
         monkeypatch.setattr(K, "hash_columns", _three_bit_hash)
     bkeys, pkeys, blive, plive, cap = CASES[case](np.random.default_rng(11))
     if collide:
@@ -298,15 +313,11 @@ def test_every_leaf_equals_the_full_search_oracle(case, collide, monkeypatch):
         bkeys, pkeys, jnp.asarray(blive), jnp.asarray(plive))
     want = with_expand_passes(oracle(bkeys, pkeys, blive, plive, cap), cap)
     _assert_equal_pairs(got, want)
-    nb = blive.shape[0]
-    assert 0 <= int(got.search_levels) <= K.full_search_depth(nb)
     assert 0 <= int(got.expand_levels) <= K.full_search_depth(cap - 1)
     if case == "cap_too_small":
         assert bool(got.overflow) != collide  # 16,384 candidates in 256 slots
-    if case == "one_hot_key":
-        assert int(got.search_levels) == K.full_search_depth(nb) == 10
     if case == "all_dead_build":
-        assert int(got.search_levels) == 0 and not np.asarray(got.live).any()
+        assert not np.asarray(got.live).any()
     if collide:
         return
     total = int(np.asarray(got.probe_offsets)[-1]) if plive.shape[0] else 0
@@ -351,19 +362,6 @@ def test_dead_sentinel_hash_is_a_live_hash_like_any_other(monkeypatch):
                                   jnp.ones(4, bool), 128)
     assert np.asarray(r.probe_matched).tolist() == [True, False, True, False]
     assert int(np.asarray(r.probe_offsets)[-1]) == 4 * 16  # live rows only
-    assert int(r.search_levels) == 5
-
-
-def test_uniform_keys_search_a_few_levels_of_the_full_depth():
-    nb, npr = 65_536, 100_000
-    rng = np.random.default_rng(17)
-    bk = jnp.asarray(rng.permutation(nb).astype(np.int64))
-    pk = jnp.asarray(rng.integers(0, nb, npr))
-    r = jax.jit(lambda *a: K._hash_join_pairs_sorted(*a, 1 << 17))(
-        [(bk, None)], [(pk, None)], jnp.ones(nb, bool), jnp.ones(npr, bool))
-    assert K.full_search_depth(nb) == 17
-    assert 1 <= int(r.search_levels) <= 8
-    assert int(np.asarray(r.live).sum()) == npr and not bool(r.overflow)
 
 
 @pytest.mark.parametrize("pairs_a_row,passes", [(1, 0), (2, 1), (5, 3), (64, 6)])
@@ -386,18 +384,19 @@ def test_expansion_fills_forward_as_far_as_the_most_pairs_of_a_row(pairs_a_row, 
 def test_slot_table_formulation_reports_no_depth():
     one = [(jnp.zeros(8, jnp.int64), None)]
     r = K._hash_join_pairs_table(one, one, jnp.ones(8, bool), jnp.ones(8, bool), 128)
-    assert r.search_levels is None and r.expand_levels is None
+    assert r.expand_levels is None
 
 
 def test_traces_and_agrees_under_shard_map():
-    """Each shard joins its own block, with its own trip count: the loop holds
-    no collective (what `parallel/mpp._join_block` relies on)."""
+    """Each shard joins its own block, with its own trip count: the loops (the
+    lookup's running count, the expansion's fill) hold no collective (what
+    `parallel/mpp._join_block` relies on)."""
     from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     shards, nb, npr, cap = 4, 512, 1024, 4096
     rng = np.random.default_rng(23)
-    # shard 0: one hot key (full depth); shard 3: an all-dead build side
+    # shard 0: one hot key (a run of 512 build slots); shard 3: an all-dead build side
     bk = rng.integers(0, 200, (shards, nb))
     bk[0] = 7
     pk = rng.integers(0, 200, (shards, npr))
@@ -408,7 +407,6 @@ def test_traces_and_agrees_under_shard_map():
     def block(bk, pk, blive, plive):
         r = K._hash_join_pairs_sorted([(bk, None)], [(pk, None)], blive, plive, cap)
         return r._replace(overflow=r.overflow[None],
-                          search_levels=r.search_levels[None],
                           expand_levels=r.expand_levels[None])
 
     mesh = Mesh(np.array(jax.devices()[:shards]), ("x",))
@@ -422,8 +420,6 @@ def test_traces_and_agrees_under_shard_map():
         mine = K.JoinPairs(*(np.asarray(leaf).reshape(shards, -1)[s].reshape(
             np.shape(w)) for leaf, w in zip(got, want)))
         _assert_equal_pairs(mine, want)
-    levels = np.asarray(got.search_levels).tolist()
-    assert levels[0] == K.full_search_depth(nb) and levels[3] == 0
     # the expansion's loop likewise: 512 pairs a row on shard 0, a handful on
     # shards 1 and 2, none on shard 3
     passes = np.asarray(got.expand_levels).tolist()
@@ -431,12 +427,18 @@ def test_traces_and_agrees_under_shard_map():
     assert passes[3] == 0
 
 
+# build rows (two pairs a matching probe row), probe slots a batch: a probe
+# side smaller than its build side, and one twice it
+OPERATOR_SHAPES = {"probe_700_of_2048": (2000, 700), "probe_1024_of_512": (400, 1024)}
+
+
+@pytest.mark.parametrize("shape", list(OPERATOR_SHAPES))
 @pytest.mark.parametrize("join_type", ["inner", "left", "semi", "anti"])
-def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, request):
+def test_operator_counts_its_probes_and_writes_the_expansion_on_its_span(
+        join_type, shape, request):
     """`HashJoinOp` on the TPU's formulation: one count per probe batch in
-    `JOIN_STATS`, "levels of full depth" on the span under the cursor for the
-    range search and for the expansion, and the rows of the slot-table
-    formulation."""
+    `JOIN_STATS`, "passes of full depth" of the expansion on the span under
+    the cursor, and the rows of the slot-table formulation."""
     from galaxysql_tpu.chunk.batch import Column, ColumnBatch
     from galaxysql_tpu.exec import operators as ops
     from galaxysql_tpu.expr import ir
@@ -447,10 +449,11 @@ def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, request)
         col = Column(jnp.asarray(np.asarray(values, np.int64)), None, dt.BIGINT, None)
         return ColumnBatch({name: col}, None if live is None else jnp.asarray(live))
 
+    build_rows, npr = OPERATOR_SHAPES[shape]
     rng = np.random.default_rng(29)
-    keys = rng.permutation(3000)[:1000]
+    keys = rng.permutation(3 * build_rows // 2)[:build_rows // 2]
     build = batch("k", np.concatenate([keys, keys]))  # two pairs a matching row
-    probes = [batch("a", rng.integers(0, 3000, 700), rng.random(700) > 0.1)
+    probes = [batch("a", rng.integers(0, 3 * build_rows // 2, npr), rng.random(npr) > 0.1)
               for _ in range(2)]
     bk, pk = [ir.ColRef("k", dt.BIGINT, None)], [ir.ColRef("a", dt.BIGINT, None)]
 
@@ -478,116 +481,212 @@ def test_operator_counts_the_depth_and_writes_it_on_its_span(join_type, request)
     with tracing.activate(tc):
         got = rows(op)
     assert got == want and len(want) > 0
-    full = K.full_search_depth(ops.bucket_capacity(2000))
     assert ops.JOIN_STATS["probes"] == before["probes"] + 2
-    assert ops.JOIN_STATS["full_depth_levels"] == before["full_depth_levels"] + 2 * full
-    levels = ops.JOIN_STATS["search_levels"] - before["search_levels"]
-    assert 2 <= levels <= 2 * 8 < 2 * full
-    assert span.attrs["search_levels"] == f"{levels} of {2 * full}"
-    assert f"search_levels={levels} of {2 * full}" in "\n".join(tc.tree_lines())
+    tree = "\n".join(tc.tree_lines())
     if join_type in ("semi", "anti"):
         # no residual: which probe rows match is asked of the ranges
         # themselves, no pair is enumerated and nothing is expanded
         assert ops.JOIN_STATS["expand_levels"] == before["expand_levels"]
         assert "expand_levels" not in span.attrs
-        assert span.attrs["dir_bits"] == "6 of 8"
         return
     # the expansion: one pass a probe carries a row's id to its second pair,
-    # where a search over the 700 probe slots would run ten levels
-    expand_full = K.full_search_depth(700)
+    # where a search over the probe slots would run ten or eleven levels
+    expand_full = K.full_search_depth(npr)
     assert ops.JOIN_STATS["expand_levels"] == before["expand_levels"] + 2
     assert ops.JOIN_STATS["expand_full_depth_levels"] == \
         before["expand_full_depth_levels"] + 2 * expand_full
     assert span.attrs["expand_levels"] == f"2 of {2 * expand_full}"
-    assert f"expand_levels=2 of {2 * expand_full}" in "\n".join(tc.tree_lines())
-    # the directory's width, from the shapes alone: 2,048 build slots probed
-    # by 700 take six bits of the eight the build side alone would give
-    assert span.attrs["dir_bits"] == K.directory_bits_note(
-        ops.bucket_capacity(2000), 700) == "6 of 8"
-    assert "dir_bits=6 of 8" in "\n".join(tc.tree_lines())
+    assert f"expand_levels=2 of {2 * expand_full}" in tree
 
 
-@pytest.mark.parametrize("ratio", list(RATIOS))
-@pytest.mark.parametrize("kind", list(BUILD_KINDS))
-def test_left_and_run_are_searchsorteds(kind, ratio):
-    """`_probe_ranges` alone over a sorted lane of hashes, dead rows behind the
-    live ones: `left` and `run` bit for bit what two searches of the whole
-    lane give, at every width the two shapes choose."""
-    bkeys, pkeys, blive, _plive, _cap = _ratio_case(kind, ratio)(np.random.default_rng(37))
-    npr = pkeys[0][0].shape[0]
-    h_b = np.minimum(np.asarray(K.hash_columns(bkeys)), DEAD - np.uint64(1))
-    h_sorted = jnp.asarray(np.sort(np.where(blive, h_b, DEAD)))
-    h_p = jnp.minimum(K.hash_columns(pkeys), DEAD - np.uint64(1))
-    left, run, levels = jax.jit(K._probe_ranges)(h_sorted, h_p)
-    want_left = jnp.searchsorted(h_sorted, h_p, side="left")
-    assert (np.asarray(left) == np.asarray(want_left)).all()
-    assert (np.asarray(run) == np.asarray(
-        jnp.searchsorted(h_sorted, h_p, side="right") - want_left)).all()
-    assert int(levels) == int(_widest_bucket(np.asarray(h_sorted), RATIO_NB,
-                                             npr)).bit_length()
-    assert bool((np.asarray(run) > 0).any()) and bool((np.asarray(run) == 0).any())
+TOP = DEAD - np.uint64(1)  # what a live hash is held to
 
 
-def _bucket_capacities():
-    from galaxysql_tpu.exec.operators import bucket_capacity
-    return sorted({bucket_capacity(n) for e in range(0, 25)
-                   for n in (1 << e, 5 << e >> 2, 3 << e >> 1, 7 << e >> 2)})
+def _hashes_of(kind, ratio):
+    def make(rng):
+        bkeys, pkeys, blive, _plive, _cap = _ratio_case(kind, ratio)(rng)
+        h_b = np.minimum(np.asarray(K.hash_columns(bkeys)), TOP)
+        return np.where(blive, h_b, DEAD), np.minimum(np.asarray(K.hash_columns(pkeys)), TOP)
+    return make
 
 
-def test_directory_bits_are_the_build_sides_own_where_the_probe_is_no_smaller():
-    """What keeps every program whose probe side is the larger one as it was:
-    over the capacities a batch can have, `npr >= nb` gives
-    `bit_length(nb) - 4` (one bit at least)."""
-    caps = _bucket_capacities()
-    assert len(caps) >= 40 and caps[-1] >= 1 << 24  # powers of two, then quarter steps
-    for nb in caps + [1, 2, 3, 1000, 4097]:
-        today = max(nb.bit_length() - 4, 1)
-        for npr in [n for n in caps if n >= nb] + [nb, nb + 1, 8 * nb]:
-            assert K.directory_bits(nb, npr) == today, (nb, npr)
-        assert K.directory_bits_note(nb, nb) == f"{today} of {today}"
+def _u64(*values):
+    return np.array(values, np.uint64)
 
 
-def test_directory_bits_never_fall_below_one_and_never_fall_as_the_probe_grows():
-    caps = _bucket_capacities()
-    for nb in caps:
-        widths = [K.directory_bits(nb, npr) for npr in [0, 1, 2, 15, 16, 17] + caps]
-        assert widths[0] == 1 and min(widths) >= 1
-        assert widths == sorted(widths), nb
-        assert widths[-1] == max(nb.bit_length() - 4, 1) == max(widths)
+def _joined(*parts):
+    return np.concatenate([np.asarray(part).astype(np.uint64) for part in parts])
 
 
-@pytest.mark.parametrize("join,nb,npr,now,before", [
-    # `tpch_sf1_mpp4_subq.semi_anti`'s five joins, slots a shard (PERF.md section 5)
-    ("q21_semi", 4_194_304, 65_536, 13, 19),
-    ("q21_anti", 2_097_152, 131_072, 14, 18),
-    ("q4_semi", 2_097_152, 32_768, 12, 18),
-    ("orders_broadcast", 2_097_152, 65_536, 13, 18),
-    ("supplier_broadcast", 4_096, 1_048_576, 9, 9),
-    # `tpch_sf1_mpp4.join_q3`'s shuffle join
-    ("join_q3_shuffle", 131_072, 32_768, 12, 14),
-])
-def test_directory_bits_at_the_mesh_cells_shapes(join, nb, npr, now, before):
-    assert K.directory_bits(nb, npr) == now
-    assert K.directory_bits_note(nb, npr) == f"{now} of {before}"
-    # the model the width comes from, in gathered 32-bit words: the width
-    # chosen costs no more than the build side's own, and is within a fifth
-    # of the best width there is
-    depth = nb.bit_length()
+# the two lanes the lookup is handed: build hashes as they come (dead rows hold
+# `DEAD`), probe hashes (a dead probe row's is looked up like any other: its
+# count is dropped after)
+HASH_LANES = {f"{kind}_build_npr_{ratio}": _hashes_of(kind, ratio)
+              for kind in BUILD_KINDS for ratio in RATIOS}
+HASH_LANES.update({
+    "nb_1_npr_1_match": lambda rng: (_u64(77), _u64(77)),
+    "nb_1_npr_1_no_match": lambda rng: (_u64(77), _u64(78)),
+    "nb_1_dead": lambda rng: (_u64(DEAD), _u64(5, TOP, 0)),
+    "all_dead_build": lambda rng: (np.full(300, DEAD), rng.integers(0, 1 << 62, 500).astype(np.uint64)),
+    # live rows AT the top live value, beside dead rows one above it
+    "top_live_value": lambda rng: (
+        rng.permutation(_joined(np.full(5, TOP), np.full(40, DEAD), _u64(3, 3, TOP - np.uint64(1)))),
+        _u64(TOP, TOP - np.uint64(1), TOP - np.uint64(2), 3, TOP, 9)),
+    # hashes equal in their upper 63 bits are one hash to the lookup (its lane
+    # keeps the lowest bit for the side)
+    "differ_in_the_lowest_bit": lambda rng: (
+        rng.permutation(_u64(*[2 * x + b for x in range(100, 164) for b in (0, 0, 1)])),
+        _u64(*[2 * x + b for x in range(98, 168) for b in (1, 0)])),
+    "probes_below_and_above_every_build_hash": lambda rng: (
+        rng.integers(1 << 20, 1 << 21, 64).astype(np.uint64),
+        _joined(np.zeros(9, np.uint64), np.full(9, TOP), rng.integers(1 << 20, 1 << 21, 30))),
+    "one_hot_key_between_two_keys": lambda rng: (
+        rng.permutation(_joined(np.full(5000, 50), _u64(49, 51), np.full(70, DEAD))),
+        rng.integers(48, 53, 4000).astype(np.uint64)),
+})
 
-    def words(k):
-        return 2 * depth * ((1 << k) + 1) + npr * (2 + 2 * (depth - k + 2) + 3)
-    assert words(now) <= words(before)
-    assert words(now) <= 1.2 * min(words(k) for k in range(1, before + 1))
+
+def _assert_searchsorteds(h_b, h_p):
+    left, run = jax.jit(K._merge_ranges)(jnp.asarray(h_b), jnp.asarray(h_p))
+    want_left, want_right = _searchsorteds(np.sort(h_b), h_p)
+    assert (np.asarray(left) == want_left).all()
+    assert (np.asarray(run) == want_right - want_left).all()
+    assert left.dtype == run.dtype == jnp.int32
+    return np.asarray(run)
+
+
+@pytest.mark.parametrize("lanes", list(HASH_LANES))
+def test_left_and_run_are_searchsorteds(lanes):
+    """The lookup alone over the build hashes (dead rows behind the live ones
+    once sorted) and the probe hashes: `left` and `run` bit for bit what
+    `np.searchsorted` left and right give over the upper 63 bits of the live
+    rows' sorted hashes, with no search at all."""
+    h_b, h_p = HASH_LANES[lanes](np.random.default_rng(37))
+    run = _assert_searchsorteds(h_b, h_p)
+    if "_build_npr_" in lanes or lanes in ("top_live_value", "differ_in_the_lowest_bit"):
+        assert bool((run > 0).any()) and bool((run == 0).any())
+    if lanes == "top_live_value":  # never a dead row; TOP - 2 shares 63 bits with TOP - 1
+        assert run.tolist() == [5, 1, 1, 2, 5, 0]
+    if lanes == "differ_in_the_lowest_bit":
+        assert sorted(set(run.tolist())) == [0, 3]
+    if lanes == "one_hot_key_between_two_keys":
+        assert dict(zip(h_p.tolist(), run.tolist())) == \
+            {48: 1, 49: 1, 50: 5001, 51: 5001, 52: 0}
+
+
+# build x probe slots of every sorted probe the benchmark's cells make at SF1
+# (listed on this CPU under the chip's formulations; PERF.md sections 4-5)
+CELL_SHAPES = {
+    # `tpch_sf1.join`'s seven probes: every probe side the larger one
+    "q3_customer_orders": (32_768, 1_572_864),
+    "q3_orders_lineitem": (163_840, 6_291_456),
+    "q5_customer_orders": (163_840, 1_572_864),
+    "q5_region_nation": (1_024, 1_024),
+    "q5_nation_supplier": (1_024, 16_384),
+    "q5_supplier_lineitem": (4_096, 6_291_456),
+    "q5_orders_lineitem": (229_376, 458_752),
+    # `tpch_sf1_joinkinds.left_anti_semi`: build sides ten to a hundred times
+    # their probe sides
+    "q13_left": (1_572_864, 163_840),
+    "q22_anti": (1_572_864, 32_768),
+    "q4_semi_one_chip": (6_291_456, 65_536),
+    # `tpch_sf1_mpp4_subq.semi_anti`, slots a shard
+    "q21_semi": (4_194_304, 65_536),
+    "q21_anti": (2_097_152, 131_072),
+    "q4_semi": (2_097_152, 32_768),
+    "orders_broadcast": (2_097_152, 65_536),
+    "supplier_broadcast": (4_096, 1_048_576),
+    "nation_broadcast": (512, 1_024),
+    # `tpch_sf1_mpp4.join_q3`: `customer` gathered into `orders`, then that
+    # result shuffled into `lineitem`
+    "join_q3_broadcast": (32_768, 65_536),
+    "join_q3_shuffle": (131_072, 32_768),
+    # `tpch_sf1.scan` joins nothing
+}
+
+
+@pytest.mark.parametrize("join", list(CELL_SHAPES))
+def test_left_and_run_are_searchsorteds_at_the_cells_ratios(join):
+    """One lookup whatever the two shapes: the cells' build and probe slots over
+    256 (one slot at least), build keys in runs of 1-7 and a third of the build
+    rows dead, probe keys from twice the build side's domain."""
+    nb, npr = (max(n // 256, 1) for n in CELL_SHAPES[join])
+    rng = np.random.default_rng(nb + npr)
+    bk = np.repeat(np.arange(nb), rng.integers(1, 8, nb))[:nb].astype(np.int64)
+    h_b = np.minimum(np.asarray(K.hash_columns([(jnp.asarray(bk), None)])), TOP)
+    h_b = np.where(rng.random(nb) < 0.67, h_b, DEAD)
+    pk = rng.integers(0, 2 * int(bk.max()) + 2, npr)
+    h_p = np.minimum(np.asarray(K.hash_columns([(jnp.asarray(pk), None)])), TOP)
+    run = _assert_searchsorteds(h_b, h_p)
+    if npr >= 64:
+        assert bool((run > 0).any()) and bool((run == 0).any())
+
+
+def _collide_in_63_bits(cols):
+    """Keys 2x and 2x + 1 hash to values that differ in the lowest bit only."""
+    (data, _), = cols
+    return (_REAL_HASH([(data >> 1, None)]) & ~np.uint64(1)) | (data & 1).astype(jnp.uint64)
+
+
+def test_keys_whose_hashes_differ_in_the_lowest_bit_join_exactly(monkeypatch):
+    """Neighbouring keys collide in 63 bits of their hash and differ in the
+    64th: the lookup hands both to `verify` (and to the pair-less probe's
+    comparison in place), which tells them apart; and with the bit dropped
+    from the hash too: the pairs are the equal keys."""
+    for hash_ in (_collide_in_63_bits,
+                  lambda cols: _collide_in_63_bits(cols) & ~np.uint64(1)):
+        monkeypatch.setattr(K, "hash_columns", hash_)
+        rng = np.random.default_rng(41)
+        bd, pd = rng.integers(0, 400, 700), rng.integers(0, 400, 900)
+        r = jax.jit(lambda *a: K._hash_join_pairs_sorted(*a, 1 << 13))(
+            [(jnp.asarray(bd), None)], [(jnp.asarray(pd), None)],
+            jnp.ones(700, bool), jnp.ones(900, bool))
+        live = np.asarray(r.live)
+        got = sorted(zip(np.asarray(r.build_idx)[live].tolist(),
+                         np.asarray(r.probe_idx)[live].tolist()))
+        assert got == sorted((int(b), int(p)) for p in range(900)
+                             for b in np.nonzero(bd == pd[p])[0])
+        assert not bool(r.overflow)
+        matched = jax.jit(lambda *a: K.hash_join_matched(*a))(
+            [(jnp.asarray(bd), None)], [(jnp.asarray(pd), None)],
+            jnp.ones(700, bool), jnp.ones(900, bool))
+        assert (np.asarray(matched) == np.isin(pd, bd)).all()
+
+
+# build slots, probe slots: a probe side smaller, equal, larger, and far apart
+SIDE_SHAPES = [(1000, 999), (1000, 1000), (1000, 1001), (64, 4096), (4096, 64), (1, 1)]
+
+
+@pytest.mark.parametrize("nb,npr", SIDE_SHAPES)
+def test_pairs_and_the_pairless_probe_agree_whichever_side_is_larger(nb, npr):
+    """`_hash_join_pairs_sorted` against the oracle, and `hash_join_matched`,
+    whole and through a front bucket that holds the live probe rows, against
+    the pairs' `probe_matched`."""
+    rng = np.random.default_rng(nb + npr)
+    bkeys, pkeys = [_lane(rng, nb, 600, 0.1)], [_lane(rng, npr, 900, 0.1)]
+    blive, plive = jnp.asarray(rng.random(nb) > 0.2), jnp.asarray(rng.random(npr) > 0.5)
+    cap = 1 << 14
+    slots = max(int(np.asarray(_np_live(pkeys, plive)).sum()), 1)
+    pairs = jax.jit(lambda *a: K._hash_join_pairs_sorted(*a, cap))(
+        bkeys, pkeys, blive, plive)
+    whole = jax.jit(lambda *a: K.hash_join_matched(*a))(bkeys, pkeys, blive, plive)
+    front = jax.jit(lambda *a: K.hash_join_matched(*a, slots))(bkeys, pkeys, blive, plive)
+    want = with_expand_passes(oracle(bkeys, pkeys, np.asarray(blive), np.asarray(plive),
+                                     cap), cap)
+    _assert_equal_pairs(pairs, want)
+    assert whole.shape == front.shape == (npr,)
+    assert (np.asarray(whole) == np.asarray(want.probe_matched)).all()
+    assert (np.asarray(front) == np.asarray(want.probe_matched)).all()
 
 
 @pytest.mark.parametrize("exchange", ["broadcast", "shuffle"])
-def test_stage_span_carries_the_directory_bits_its_program_was_built_with(
-        exchange, monkeypatch, chip_formulation):
+def test_a_mesh_join_looks_up_the_slots_a_shard_joins(exchange, monkeypatch,
+                                                      chip_formulation):
     """A semi join through `MppExecutor` on four virtual devices, a fact
-    table on its build side: `dir_bits=` on `stage:Join` is the width the
-    traced kernel chose from the slots a shard joined, a narrowed directory
-    here; with the sides swapped, the build side's own.  The slot-table
-    formulation builds no directory and says nothing."""
+    table on its build side and then on its probe side: the one lookup is
+    traced under `shard_map` with the slots a shard joins (what the stage's
+    span says of its quotas), whichever side is the larger, and the counts are
+    exact.  The slot-table formulation looks no range up."""
     from galaxysql_tpu.parallel import mpp as M
     from galaxysql_tpu.parallel.mesh import make_mesh
     from galaxysql_tpu.plan.physical import ExecContext
@@ -614,9 +713,9 @@ def test_stage_span_carries_the_directory_bits_its_program_was_built_with(
         monkeypatch.setattr(M, "BROADCAST_BUILD_LIMIT", 0)
 
     traced = []  # (build slots, probe slots) of every range lookup traced
-    real = K._probe_ranges
-    monkeypatch.setattr(K, "_probe_ranges", lambda h_sorted, h_p: (
-        traced.append((h_sorted.shape[0], h_p.shape[0])), real(h_sorted, h_p))[1])
+    merge = K._merge_ranges
+    monkeypatch.setattr(K, "_merge_ranges", lambda h_b, h_p: (
+        traced.append((h_b.shape[0], h_p.shape[0])), merge(h_b, h_p))[1])
 
     def run(outer, inner):
         inst.frag_cache.clear()
@@ -631,35 +730,34 @@ def test_stage_span_carries_the_directory_bits_its_program_was_built_with(
             batch = M.MppExecutor(ctx, make_mesh(shards)).execute(plan.rel)
         join, = [sp for sp in tc.spans if sp.kind == "stage" and sp.name == "mpp:Join"]
         assert join.attrs["exchange"] == exchange and join.attrs["kind"] == "semi"
-        return batch.to_pylist()[0][0], join.attrs, "\n".join(tc.tree_lines())
+        return batch.to_pylist()[0][0], join.attrs
 
-    try:
-        count, attrs, tree = run("few", "fact")
-        assert count == int(np.isin(few["k"], fact["k"]).sum())
-        (nb, npr), = set(traced)
+    def slots_of(attrs, nb, npr):
         if exchange == "shuffle":
             assert (nb, npr) == (shards * attrs["quota_b"], shards * attrs["quota_p"])
         else:
             assert nb == attrs["build_slots"]
-        assert nb >= 16 * npr
-        assert attrs["dir_bits"] == K.directory_bits_note(nb, npr)
-        narrowed, full = (int(n) for n in attrs["dir_bits"].split(" of "))
-        assert 1 <= narrowed == npr.bit_length() - 4 < full == nb.bit_length() - 4
-        assert f"dir_bits={narrowed} of {full}" in tree
 
-        count, attrs, _ = run("fact", "few")  # the fact table probes
+    try:
+        count, attrs = run("few", "fact")
+        assert count == int(np.isin(few["k"], fact["k"]).sum())
+        (nb, npr), = set(traced)
+        slots_of(attrs, nb, npr)
+        assert nb >= 16 * npr
+
+        count, attrs = run("fact", "few")  # the fact table probes
         assert count == int(np.isin(fact["k"], few["k"]).sum())
         (nb, npr), = set(traced)
+        slots_of(attrs, nb, npr)
         assert npr >= nb
-        assert attrs["dir_bits"] == f"{nb.bit_length() - 4} of {nb.bit_length() - 4}"
 
         monkeypatch.setattr(K, "prefer_scatter", lambda: True)
         from galaxysql_tpu.exec import operators as ops
         with ops._JIT_CACHE_LOCK:
             ops._JIT_CACHE.clear()  # keyed alike under both formulations
-        count, attrs, _ = run("few", "fact")
+        count, attrs = run("few", "fact")
         assert count == int(np.isin(few["k"], fact["k"]).sum())
-        assert "dir_bits" not in attrs and not traced
+        assert not traced
     finally:
         s.close()
 
@@ -694,20 +792,28 @@ def _located(text):
             open_.append((op.group(0), line) if op else None)
 
 
+def _lowered_for_a_tpu(nb, npr, cap, debug_info=True, matched=False):
+    """The sorted join (or the pair-less probe) over one int64 key a side, as
+    the chip's compiler is handed it: lowered for a TPU here, no chip."""
+    def run(bk, pk, blive, plive):
+        if matched:
+            return K.hash_join_matched([(bk, None)], [(pk, None)], blive, plive)
+        return K._hash_join_pairs_sorted([(bk, None)], [(pk, None)], blive, plive, cap)
+
+    shapes = [jax.ShapeDtypeStruct((n,), t) for n, t in
+              ((nb, jnp.int64), (npr, jnp.int64), (nb, jnp.bool_), (npr, jnp.bool_))]
+    return jax.jit(run).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=debug_info)
+
+
 def test_expand_scope_gathers_no_64_bit_lane_and_nothing_runs_a_window():
     """What the chip's compiler is handed (lowered for a TPU here, no chip):
     inside `join_pairs/expand` no gather reads a 64-bit lane (two words a
     gathered element on the chip), and no running maximum or minimum came back
     as a reduce-window (33-40 s of compile a program, PERF.md PR 26): the only
-    windows are `jnp.cumsum`'s two running sums, which stood before."""
-    def run(bk, pk, blive, plive):
-        return K._hash_join_pairs_sorted([(bk, None)], [(pk, None)], blive, plive, 640)
-
-    shapes = [jax.ShapeDtypeStruct((n,), t) for n, t in
-              ((256, jnp.int64), (1000, jnp.int64), (256, jnp.bool_), (1000, jnp.bool_))]
-    text = jax.jit(run).trace(*shapes).lower(
-        lowering_platforms=("tpu",)).as_text(debug_info=True)
-    ops_ = list(_located(text))
+    windows are `jnp.cumsum`'s running sums: the lookup's count of build slots
+    along the merged lane, the pair offsets, `probe_matched_from`'s."""
+    ops_ = list(_located(_lowered_for_a_tpu(256, 1000, 640)))
     in_expand = [(op, line) for op, line, where in ops_ if "join_pairs/expand" in where]
     assert {"stablehlo.scatter", "stablehlo.gather", "stablehlo.while"} <= \
         {op for op, _ in in_expand}  # the scope is found, and is the expansion
@@ -719,35 +825,31 @@ def test_expand_scope_gathers_no_64_bit_lane_and_nothing_runs_a_window():
     assert not [line for op, line in in_expand if op == "stablehlo.scatter"
                 and not re.search(r"\}\) : \(tensor<\d+xi32>", line)]
     windows = [where for op, _, where in ops_ if op == "stablehlo.reduce_window"]
-    assert len(windows) == 2 and all("reduce_window_sum" in w for w in windows), windows
+    assert len(windows) == 3, windows
+    assert all("reduce_window_sum" in w for w in windows), windows
 
 
-def test_probe_scope_builds_the_narrow_directory_where_the_probe_is_small():
-    """Lowered for a TPU here, no chip: 1,048,576 build slots probed by 4,096.
-    The build side alone would take 17 bits, a directory of 131,073 full-depth
-    searches; the probe side's 9 make it 513, and nothing of 2^16 + 1 or
-    2^17 + 1 elements is left in `join_pairs/probe`.  With the sides swapped
-    the directory is the build side's own, as it was."""
-    def lowered(nb, npr):
-        def run(bk, pk, blive, plive):
-            return K._hash_join_pairs_sorted([(bk, None)], [(pk, None)], blive,
-                                             plive, 8192)
-        shapes = [jax.ShapeDtypeStruct((n,), t) for n, t in
-                  ((nb, jnp.int64), (npr, jnp.int64), (nb, jnp.bool_), (npr, jnp.bool_))]
-        text = jax.jit(run).trace(*shapes).lower(
-            lowering_platforms=("tpu",)).as_text(debug_info=True)
-        return [(op, line) for op, line, where in _located(text)
-                if "join_pairs/probe" in where]
-
-    def lanes_of(in_probe, elements):
-        return [line for _, line in in_probe if f"tensor<{elements}x" in line]
-
-    in_probe = lowered(1 << 20, 1 << 12)
-    assert {"stablehlo.while", "stablehlo.gather"} <= {op for op, _ in in_probe}
-    assert K.directory_bits(1 << 20, 1 << 12) == 9
-    assert lanes_of(in_probe, (1 << 9) + 1)
-    assert not lanes_of(in_probe, (1 << 17) + 1) and not lanes_of(in_probe, (1 << 16) + 1)
-
-    swapped = lowered(1 << 12, 1 << 20)
-    assert K.directory_bits(1 << 12, 1 << 20) == 9
-    assert lanes_of(swapped, (1 << 9) + 1)
+@pytest.mark.parametrize("matched", [False, True], ids=["pairs", "matched"])
+@pytest.mark.parametrize("nb,npr", [(4096, 65_536), (65_536, 4096)],
+                         ids=["probe_larger", "build_larger"])
+def test_probe_scope_sorts_twice_and_gathers_nothing(nb, npr, matched):
+    """Lowered for a TPU here, no chip, whichever side is the larger:
+    `join_pairs/probe` holds two sorts (the merged lane of 69,632 slots by
+    hash with its ids, and the sort back by id), the loop of the running
+    count, and NO gather or scatter, where a search gathers words by the probe
+    slot; `join_pairs/sort` holds the build side's `argsort` and gathers no
+    sorted hash back."""
+    ops_ = list(_located(_lowered_for_a_tpu(nb, npr, 1 << 17, matched=matched)))
+    in_probe = [(op, line) for op, line, where in ops_ if "join_pairs/probe" in where]
+    names = [op for op, _ in in_probe]
+    assert "stablehlo.gather" not in names and "stablehlo.scatter" not in names
+    assert "stablehlo.while" in names
+    sorts = [line for op, line in in_probe if op == "stablehlo.sort"]
+    assert len(sorts) == 2 and all(f"tensor<{nb + npr}x" in line for line in sorts)
+    assert all("is_stable = false" in line for line in sorts), sorts
+    assert "tensor<69632xui64>" in sorts[0] and "ui64" not in sorts[1]
+    # a third sort in the program, `argsort`'s (a function of its own in the
+    # text), whose lane is not gathered back
+    assert [op for op, _, _ in ops_].count("stablehlo.sort") == 3
+    assert "stablehlo.gather" not in [op for op, _, where in ops_
+                                      if "join_pairs/sort" in where]

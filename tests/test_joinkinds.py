@@ -267,11 +267,10 @@ def test_a_mostly_dead_probe_side_is_searched_in_the_bucket_its_live_rows_fill(
     keep = live & ((a % 2 == 0) == (kind == "semi"))
     assert got == sorted(((int(v),) for v in a[keep]), key=str)
     assert span.attrs["matched"] == int(keep.sum())
-    # 1,024 probe slots searched, not 8,192: the directory is sized from them
+    # 1,024 probe slots looked up and compared, not 8,192
     (key,) = [k for k in ops._JIT_CACHE if k[0] == "join_pairs"]
     assert key[-1] == 1024
-    assert span.attrs["dir_bits"] == K.directory_bits_note(16384, 1024)
-    # a probe side over half full is searched where it lies
+    # a probe side over half full is looked up where it lies
     dense = rng.random(8192) < 0.6
     rows_of(join_of(build, [batch("a", a, dense)], kind))
     assert sorted(str(k[-1]) for k in ops._JIT_CACHE
