@@ -288,6 +288,7 @@ def main():
         print(json.dumps({"phase": phase, **kv, **tag}), flush=True)
 
     from galaxysql_tpu.exec import operators as ops
+    from galaxysql_tpu.exec.programs import PROGRAMS
     from galaxysql_tpu.exec.device_cache import (GLOBAL_DEVICE_CACHE,
                                                  hbm_high_water)
     from galaxysql_tpu.parallel.mesh import GLOBAL_MESH_CACHE
@@ -462,8 +463,7 @@ def main():
             ops.COMPILE_STATS["compile_ms"] / 1000, 1),
         "compile_wall_s_by_program_observed": {
             k: {"programs": n, "wall_s": round(ms / 1000, 1)}
-            for k, (n, ms) in sorted(ops.COMPILE_MS_BY_PROGRAM.items(),
-                                     key=lambda kv: -kv[1][1])},
+            for k, (n, ms) in PROGRAMS.compile_ms_by_family().items()},
         "mpp_queries": inst.counters["mpp_queries"],
         "mpp_fallback_local": inst.counters["mpp_fallback_local"],
         "device_cache_bytes": GLOBAL_DEVICE_CACHE._bytes,

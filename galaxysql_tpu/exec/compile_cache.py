@@ -124,44 +124,22 @@ class CompileCache:
 
     # -- capture ------------------------------------------------------------
 
-    def observe(self, key: Tuple, f, args: tuple, kwargs: dict):
-        """Record a freshly compiled program's input signature for a later
-        flush().  Called from the hot first-invocation path: cheap, and bails
-        on anything it cannot describe (kwargs, non-array leaves)."""
-        if self._dir is None or kwargs:
-            return
-        if not hasattr(f, "lower"):
-            return  # host-np programs / plain closures: nothing to serialize
-        try:
-            import jax.numpy as jnp
-            if exec_platform() != jax.default_backend():
-                # ran under the TP path's CPU pin on an accelerator host:
-                # flush() would AOT-lower it for the accelerator instead
-                return
-            leaves, treedef = jax.tree_util.tree_flatten(args)
-            specs = []
-            for leaf in leaves:
-                if isinstance(leaf, (bool, int, float)):
-                    specs.append(leaf)
-                elif hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
-                    # carry the input sharding: a program whose steady-state
-                    # args are mesh-sharded (MPP scan segments) must be
-                    # AOT-lowered for that sharding or the restored
-                    # executable rejects every call
-                    sharding = getattr(leaf, "sharding", None)
-                    try:
-                        specs.append(jax.ShapeDtypeStruct(
-                            jnp.shape(leaf), leaf.dtype, sharding=sharding))
-                    except Exception:
-                        specs.append(jax.ShapeDtypeStruct(jnp.shape(leaf),
-                                                          leaf.dtype))
-                else:
-                    return
-        except Exception:
+    def observe(self, key: Tuple, f, signature: tuple):
+        """Record a freshly compiled program's input signature (`(treedef,
+        specs)` as `exec/programs.py:abstract_signature` makes it for the
+        registry: shapes, dtypes and the leaves' SHARDINGS, since a program
+        whose steady-state args are mesh-sharded must be AOT-lowered for that
+        sharding or the restored executable rejects every call) for a later
+        flush().  Called from the first-invocation path: cheap."""
+        if self._dir is None or not hasattr(f, "lower"):
+            return  # detached; host-np programs / plain closures
+        if exec_platform() != jax.default_backend():
+            # ran under the TP path's CPU pin on an accelerator host:
+            # flush() would AOT-lower it for the accelerator instead
             return
         with self._lock:
             if self._dir is not None:
-                self._observed[key] = (treedef, tuple(specs))
+                self._observed[key] = signature
 
     # -- restore ------------------------------------------------------------
 

@@ -24,6 +24,7 @@ from galaxysql_tpu.chunk.batch import (Column, ColumnBatch, Dictionary, concat_b
 from galaxysql_tpu.expr import ir
 from galaxysql_tpu.expr.compiler import ExprCompiler, batch_env, _find_dictionary, \
     _signed_div_round, _pow10
+from galaxysql_tpu.exec.programs import PROGRAMS
 from galaxysql_tpu.exec.runtime_filter import RF_STATS
 from galaxysql_tpu.kernels import relational as K
 from galaxysql_tpu.runtime import exec_platform
@@ -74,10 +75,6 @@ DISPATCH_STATS = {"dispatches": 0}
 # compiles_in_window.py read them, the statement summary snapshots them per
 # query, and traced queries get one `compile` span per event.
 COMPILE_STATS = {"retraces": 0, "compile_ms": 0.0, "cache_hits": 0}
-# the same first-invocation wall time split by program family (the key's
-# first element: agg_partial, join_pairs, mpp_agg, ...) with the number of
-# programs behind it — which family a cold start's compile seconds belong to
-COMPILE_MS_BY_PROGRAM: Dict[str, List[float]] = {}
 
 
 # the sorted (TPU) join, one add per probe batch: `probes`, and where pairs
@@ -126,7 +123,6 @@ def reset_compile_stats():
     COMPILE_STATS["retraces"] = 0
     COMPILE_STATS["compile_ms"] = 0.0
     COMPILE_STATS["cache_hits"] = 0
-    COMPILE_MS_BY_PROGRAM.clear()
 
 
 def program_family(key) -> str:
@@ -172,11 +168,13 @@ def _family_scoped(key, builder):
 
 def _timed_first_call(key, f, persist=True):
     """Wrap a freshly built program so its first invocation — where jax pays
-    the synchronous trace+compile — is timed into COMPILE_STATS and, when a
-    query is being traced, recorded as a `compile` span attributed to the
-    active span.  After the first call the bare program is swapped back into
-    _JIT_CACHE so steady-state dispatches pay no wrapper frame; callers still
-    holding the wrapper degrade to a single cell-load per call."""
+    the synchronous trace+compile — is timed into COMPILE_STATS, recorded in
+    the program registry (`exec/programs.py`: family, key digest, the call's
+    abstract signature, the span that launched it) and, when a query is
+    being traced, recorded as a `compile` span attributed to the active span.
+    After the first call the bare program is swapped back into _JIT_CACHE so
+    steady-state dispatches pay no wrapper frame; callers still holding the
+    wrapper degrade to a single cell-load per call."""
     import time as _t
     cell = [None]
     family = program_family(key)
@@ -187,6 +185,7 @@ def _timed_first_call(key, f, persist=True):
             return inner(*a, **k)
         from galaxysql_tpu.utils import tracing as _tr
         tc = _tr.current()
+        launcher = tc.span_at_cursor() if tc is not None else None
         # while a profiler session records the statement, the trace+compile
         # is a real span in both trees; otherwise an event after the fact
         sp = tc.begin(f"compile:{family}", kind="compile") \
@@ -203,19 +202,22 @@ def _timed_first_call(key, f, persist=True):
             if _JIT_CACHE.get(key) is wrapper:
                 _JIT_CACHE[key] = f
         COMPILE_STATS["compile_ms"] += dt_ms
-        fam = COMPILE_MS_BY_PROGRAM.setdefault(family, [0, 0.0])
-        fam[0] += 1
-        fam[1] += dt_ms
-        if persist and not k:
-            # record the input signature so Instance.save can AOT-serialize
-            # this program into the persistent compile cache (no-op detached)
+        program = PROGRAMS.record(
+            key, family, a, k, dt_ms,
+            unsigned="" if persist and hasattr(f, "lower") else "a host closure",
+            span=_tr._annotation_name(launcher.name, launcher.kind)
+            if launcher is not None else "",
+            trace_id=tc.trace_id if tc is not None else 0)
+        if persist and program.signature is not None:
+            # the same signature lets Instance.save AOT-serialize this
+            # program into the persistent compile cache (no-op detached)
             from galaxysql_tpu.exec import compile_cache as _cc
-            _cc.GLOBAL_COMPILE_CACHE.observe(key, f, a, k)
+            _cc.GLOBAL_COMPILE_CACHE.observe(key, f, program.signature)
         if sp is not None:
-            sp.attrs["wall_ms"] = round(dt_ms, 3)
+            sp.attrs.update(wall_ms=round(dt_ms, 3), program=program.digest)
         elif tc is not None:
             tc.event(f"compile:{family}", kind="compile",
-                     wall_ms=round(dt_ms, 3))
+                     wall_ms=round(dt_ms, 3), program=program.digest)
         return out
 
     return wrapper
@@ -255,9 +257,10 @@ def global_jit(key: Tuple, builder, built_flag=None, persist=True):
             if f is not None:
                 with _JIT_CACHE_LOCK:
                     if key not in _JIT_CACHE:
-                        while len(_JIT_CACHE) >= _JIT_CACHE_LIMIT:
-                            _JIT_CACHE.popitem(last=False)
+                        _make_room()
                         _JIT_CACHE[key] = f
+                        PROGRAMS.record(key, program_family(key), (), {}, 0.0,
+                                        unsigned="restored ahead of time")
                     else:
                         f = _JIT_CACHE[key]
                     _JIT_CACHE.move_to_end(key)
@@ -275,11 +278,17 @@ def global_jit(key: Tuple, builder, built_flag=None, persist=True):
         built_flag()
     with _JIT_CACHE_LOCK:
         if key not in _JIT_CACHE:
-            while len(_JIT_CACHE) >= _JIT_CACHE_LIMIT:
-                _JIT_CACHE.popitem(last=False)
+            _make_room()
         _JIT_CACHE[key] = f
         _JIT_CACHE.move_to_end(key)
     return f
+
+
+def _make_room():
+    """Evict the oldest programs (and their registry entries) until one more
+    fits; the caller holds `_JIT_CACHE_LOCK`."""
+    while len(_JIT_CACHE) >= _JIT_CACHE_LIMIT:
+        PROGRAMS.evict(_JIT_CACHE.popitem(last=False)[0])
 
 
 def _dict_sig(e: ir.Expr) -> Tuple:

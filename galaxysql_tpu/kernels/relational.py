@@ -605,7 +605,8 @@ def groupby(keys, inputs, specs, live, max_groups, domains=None):
             s.kind in ("sum", "sum_float") and s.arg >= 0 and
             jnp.issubdtype(inputs[s.arg][0].dtype, jnp.floating) for s in specs)
         if not float_sum:
-            return matmul_groupby(keys, inputs, specs, live, domains)
+            with jax.named_scope("groupby/matmul"):
+                return matmul_groupby(keys, inputs, specs, live, domains)
     if prefer_scatter():
         return hash_groupby(keys, inputs, specs, live, max_groups)
     return sort_groupby(keys, inputs, specs, live, max_groups)

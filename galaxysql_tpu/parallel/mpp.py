@@ -199,23 +199,33 @@ def _join_block(benv, blive, penv, plive, bk, pk, kind, residual_pred, cap,
     pairs = pairs_fn(bkeys, pkeys, blive, plive, cap)
     over = pairs.overflow
 
-    bcols = {i: (benv[i][0][pairs.build_idx],
-                 None if benv[i][1] is None else benv[i][1][pairs.build_idx])
-             for i in build_ids}
-    pcols = {i: (penv[i][0][pairs.probe_idx],
-                 None if penv[i][1] is None else penv[i][1][pairs.probe_idx])
-             for i in probe_ids}
-    live = pairs.live
-    if residual_pred is not None:
-        live = live & residual_pred({**bcols, **pcols})
+    # what follows the pairs is named for a device profile (`exec/programs.py`
+    # STAGES): both sides' columns gathered at the pair slots and the
+    # residual on them, then which probe rows kept a pair
+    with jax.named_scope("join_block/gather"):
+        bcols = {i: (benv[i][0][pairs.build_idx],
+                     None if benv[i][1] is None
+                     else benv[i][1][pairs.build_idx])
+                 for i in build_ids}
+        pcols = {i: (penv[i][0][pairs.probe_idx],
+                     None if penv[i][1] is None
+                     else penv[i][1][pairs.probe_idx])
+                 for i in probe_ids}
+        live = pairs.live
+        if residual_pred is not None:
+            live = live & residual_pred({**bcols, **pcols})
 
     if kind in ("semi", "anti"):
-        matched = K.probe_matched_from(live, pairs.probe_starts, pairs.probe_offsets)
-        out_live = plive & (matched if kind == "semi" else ~matched)
+        with jax.named_scope("join_block/matched"):
+            matched = K.probe_matched_from(live, pairs.probe_starts,
+                                           pairs.probe_offsets)
+            out_live = plive & (matched if kind == "semi" else ~matched)
         return ({i: penv[i] for i in probe_ids}, out_live), over
 
     if kind == "left":
-        matched = K.probe_matched_from(live, pairs.probe_starts, pairs.probe_offsets)
+        with jax.named_scope("join_block/matched"):
+            matched = K.probe_matched_from(live, pairs.probe_starts,
+                                           pairs.probe_offsets)
         unmatched = plive & ~matched
         out = {}
         for i in build_ids:

@@ -74,6 +74,33 @@ def _profile_rows(inst):
         rows)
 
 
+def _program_rows(stmt: ast.Show):
+    """SHOW [FULL] PROGRAMS [LIKE family]: one row a `global_jit` program this
+    process built (`exec/programs.py`).  `Stages` counts the compiled
+    module's instructions by stage once something asked for them; FULL asks
+    (it lowers each program again from its signature, off any statement's
+    path but this one's)."""
+    import collections
+    from galaxysql_tpu.exec.programs import PROGRAMS
+    from galaxysql_tpu.server.session import ResultSet
+    rows = []
+    for p in PROGRAMS.entries():
+        if stmt.like and not _like_filter([p.family], stmt.like):
+            continue
+        if stmt.full:
+            PROGRAMS.stages(p)
+        by_stage = collections.Counter(p.stages.values()) if p.stages else {}
+        rows.append((p.family, p.digest, "x".join(str(n) for n in p.slots),
+                     round(p.first_call_ms, 3), p.span, p.trace_id,
+                     " ".join(f"{s}:{n}" for s, n in sorted(by_stage.items()))
+                     or p.unstaged or p.unsigned))
+    return ResultSet(
+        ["Family", "Program", "Slots", "First_call_ms", "Span", "Trace_id",
+         "Stages"],
+        [dt.VARCHAR, dt.VARCHAR, dt.VARCHAR, dt.DOUBLE, dt.VARCHAR, dt.BIGINT,
+         dt.VARCHAR], rows)
+
+
 def handle(session, stmt: ast.Show):
     from galaxysql_tpu.server.session import ResultSet
 
@@ -412,6 +439,8 @@ def handle(session, stmt: ast.Show):
                          rows)
     if kind == "profiles":
         return _profile_rows(inst)
+    if kind == "programs":
+        return _program_rows(stmt)
     if kind == "ccl_rules":
         from galaxysql_tpu.utils.ccl import GLOBAL_CCL
         rows = []

@@ -5,6 +5,7 @@ Mirrors the reference's strategy of testing cluster behavior without a cluster
 xla_force_host_platform_device_count=8 virtual devices.
 """
 
+import contextlib
 import os
 
 flags = os.environ.get("XLA_FLAGS", "")
@@ -34,6 +35,7 @@ def _clear_compiled_caches():
     from galaxysql_tpu.exec import operators as _ops
     with _ops._JIT_CACHE_LOCK:
         _ops._JIT_CACHE.clear()
+    _ops.PROGRAMS._programs.clear()
     from galaxysql_tpu.exec.device_cache import GLOBAL_DEVICE_CACHE
     GLOBAL_DEVICE_CACHE.clear()
     from galaxysql_tpu.parallel.mesh import GLOBAL_MESH_CACHE
@@ -42,20 +44,41 @@ def _clear_compiled_caches():
     jax.clear_caches()
 
 
-@pytest.fixture
-def chip_formulation(monkeypatch):
-    """The formulations a TPU traces (`K.prefer_scatter` false: sort group-by,
-    sorted join), for one test on this CPU.  Programs are keyed alike under
-    either formulation, so `_JIT_CACHE` is emptied for the test and put back
-    after it: none built under the patch outlives it, none built before it is
-    taken for the chip's."""
+@contextlib.contextmanager
+def _chip_formulations():
     from galaxysql_tpu.exec import operators as ops
     from galaxysql_tpu.kernels import relational as K
-    monkeypatch.setattr(K, "prefer_scatter", lambda: False)
+    real, K.prefer_scatter = K.prefer_scatter, lambda: False
     with ops._JIT_CACHE_LOCK:
         saved = dict(ops._JIT_CACHE)
         ops._JIT_CACHE.clear()
-    yield
-    with ops._JIT_CACHE_LOCK:
-        ops._JIT_CACHE.clear()
-        ops._JIT_CACHE.update(saved)
+    # the registry describes the programs of `_JIT_CACHE`, key for key
+    described = dict(ops.PROGRAMS._programs)
+    ops.PROGRAMS._programs.clear()
+    try:
+        yield
+    finally:
+        K.prefer_scatter = real
+        with ops._JIT_CACHE_LOCK:
+            ops._JIT_CACHE.clear()
+            ops._JIT_CACHE.update(saved)
+        ops.PROGRAMS._programs.clear()
+        ops.PROGRAMS._programs.update(described)
+
+
+@pytest.fixture
+def chip_formulation():
+    """The formulations a TPU traces (`K.prefer_scatter` false: sort group-by,
+    sorted join), for one test on this CPU.  Programs are keyed alike under
+    either formulation, so `_JIT_CACHE` (and the registry that describes it)
+    is emptied for the test and put back after it: none built under the patch
+    outlives it, none built before it is taken for the chip's."""
+    with _chip_formulations():
+        yield
+
+
+@pytest.fixture(scope="module")
+def chip_formulation_module():
+    """`chip_formulation` for a module whose fixture builds the programs."""
+    with _chip_formulations():
+        yield
