@@ -1238,33 +1238,15 @@ class MppExecutor:
                             live_x, over)
 
                 def compact_hot(env, hot_mask, ids, q):
-                    """Compact rows under `hot_mask` into a [q] lane env.
-                    Backend-adaptive, same stance as the join kernels:
-                    scatter-by-rank on CPU (XLA:CPU comparator sorts are
-                    ~100x slower than its scatters), argsort on TPU
-                    (scatters serialize there)."""
-                    over = jnp.sum(hot_mask.astype(jnp.int32)) > q
-                    if K.prefer_scatter():
-                        rank = jnp.cumsum(hot_mask.astype(jnp.int64)) - 1
-                        pos = jnp.where(hot_mask, rank, jnp.int64(q))
-
-                        def compact(lane):
-                            return jnp.zeros(q, lane.dtype).at[pos].set(
-                                lane, mode="drop")
-                        clive = jnp.zeros(q, jnp.bool_).at[pos].set(
-                            hot_mask, mode="drop")
-                    else:
-                        order = jnp.argsort(~hot_mask, stable=True)[:q]
-
-                        def compact(lane):
-                            return lane[order]
-                        clive = hot_mask[order]
-                    out = {}
-                    for i in ids:
-                        d, v = env[i]
-                        out[i] = (compact(d),
-                                  None if v is None else compact(v))
-                    return out, clive, over
+                    """The rows under `hot_mask` at the front of a [q] lane
+                    env (`exchange.compact_rows`, on every platform), and
+                    whether there were more than `q` of them: the first `q`
+                    are kept."""
+                    pairs = [env[i] for i in ids]
+                    lanes, clive = exchange.compact_rows(
+                        _pack_lanes(pairs), hot_mask, q)
+                    over = jnp.sum(hot_mask, dtype=jnp.int32) > q
+                    return dict(zip(ids, _unpack_lanes(lanes, pairs))), clive, over
 
                 def broadcast_hot(env, hot_mask, ids, costs):
                     # compact hot rows to _hq slots, then replicate
@@ -1391,7 +1373,9 @@ class MppExecutor:
             attrs["matched"] = int(out_rows.sum())  # probe rows kept
         for name, side in (("compact_b", build), ("compact_p", probe)):
             if side.compacted is not None:
-                attrs[name] = "%d/%d" % side.compacted  # slots a shard, in/out
+                # slots a shard, in/out, and how the kept ones were found
+                attrs[name] = "%d/%d:%s" % (
+                    *side.compacted, exchange.compact_path(*side.compacted))
         src_meta = {fid: (typ, d)
                     for fid, typ, d in (node.left.fields() + node.right.fields())}
         out_cols = {}

@@ -5,14 +5,19 @@ overflow retry, the compaction of a join's sides to their live rows,
 `stage:Join` span attributes in SHOW TRACE, and the traced run's host
 transfers."""
 
+import re
+
 import numpy as np
 import pandas as pd
 import pytest
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from galaxysql_tpu.chunk.batch import Column
 from galaxysql_tpu.exec.operators import bucket_capacity
+from galaxysql_tpu.parallel import exchange
 from galaxysql_tpu.parallel import mpp as M
 from galaxysql_tpu.parallel.mesh import make_mesh, shard_bucket
 from galaxysql_tpu.plan import logical as L
@@ -173,8 +178,11 @@ def test_q3_shuffle_from_a_quota_that_overflows_retries_and_equals_pandas(
 
 
 def slots_in_out(attr):
-    slots_in, slots_out = attr.split("/")
-    return int(slots_in), int(slots_out)
+    """`<slots in>/<slots out>:<how the kept slots were found>`"""
+    slots, path = attr.split(":")
+    slots_in, slots_out = map(int, slots.split("/"))
+    assert path == exchange.compact_path(slots_in, slots_out)
+    return slots_in, slots_out
 
 
 def most_rows_a_shard(spans, stage):
@@ -238,6 +246,13 @@ def live_mask(case):
         live[2] = False
     elif case == "one_row_in_the_last_slot":
         live[S - 1, R_SLOTS - 1] = True
+    elif case == "half_the_slots_to_the_last":
+        # rows == n / 2 and shard 1 fills every one of them, its last live
+        # row in the shard's last slot: the deepest the old search went
+        live[:] = rng.random((S, R_SLOTS)) < 0.3
+        live[1] = False
+        live[1, rng.permutation(R_SLOTS - 1)[:R_SLOTS // 2 - 1]] = True
+        live[1, R_SLOTS - 1] = True
     elif case.startswith("random_"):
         live[:] = rng.random((S, R_SLOTS)) < int(case[7:]) / 100
     else:
@@ -271,7 +286,7 @@ def rows_by_shard(batch):
 @pytest.mark.parametrize("nullable", [False, True], ids=["not_null", "nulls"])
 @pytest.mark.parametrize("case", [
     "all_live", "all_dead", "one_shard_empty", "one_row_in_the_last_slot",
-    "random_1", "random_50"])
+    "half_the_slots_to_the_last", "random_1", "random_50"])
 def test_compaction_keeps_rows_order_and_nulls(case, nullable):
     live = live_mask(case)
     batch = hand_made(live, nullable)
@@ -294,7 +309,69 @@ def test_compaction_keeps_rows_order_and_nulls(case, nullable):
     # live rows first on every shard: the slots behind them are dead
     packed = np.asarray(out.live).reshape(S, rows)
     assert all(not packed[s, int(packed[s].sum()):].any() for s in range(S))
+    # and they are dead in every lane: zeros, in a validity lane too
+    for col in out.columns.values():
+        for lane in (col.data, col.valid):
+            if lane is not None:
+                assert not np.asarray(lane).reshape(S, rows)[~packed].any()
+    if case == "half_the_slots_to_the_last":
+        assert rows == R_SLOTS // 2 and packed[1].all()
     assert delta["compactions"] == 1 and not delta["overflow_retries"]
+
+
+@pytest.mark.parametrize("rows,path", [
+    (1024, "search"), (2048, "scatter"), (512, "search"), (32768, "scatter")])
+@pytest.mark.parametrize("nullable", [False, True], ids=["not_null", "nulls"])
+def test_compact_rows_either_side_of_the_crossover(rows, path, nullable):
+    """`compact_path` by hand: 65,536 slots kept in 1,024 (a 64th: searched
+    for) and in 2,048 (scattered) are the same rows in the same order, the
+    slots behind them dead and zero; the search is a loop in the lowered text
+    and no scatter, the scatter no loop."""
+    n = 65536
+    assert exchange.compact_path(n, rows) == path
+    rng = np.random.default_rng(36)
+    live = np.zeros(n, np.bool_)
+    live[rng.permutation(n - 1)[:500]] = True
+    live[n - 1] = True                      # the last slot holds a live row
+    lanes = [np.arange(1, n + 1, dtype=np.int64),
+             rng.integers(1, 99, n).astype(np.int32)]
+    if nullable:
+        lanes.append(rng.random(n) < 0.5)
+    fn = jax.jit(lambda la, lv: exchange.compact_rows(la, lv, rows))
+    args = ([jax.numpy.asarray(x) for x in lanes], jax.numpy.asarray(live))
+    out, keep = fn(*args)
+    assert list(np.asarray(keep)) == [True] * 501 + [False] * (rows - 501)
+    for x, y in zip(lanes, out):
+        want = np.zeros(rows, x.dtype)
+        want[:501] = x[live]
+        assert (np.asarray(y) == want).all()
+    text = fn.lower(*args).as_text()
+    assert ("stablehlo.while" in text) == (path == "search")
+    assert ("stablehlo.scatter" in text) == (path == "scatter")
+
+
+@pytest.mark.parametrize("rows,case", [
+    (64, "more_slots_than_there_are"), (20, "as_many_as_live"),
+    (7, "fewer_than_live")])
+def test_compact_rows_alone_keeps_the_first_rows_that_fit(rows, case):
+    """What the hybrid join's `compact_hot` leans on: a quota under the live
+    count keeps the first rows in slot order (its caller reads the flag and
+    climbs), one over the slots there are is padded with dead slots."""
+    rng = np.random.default_rng(5)
+    n = 32
+    live = np.zeros(n, np.bool_)
+    live[rng.permutation(n)[:20]] = True
+    k = np.arange(1, n + 1, dtype=np.int64)
+    valid = rng.random(n) < 0.5
+    (k_out, valid_out), keep = jax.jit(
+        lambda la, lv: exchange.compact_rows(la, lv, rows))(
+            [jax.numpy.asarray(k), jax.numpy.asarray(valid)],
+            jax.numpy.asarray(live))
+    kept = min(20, rows)
+    assert list(np.asarray(keep)) == [True] * kept + [False] * (rows - kept)
+    assert list(np.asarray(k_out)) == list(k[live][:kept]) + [0] * (rows - kept)
+    assert list(np.asarray(valid_out)) == \
+        list(valid[live][:kept]) + [False] * (rows - kept)
 
 
 def test_exchange_stats_of_one_compaction_by_hand():
@@ -313,6 +390,143 @@ def test_exchange_stats_of_one_compaction_by_hand():
     assert delta.pop("compact_slots_in") == S * 4096
     assert delta.pop("compact_slots_out") == S * 1024
     assert not any(delta.values())      # no exchange, no statement
+
+
+# -- the repartition alone, against NumPy -------------------------------------------
+
+
+R_PART = 512        # slots a shard of the repartitioned side
+
+
+def destinations(case):
+    """(dest [S, R] of every slot, live [S, R], quota) of a case."""
+    rng = np.random.default_rng(36)
+    dest = rng.integers(0, S, (S, R_PART))
+    live = rng.random((S, R_PART)) < 0.8
+    quota = 2 * R_PART // S
+    if case == "one_destination":
+        dest[:] = 2
+        quota = R_PART
+    elif case == "one_destination_empty":
+        dest[dest == 1] = 3
+    elif case == "all_dead":
+        live[:] = False
+    elif case in ("quota_met_exactly", "quota_one_short"):
+        fullest = max(int((live[s] & (dest[s] == d)).sum())
+                      for s in range(S) for d in range(S))
+        quota = fullest - (case == "quota_one_short")
+    else:
+        assert case == "uniform"
+    return dest, live, quota
+
+
+def repartitioned(lanes, live, hashes, quota):
+    """`exchange.repartition_by_hash` under `shard_map` on four devices:
+    (lanes [S, S * quota], live [S, S * quota], overflow [S])."""
+
+    def spmd(lanes, live, hashes):
+        out, live_x, over = exchange.repartition_by_hash(lanes, live, hashes,
+                                                         quota)
+        return out, live_x, over.reshape(1)
+
+    fn = jax.jit(M.shard_map(spmd, mesh=make_mesh(S), in_specs=P("shard"),
+                           out_specs=P("shard"), check_vma=False))
+    out, live_x, over = fn(lanes, live, hashes)
+    return ([np.asarray(x).reshape(S, -1) for x in out],
+            np.asarray(live_x).reshape(S, -1), np.asarray(over))
+
+
+@pytest.mark.parametrize("nullable", [False, True], ids=["not_null", "nulls"])
+@pytest.mark.parametrize("case", [
+    "uniform", "one_destination", "one_destination_empty", "all_dead",
+    "quota_met_exactly", "quota_one_short"])
+def test_repartition_buckets_hold_their_rows_in_slot_order(case, nullable):
+    dest, live, quota = destinations(case)
+    rng = np.random.default_rng(8)
+    # the destination is read from the hash's HIGH word; the low word is noise
+    hashes = (dest.astype(np.uint64) + S * rng.integers(0, 1 << 20, dest.shape)
+              .astype(np.uint64)) << np.uint64(32) | \
+        rng.integers(0, 1 << 32, dest.shape).astype(np.uint64)
+    k = np.arange(S * R_PART, dtype=np.int64).reshape(S, R_PART) + 1
+    v = rng.integers(1, 99, (S, R_PART)).astype(np.int32)
+    lanes = [k, v] + ([rng.random((S, R_PART)) < 0.7] if nullable else [])
+    got, live_x, over = repartitioned(
+        [jax.numpy.asarray(x.reshape(-1)) for x in lanes],
+        jax.numpy.asarray(live.reshape(-1)),
+        jax.numpy.asarray(hashes.reshape(-1)), quota)
+    assert live_x.shape == (S, S * quota)
+    for d in range(S):
+        for s in range(S):
+            sent = live[s] & (dest[s] == d)         # in source slot order
+            kept = min(int(sent.sum()), quota)
+            bucket = slice(s * quota, (s + 1) * quota)
+            # the live slots of a bucket are its first `kept`, no other
+            assert list(live_x[d, bucket]) == [True] * kept + \
+                [False] * (quota - kept), (case, s, d)
+            for lane, moved in zip(lanes, got):
+                assert list(moved[d, bucket][:kept]) == \
+                    list(lane[s][sent][:kept]), (case, s, d)
+                assert not moved[d, bucket][kept:].any()    # dead rows nowhere
+    counts = np.array([[int((live[s] & (dest[s] == d)).sum()) for d in range(S)]
+                       for s in range(S)])
+    assert list(over) == list((counts > quota).any(axis=1))
+    assert over.any() == (case == "quota_one_short")
+    if case == "quota_met_exactly":
+        assert counts.max() == quota
+    assert int(live_x.sum()) == int(np.minimum(counts, quota).sum())
+
+
+def lowered_for_a_tpu(fn, *shapes):
+    """`fn`'s per-shard body as the chip's compiler is handed it, under
+    `shard_map` on four devices: lowered for a TPU here, no chip."""
+    spmd = M.shard_map(fn, mesh=make_mesh(S), in_specs=P("shard"),
+                     out_specs=P("shard"), check_vma=False)
+    return jax.jit(spmd).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def compacted(lanes, live, _hashes):
+    return exchange.compact_rows(lanes, live, 2048)
+
+
+def dealt(lanes, live, hashes):
+    out, live_x, over = exchange.repartition_by_hash(lanes, live, hashes, 2048)
+    return out, live_x, over.reshape(1)
+
+
+@pytest.mark.parametrize("body,scope", [
+    (compacted, "exchange/compact"), (dealt, "exchange/repartition")],
+    ids=["compact", "repartition"])
+def test_a_scope_as_lowered_searches_and_sorts_nothing(body, scope):
+    """Lowered for a TPU here, no chip: no loop (a search is one) and no sort
+    anywhere in the program; every scatter writes 32-bit words (or flags) to
+    32-bit indices.  `exchange/compact`: ONE scatter, of the slot ids, and a
+    gather a lane.  `exchange/repartition`: no gather; a scatter a 32-bit
+    lane, two for the 64-bit one, none for `live` (written from the counts);
+    an `all_to_all` a lane and one for `live`; a running count a destination
+    (each a call of the ONE `cumsum` in the text)."""
+    from test_join_probe_ranges import _located
+    lanes = [jax.ShapeDtypeStruct((S * 4096,), t)
+             for t in (jnp.int64, jnp.int32, jnp.int32, jnp.bool_)]
+    ops_ = list(_located(lowered_for_a_tpu(
+        body, lanes, jax.ShapeDtypeStruct((S * 4096,), jnp.bool_),
+        jax.ShapeDtypeStruct((S * 4096,), jnp.uint64))))
+    inside = [(op, line) for op, line, where in ops_ if scope in where]
+    names = [op for op, _ in inside]
+    every = [op for op, _, _ in ops_]
+    assert not {"stablehlo.while", "stablehlo.sort"} & set(every)
+    assert every.count("stablehlo.reduce_window") == 1
+    scatters = [re.search(r"\}\) : \(tensor<\d+x(\w+)>, tensor<\d+x1x(\w+)>", line)
+                .groups() for op, line in inside if op == "stablehlo.scatter"]
+    if scope == "exchange/compact":
+        assert scatters == [("i32", "i32")]
+        assert names.count("stablehlo.gather") == len(lanes)
+        assert "stablehlo.all_to_all" not in names
+    else:
+        assert sorted(scatters) == sorted(
+            [("ui32", "i32")] * 2 + [("i32", "i32")] * 2 + [("i1", "i32")])
+        assert "stablehlo.gather" not in every
+        assert names.count("stablehlo.all_to_all") == len(lanes) + 1
 
 
 # -- EXCHANGE_STATS against a count by hand ----------------------------------------
