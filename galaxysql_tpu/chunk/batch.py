@@ -99,6 +99,35 @@ class Dictionary:
         return np.argsort(np.array(self.values, dtype=object), kind="stable").astype(np.int32)
 
 
+class EncodedStrings:
+    """A string column handed over already encoded: `values[codes[i]]` is row
+    i's string, a negative code a NULL.  The bulk load's input where a column's
+    domain is small (`TableStore.insert_arrays` maps the codes through one
+    table of `len(values)` entries); `len()`, iteration, `tolist()` and
+    `np.asarray()` answer with the strings themselves."""
+
+    __slots__ = ("codes", "values")
+
+    def __init__(self, codes, values):
+        self.codes = np.asarray(codes)
+        self.values = np.asarray(values, dtype=str)
+
+    def __len__(self) -> int:
+        return int(self.codes.shape[0])
+
+    def __array__(self, dtype=None, copy=None):
+        out = self.values.take(self.codes, mode="clip")
+        return out if dtype is None else out.astype(dtype)
+
+    def tolist(self) -> List[Optional[str]]:
+        out = self.values.astype(object)[self.codes]
+        out[self.codes < 0] = None
+        return out.tolist()
+
+    def __iter__(self):
+        return iter(self.tolist())
+
+
 def dictionary_translation(target: Dictionary, source: Dictionary) -> np.ndarray:
     """trans[source_code] = target_code (or -1 when the string is absent from target).
 

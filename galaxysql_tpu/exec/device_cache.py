@@ -92,6 +92,10 @@ class DeviceCache:
         self._metrics_refs: list = []
         self.hits = 0
         self.misses = 0
+        # lanes dropped to stay inside the budget (a later scan uploads them
+        # again), and their bytes
+        self.evictions = 0
+        self.evicted_bytes = 0
 
     def bind_metrics(self, registry):
         """Surface hits/misses/bytes through a typed MetricsRegistry
@@ -118,6 +122,9 @@ class DeviceCache:
                     "device lane cache resident bytes").set(self._bytes)
             m.gauge("device_cache_entries",
                     "device lane cache entries").set(len(self._map))
+            m.gauge("device_cache_evictions",
+                    "device lane cache lanes evicted over budget"
+                    ).set(self.evictions)
         self._metrics_refs = live
 
     def _lookup_or_claim(self, key: Key):
@@ -172,7 +179,10 @@ class DeviceCache:
                 self._bytes += nbytes
                 while self._bytes > self.budget and len(self._map) > 1:
                     _, old = self._map.popitem(last=False)
-                    self._bytes -= old.nbytes if hasattr(old, "nbytes") else 0
+                    old_bytes = old.nbytes if hasattr(old, "nbytes") else 0
+                    self._bytes -= old_bytes
+                    self.evictions += 1
+                    self.evicted_bytes += old_bytes
         finally:
             with self._lock:
                 self._building.pop(key, None)

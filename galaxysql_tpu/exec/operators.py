@@ -1362,7 +1362,14 @@ class HashJoinOp(Operator):
             return self._build_bloom_device(build_batch, pf)
         from galaxysql_tpu import native
         n_build = build_batch.num_live()
-        if n_build == 0 or n_build > self.BLOOM_MAX_BUILD:
+        # `BLOOM_MAX_BUILD` keeps a large side's lanes from crossing the host
+        # link for a filter; a side `_materialize_build` compacted on the host
+        # is there already, and without its filter the pair capacity starts
+        # from every live probe row (at SF10 Q3's 1.5M orders under 60M rows
+        # of `lineitem`: 134M pair slots for 0.3M pairs)
+        on_host = all(isinstance(c.data, np.ndarray)
+                      for c in build_batch.columns.values())
+        if n_build == 0 or (n_build > self.BLOOM_MAX_BUILD and not on_host):
             return None
         be = self.build_keys[0]
         benv = {n: (c.np_data(), None if c.valid is None else c.np_valid())
@@ -1872,6 +1879,14 @@ class HashJoinOp(Operator):
             for b in build_iter:
                 build_parts.append(b)
                 build_bytes += _batch_bytes(b)
+                if build_bytes > self.spill_threshold:
+                    # what the side will hold once materialized: its live
+                    # rows (a side gathered out of an upstream join at its
+                    # pair capacity is mostly dead slots: at SF10 Q5's 2.3M
+                    # orders of a year arrive in 33.5M)
+                    build_bytes = sum(
+                        _batch_bytes(p) * p.num_live() // max(p.capacity, 1)
+                        for p in build_parts)
                 if build_bytes > self.spill_threshold or \
                         not charge.to(build_bytes) or charge.squeeze:
                     # grace spill: the build never materializes in one

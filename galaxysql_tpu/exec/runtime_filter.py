@@ -47,11 +47,17 @@ import numpy as np
 
 RF_MIN_PROBE_ROWS = 8192        # probe below this is already cheap: no filter
 RF_MAX_SELECTIVITY = 0.75       # filter passing more than this prunes nothing
-RF_BLOOM_MAX_BUILD = 1 << 20    # bloom kind only below this build cardinality
-RF_BLOOM_MIN_BITS = 1 << 12
-RF_BLOOM_MAX_BITS = 1 << 22     # 4MB flags ceiling (host build + device arg)
-RF_IN_LIST_MAX = 256            # small builds additionally ship an IN-list
 RF_PUBLISH_MAX_ROWS = 1 << 22   # LIVE build rows above this skip publishing
+# the bloom kind goes with every build side that publishes at all: at SF10
+# TPC-H Q5's 2.3M orders of a year are what prunes `lineitem` at its scan, and
+# without them the two joins above carry 25M pair slots each where 5M do
+RF_BLOOM_MAX_BUILD = RF_PUBLISH_MAX_ROWS
+RF_BLOOM_MIN_BITS = 1 << 12
+# flags ceiling (host build + device arg): 4MB up to a million build rows,
+# which is four bits a key there, and four bits a key above that
+RF_BLOOM_MAX_BITS = 1 << 22
+RF_BLOOM_MIN_BITS_A_KEY = 4
+RF_IN_LIST_MAX = 256            # small builds additionally ship an IN-list
 RF_PUBLISH_MAX_LANES = RF_PUBLISH_MAX_ROWS * 4  # transfer-size bail-out:
 # a padded/mostly-dead build keeps its filter as long as the key-lane
 # transfer stays bounded; above this even the transfer is not worth it
@@ -171,7 +177,9 @@ class RuntimeFilter:
         if "bloom" in kinds and n <= RF_BLOOM_MAX_BUILD:
             nbits = 1 << max(RF_BLOOM_MIN_BITS.bit_length() - 1,
                              int(n * 16 - 1).bit_length())  # ~16 bits/key
-            nbits = min(nbits, RF_BLOOM_MAX_BITS)
+            nbits = min(nbits, max(
+                RF_BLOOM_MAX_BITS,
+                1 << int(n * RF_BLOOM_MIN_BITS_A_KEY - 1).bit_length()))
             flags = _bloom_flags(keys, nbits)
         # the IN-list is exact membership — the bloom family: honoring the
         # RUNTIME_FILTER(MINMAX) hint means no membership pushdown either

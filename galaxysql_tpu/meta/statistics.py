@@ -33,6 +33,19 @@ def _mix64(h: np.ndarray) -> np.ndarray:
     return h ^ (h >> np.uint64(33))
 
 
+def value_counts(values: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """`np.unique(values, return_counts=True)`, by one count over the value
+    range where integers span few enough values for that (dictionary codes,
+    dates, scaled decimals, dense keys), by its sort otherwise."""
+    if values.dtype.kind in "iu" and values.size:
+        lo, hi = int(values.min()), int(values.max())
+        if hi - lo < max(4 * values.size, 1 << 16):
+            per = np.bincount(values - lo if lo else values)
+            hit = np.flatnonzero(per)
+            return (hit + lo).astype(values.dtype), per[hit]
+    return np.unique(values, return_counts=True)
+
+
 class NdvSketch:
     """HyperLogLog with 2^P registers (mergeable; ~1.6% error at P=12)."""
 
@@ -52,16 +65,16 @@ class NdvSketch:
         else:
             h = _mix64(values.astype(np.int64).astype(np.uint64))
         idx = (h >> np.uint64(64 - self.P)).astype(np.int64)
-        rest = h << np.uint64(self.P)
-        # rank = leading zeros of the remaining 64-P bits, +1 (cap at 64-P+1)
-        lz = np.full(h.shape, 64 - self.P + 1, dtype=np.uint8)
-        found = np.zeros(h.shape, dtype=bool)
-        for bit in range(64 - self.P):
-            is_set = ~found & (((rest >> np.uint64(63 - bit)) &
-                                np.uint64(1)) == 1)
-            lz[is_set] = bit + 1
-            found |= is_set
-        np.maximum.at(self.registers, idx, lz)
+        # rank = leading zeros of the remaining 64-P bits, +1 (64-P+1 where
+        # none is set): those bits are an integer below 2^52, exact as a
+        # float64, whose binary exponent is its bit length
+        rest = h & np.uint64((1 << (64 - self.P)) - 1)
+        rank = (64 - self.P + 1) - np.frexp(rest.astype(np.float64))[1]
+        # the highest rank a register: one count over (register, rank) pairs
+        seen = np.bincount(idx * 64 + rank, minlength=self.M * 64
+                           ).reshape(self.M, 64) > 0
+        top = np.where(seen.any(axis=1), 63 - np.argmax(seen[:, ::-1], axis=1), 0)
+        np.maximum(self.registers, top.astype(np.uint8), out=self.registers)
 
     def merge(self, other: "NdvSketch") -> "NdvSketch":
         return NdvSketch(np.maximum(self.registers, other.registers))
@@ -113,8 +126,12 @@ class HeavyHitterSketch:
             values = values[~np.isnan(values)]
             if values.size == 0:
                 return
-        vals, cnts = np.unique(values, return_counts=True)
-        self.total += int(values.size)
+        self.add_counts(*value_counts(values))
+
+    def add_counts(self, vals: np.ndarray, cnts: np.ndarray):
+        """Fold in a batch given as its distinct values (ascending) and the
+        count of each."""
+        self.total += int(cnts.sum())
         counts = self.counts
         if vals.size > 32 * self.K:
             # high-NDV batch: only its top counts (plus already-tracked
@@ -251,8 +268,15 @@ def analyze_store(tm, store, sample_cap: int = 262144):
             vals = lane[valid] if not bool(valid.all()) else lane
             if vals.size == 0:
                 continue
-            sk.add_array(vals)  # per-partition sketch; np.maximum.at merges
-            hh.add_array(vals)  # frequent items fold across partitions too
+            if vals.dtype.kind == "f":
+                vals = vals[~np.isnan(vals)]
+                if vals.size == 0:
+                    continue
+            # both sketches read the partition's distinct values: a register
+            # keeps a maximum, which the repeats of a value cannot move
+            distinct, counts = value_counts(vals)
+            sk.add_array(distinct)  # per-partition sketch; registers merge by max
+            hh.add_counts(distinct, counts)  # frequent items fold across partitions
             if vals.size > per_part:
                 # strided sample: a leading-prefix slice of insertion-ordered
                 # data (e.g. monotone timestamps) sees only the oldest rows and

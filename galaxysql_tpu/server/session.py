@@ -2319,6 +2319,14 @@ class Session:
         from galaxysql_tpu.server.maintain import check_table
         schema = self._require_schema()
         rows = []
+        # a span a table (`analyze:<table>`, rows= and its seconds) where the
+        # session asked for its statements' trees or a profiler session records
+        annotate = tracing.device_trace_active()
+        tc = None
+        if annotate or self.vars.get("ENABLE_QUERY_TRACING"):
+            tc = tracing.TraceContext(self.instance.trace_ids.next(),
+                                      node=self.instance.node_id,
+                                      annotate=annotate)
         for name in stmt.names:
             tm = self.instance.catalog.table(name.schema or schema, name.table)
             if getattr(tm, "remote", None) is not None:
@@ -2373,14 +2381,28 @@ class Session:
     def _run_analyze(self, stmt: ast.AnalyzeTable) -> ResultSet:
         schema = self._require_schema()
         rows = []
+        # a span a table (`analyze:<table>`, rows= and its seconds) where the
+        # session asked for its statements' trees or a profiler session records
+        annotate = tracing.device_trace_active()
+        tc = None
+        if annotate or self.vars.get("ENABLE_QUERY_TRACING"):
+            tc = tracing.TraceContext(self.instance.trace_ids.next(),
+                                      node=self.instance.node_id,
+                                      annotate=annotate)
         for name in stmt.names:
             tm = self.instance.catalog.table(name.schema or schema, name.table)
             store = self.instance.store(tm.schema, tm.name)
             from galaxysql_tpu.meta.statistics import analyze_store
             # per-partition HLL sketches merged + equi-depth histograms
             # (Histogram.java / statistic/ndv analog)
-            analyze_store(tm, store)
+            with tc.span(f"analyze:{tm.name}", "analyze") if tc is not None \
+                    else _NULL_CTX as sp:
+                analyze_store(tm, store)
+                if sp is not None:
+                    sp.attrs["rows"] = tm.stats.row_count
             rows.append((f"{tm.schema}.{tm.name}", "analyze", "status", "OK"))
+        if tc is not None:
+            self.last_spans = list(tc.spans)
         self.instance.catalog.version += 1
         # fresh statistics re-arm HEAL_FAILED-parked plan baselines
         self.instance.catalog.stats_version += 1
