@@ -94,7 +94,10 @@ JOIN_STATS = {"probes": 0, "expand_levels": 0, "expand_full_depth_levels": 0,
               # the candidate pairs counted and the pair slots they were
               # given, summed over the probe batches that enumerated pairs
               # (benchmarks/metrics/ap_join_pair_fill_share.py)
-              "pairs": 0, "pair_slots": 0}
+              "pairs": 0, "pair_slots": 0,
+              # join-local bloom filters built, once a join
+              # (`HashJoinOp._device_probe`; span attribute `bloom=`)
+              "blooms": 0}
 
 # Where an aggregate's group capacity ladder settled: the key of its first
 # rung (its keys and calls, the capacity the planner's estimate gave) -> the
@@ -1372,9 +1375,8 @@ class HashJoinOp(Operator):
         n_build = build_batch.num_live()
         # `BLOOM_MAX_BUILD` keeps a large side's lanes from crossing the host
         # link for a filter; a side `_materialize_build` compacted on the host
-        # is there already, and without its filter the pair capacity starts
-        # from every live probe row (at SF10 Q3's 1.5M orders under 60M rows
-        # of `lineitem`: 134M pair slots for 0.3M pairs)
+        # is there already, and its filter leaves fewer live rows to a lookup
+        # that pays by the live row
         on_host = all(isinstance(c.data, np.ndarray)
                       for c in build_batch.columns.values())
         if n_build == 0 or (n_build > self.BLOOM_MAX_BUILD and not on_host):
@@ -2087,17 +2089,6 @@ class HashJoinOp(Operator):
     def _device_probe(self, build_batch: ColumnBatch, art,
                       stored: bool) -> Iterator[ColumnBatch]:
         kind = self.join_type
-        # runtime bloom filter (reference: RuntimeFilterBuilderExec -> scan pushdown,
-        # SURVEY.md §2.7): for inner/semi joins with one key, probe rows that cannot
-        # match are masked out before pair enumeration.  Bloom-negative rows are
-        # provably unmatched, so semantics are exact for inner/semi; left/anti must
-        # keep unmatched rows and skip the filter.
-        bloom_filter = None
-        if self.enable_bloom and kind in ("inner", "semi") and \
-                len(self.build_keys) == 1:
-            _, pk = self._key_compilers()
-            bloom_filter = self._build_bloom(build_batch, pk[0])
-
         csr = None
         if K.prefer_scatter():
             csr = art.csr if art is not None and art.csr is not None \
@@ -2105,16 +2096,34 @@ class HashJoinOp(Operator):
         if art is not None and not stored:
             art.csr = csr
             self._frag_store(art)
+        # a semi or anti join without a residual asks only WHETHER a probe row
+        # has a match: no pair is enumerated, so there is no capacity
+        matched_only = self.residual is None and kind in ("semi", "anti")
+        plain_inner = self.residual is None and kind == "inner"
+        # runtime bloom filter (reference: RuntimeFilterBuilderExec -> scan
+        # pushdown, SURVEY.md §2.7): probe rows of an inner or semi join with
+        # one key that cannot match are masked out before the lookup (exact:
+        # a bloom-negative row has no match; left/anti keep unmatched rows).
+        # It is built only where the next program pays by the LIVE row: the
+        # slot table's lookup (a slot's other keys are candidates) and the
+        # sorted formulation's matched-only semi join (`_front_rows`, in the
+        # bucket the live rows fill).  The sorted pair enumeration hashes,
+        # sorts and expands every probe SLOT, and a dead row counts no pair.
+        bloom_filter = None
+        if self.enable_bloom and kind in ("inner", "semi") and \
+                len(self.build_keys) == 1 and \
+                (csr is not None or matched_only):
+            _, pk = self._key_compilers()
+            bloom_filter = self._build_bloom(build_batch, pk[0])
+        if bloom_filter is not None:
+            JOIN_STATS["blooms"] += 1
         plits = self._plits()
         JOIN_STATS[kind] += 1
         sp = self._span()
         if sp is not None:
             sp.attrs.update(kind=kind, residual=int(self.residual is not None),
-                            cap=0, pairs=0)
-        # a semi or anti join without a residual asks only WHETHER a probe row
-        # has a match: no pair is enumerated, so there is no capacity
-        matched_only = self.residual is None and kind in ("semi", "anti")
-        plain_inner = self.residual is None and kind == "inner"
+                            bloom=int(bloom_filter is not None), cap=0,
+                            pairs=0)
         cap_max = total = 0
         # read once after the last batch is taken: row counts, and the
         # sorted formulation's expansions as (probe slots, passes)

@@ -47,10 +47,10 @@ def module_names(key, program):
 
 @pytest.fixture(scope="module")
 def modules_by_family(tmp_path_factory):
-    """Tiny TPC-H Q1/Q3/Q5/Q6 on the local engine (CPU formulation, then the
-    TPU's sort-based one) and Q3 on four virtual devices, broadcast and
-    shuffled; every program then
-    in `_JIT_CACHE`, by family."""
+    """Tiny TPC-H Q1/Q3/Q5/Q6 on the local engine (CPU formulation), Q3/Q4/Q5
+    on the TPU's sort-based one (Q4's EXISTS is the semi join that builds a
+    bloom filter there) and Q3 on four virtual devices, broadcast and
+    shuffled; every program then in `_JIT_CACHE`, by family."""
     data = tpch.generate(0.01)
     inst = Instance()
     s = Session(inst)
@@ -66,7 +66,7 @@ def modules_by_family(tmp_path_factory):
         for q in (1, 3, 5, 6):
             s.execute(HINT + QUERIES[q])
         K.prefer_scatter = lambda: False  # what a TPU traces: join_pairs
-        for q in (3, 5):
+        for q in (3, 4, 5):
             s.execute(HINT + QUERIES[q])
         K.prefer_scatter = cpu_formulation
         plan = inst.planner.plan_select(QUERIES[3], "tpch")
